@@ -41,19 +41,20 @@ def traced_bytes(num_tables: int, batch: int, pooling: int, dim: int,
     """
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import comm
     from repro.core.embedding_bag import (
         EmbeddingBagConfig, pooled_lookup_sharded)
     from repro.core.jagged import JaggedBatch
-    from repro.utils.compat import shard_map
+    from repro.launch.mesh import auto_mesh
 
     cfg = EmbeddingBagConfig(num_tables=num_tables, rows_per_table=1 << 20,
                              dim=dim, sharding="row", rw_impl="a2a")
     devs = jax.devices()
     if len(devs) < n_devices:           # abstract trace against a fake axis
         n_devices = len(devs)
-    mesh = jax.make_mesh((n_devices,), ("model",))
+    mesh = auto_mesh((n_devices,), ("model",))
     table_sds = jax.ShapeDtypeStruct(
         (num_tables, (1 << 20), dim), jnp.float32)
     batch_sds = JaggedBatch(
@@ -127,4 +128,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

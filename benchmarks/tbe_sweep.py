@@ -4,7 +4,7 @@ paper's #tables axis (§5): T in {1, 4, 16, 64}.
 Three views per (T, path):
 
   * ``launches`` — pallas_call count in the traced program (structural
-    proof: fused == 1 regardless of T, per_table == T under vmap).
+    proof: fused == 1 regardless of T, per_table == T).
   * modeled per-phase times (core/perf_model.tbe_gather_phases): ``launch``
     (per-kernel setup floor, the term TBE amortizes) and ``stream`` (HBM
     row traffic, identical in both layouts) on both calibrated platforms.
@@ -58,9 +58,7 @@ def count_launches(T: int, fused: bool) -> int:
                        kops.KERNEL_CONTRACTS["tbe_fused"])
         report.raise_if_failed()
         return report.summary.pallas_calls
-    # under vmap the T launches appear as ONE batched call-site; report
-    # the executed grid instances
-    return count_pallas_calls(fn, tables, idx, w) * T
+    return count_pallas_calls(fn, tables, idx, w)
 
 
 def measure(T: int, fused: bool, mode: str, reps: int) -> float:
@@ -138,4 +136,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

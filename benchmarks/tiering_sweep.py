@@ -127,7 +127,7 @@ def measured(shape: dict) -> dict:
 
     # instrumented fetch traffic (no HLO parsing): trace one fetch program
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.utils.compat import shard_map
+    from jax import shard_map
     M = 8
     mesh = Mesh(np.asarray(jax.devices()), ("hosts",))
     with comm.instrument() as events:
@@ -252,4 +252,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

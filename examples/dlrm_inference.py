@@ -28,6 +28,7 @@ from repro.configs import dlrm as dlrm_cfg
 from repro.core import comm
 from repro.core.jagged import JaggedBatch, random_jagged_batch
 from repro.core.parallel import make_context
+from repro.launch.mesh import auto_mesh
 from repro.models import dlrm as dlrm_mod
 from repro.serving.engine import CTRRequest, DLRMEngine
 
@@ -101,7 +102,7 @@ def main():
               "(run under XLA_FLAGS=--xla_force_host_platform_device_count=8)")
         return
 
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = auto_mesh((1, n_dev), ("data", "model"))
     ctx = make_context(mesh)
     for sharding, impl in [("row", "allgather"), ("row", "a2a"),
                            ("column", None), ("table", None)]:
@@ -125,4 +126,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -9,7 +9,7 @@ gather/pool -> reduce-scatter), and verifies it against the local oracle.
 import numpy as np
 import jax
 import jax.numpy as jnp
-from repro.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.embedding_bag import (
@@ -20,11 +20,12 @@ from repro.core.embedding_bag import (
     table_pspec,
 )
 from repro.core.jagged import random_jagged_batch
+from repro.launch.mesh import auto_mesh
 
 
 def main():
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("model",))
+    mesh = auto_mesh((n_dev,), ("model",))
     print(f"mesh: {n_dev} devices on axis 'model'")
 
     cfg = EmbeddingBagConfig(
@@ -61,4 +62,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

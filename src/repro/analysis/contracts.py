@@ -36,11 +36,21 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
-# Collective primitives as they appear in jaxprs (jax 0.4.x names).
+# Collective primitives as they appear in jaxprs (under a checked
+# shard_map, lax.psum traces as "psum_invariant" and lax.all_gather as
+# "all_gather_invariant").  Every primitive of ``jax._src.lax.parallel``
+# except the non-communicating ones in ``NON_COLLECTIVE_PARALLEL``.
 COLLECTIVE_PRIMITIVES = frozenset({
-    "psum", "psum2", "all_gather", "all_to_all", "ppermute",
-    "pbroadcast", "reduce_scatter", "psum_scatter", "pmin", "pmax",
-    "pgather", "collective_permute",
+    "psum", "psum_invariant", "all_gather", "all_gather_invariant",
+    "all_gather_reduced", "all_to_all", "ragged_all_to_all", "ppermute",
+    "psend", "precv", "pbroadcast", "reduce_scatter", "pmin", "pmax",
+    "pgather", "unreduced_psum", "unreduced_reduce_scatter",
+})
+
+# Primitives of ``jax._src.lax.parallel`` that move no data: the rank
+# index and two no-op type casts.
+NON_COLLECTIVE_PARALLEL = frozenset({
+    "axis_index", "preduced", "vary_unreduced_cast_p",
 })
 
 # Primitives that move data across the host<->device boundary (or call
@@ -126,9 +136,9 @@ class ContractViolation(AssertionError):
 
 def _iter_jaxprs(value):
     """Yield every (Closed)Jaxpr reachable from one eqn param value."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif hasattr(value, "eqns"):                       # raw Jaxpr
         yield value
@@ -337,6 +347,22 @@ def audit_hlo(hlo_text: str, contract: KernelContract) -> AuditReport:
         if count and op not in allowed
     ]
     return AuditReport(contract, violations)
+
+
+# One Pallas TPU kernel in compiled HLO: the custom call up to the next
+# instruction (its attributes and serialized body hold no "%").
+_TPU_KERNEL_RE = re.compile(r'custom_call_target="tpu_custom_call"[^%]*')
+
+
+def tpu_kernels(hlo_text: str) -> Tuple[int, int]:
+    """``(Pallas TPU kernels, of which collective)`` in a compiled TPU
+    program.  A collective kernel synchronises with its peers on a
+    barrier semaphore (``"barrier_type":"CUSTOM"``): the one-sided RDMA
+    kernels do, the embedding gather kernels do not, so a one-sided
+    path that fell back to ``lax`` collectives counts none."""
+    kernels = _TPU_KERNEL_RE.findall(hlo_text)
+    return len(kernels), sum('"barrier_type":"CUSTOM"' in k
+                             for k in kernels)
 
 
 def repo_contracts() -> Dict[str, KernelContract]:

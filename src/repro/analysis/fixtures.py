@@ -142,9 +142,7 @@ def audit_tiered_forward() -> AuditReport:
     batch_t = JaggedBatch(_sds((T, batch, cfg.pooling), jnp.int32),
                           _sds((T, batch), jnp.int32))
     return audit(
-        lambda p, d, b: jax.nn.sigmoid(
-            dlrm_mod.forward(p, d, b, cfg, None)),
-        (params_t, dense_t, batch_t),
+        engine.make_scorer(cfg), (params_t, dense_t, batch_t),
         engine.KERNEL_CONTRACTS["tiered_forward"])
 
 

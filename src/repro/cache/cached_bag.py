@@ -66,13 +66,18 @@ def _valid_mask(indices: np.ndarray, lengths: Optional[np.ndarray]):
 _PREFETCH_SEQ = itertools.count()
 
 
-def make_cold_store(tables, cache: CacheConfig) -> TableStore:
-    """Build the cold tier named by ``cache.cold_tier``."""
+def make_cold_store(tables, cache: CacheConfig,
+                    kernel_mode: str = "auto") -> TableStore:
+    """Build the cold tier named by ``cache.cold_tier``; a one-sided
+    remote tier runs its RDMA kernel under ``kernel_mode``."""
     if cache.cold_tier == "host":
         return HostStore(tables)
     if cache.cold_tier == "remote":
-        return RemoteStore(tables, hosts=cache.remote_hosts or None,
-                           backend=cache.remote_backend)
+        onesided = cache.remote_backend == "onesided"
+        return RemoteStore(
+            tables, hosts=cache.remote_hosts or None,
+            backend=cache.remote_backend,
+            interpret=onesided and kops.onesided_interpret(kernel_mode))
     raise ValueError(
         f"unknown cold_tier {cache.cold_tier!r}; pick 'host' or 'remote'")
 
@@ -93,7 +98,7 @@ class CachedEmbeddingBag:
         if tables.ndim != 3:
             raise ValueError(f"tables must be (T, R, D), got {tables.shape}")
         self.cold = cold_store if cold_store is not None \
-            else make_cold_store(tables, cc)
+            else make_cold_store(tables, cc, cfg.kernel_mode)
         T, R, D = tables.shape
         self.dtype = tables.dtype
         # slot sizing: the CacheConfig's per-table vector (the planner ->

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import comm
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
 def _pad_pow2(arrays):
@@ -208,7 +208,7 @@ class RemoteStore(TableStore):
 
     def __init__(self, tables, *, hosts: Optional[int] = None,
                  backend: str = "bulk", home: int = 0,
-                 axis_name: str = "hosts"):
+                 axis_name: str = "hosts", interpret: bool = False):
         tables = np.asarray(tables)
         if tables.ndim != 3:
             raise ValueError(f"tables must be (T, R, D), got {tables.shape}")
@@ -237,23 +237,21 @@ class RemoteStore(TableStore):
                   .transpose(1, 0, 2, 3).reshape(H, T * self._rows_per_host,
                                                  D))
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        self.mesh = Mesh(np.asarray(jax.devices()[:H]), (axis_name,))
+        self.mesh = mesh = Mesh(np.asarray(jax.devices()[:H]), (axis_name,))
         self.shards = jax.device_put(
             shards, NamedSharding(self.mesh, P(axis_name)))
-        # one-sided fetches run the Pallas RDMA kernel: real Mosaic
-        # lowering on TPU slices, the interpreter elsewhere (CPU tests).
-        # The mode is threaded per-call — building a store never flips the
-        # process-global comm.set_onesided_mode gate.
-        onesided_mode = ("tpu" if jax.default_backend() == "tpu"
-                         else "interpret") if backend == "onesided" else None
+        # one-sided fetches run the Pallas RDMA kernel: compiled, or in
+        # the Pallas interpreter when the caller asks (CPU tests).  The
+        # closure holds the mesh, not ``self``: a cycle through the jitted
+        # function would keep a dropped store's shards in HBM until the
+        # garbage collector runs.
 
         def _fetch(shards, addr, owner):
             def inner(shard, a, o):
                 return comm.fetch_rows(shard[0], a, o, axis_name,
-                                       backend=backend,
-                                       onesided_mode=onesided_mode)
+                                       backend=backend, interpret=interpret)
             return shard_map(
-                inner, mesh=self.mesh,
+                inner, mesh=mesh,
                 in_specs=(P(axis_name), P(), P()), out_specs=P(),
                 check_vma=False)(shards, addr, owner)
 

@@ -8,9 +8,12 @@ The paper compares two communication regimes:
   * NVSHMEM — device-initiated one-sided communication, latency-optimized
     for small messages. TPU analogue: Pallas ``make_async_remote_copy``
     ring kernels (see kernels/onesided_a2a.py). Backend name:
-    ``"onesided"``. On non-TPU backends it falls back to the same lax
-    collectives (identical semantics); the latency difference is modelled
-    analytically in core/perf_model.py, mirroring how the paper projects.
+    ``"onesided"``. Every collective of the phase pipeline has a
+    one-sided form (all-gather and all-reduce exchange E copies through
+    the a2a kernel); a ``"onesided"`` request runs the kernel — on TPU,
+    or in the Pallas interpreter with ``interpret=True`` — and never
+    falls back to lax. The latency difference is modelled analytically
+    in core/perf_model.py, mirroring how the paper projects.
 
 Every wrapper records (op, payload bytes, axis size) into an optional
 instrumentation log so benchmarks can account collective traffic without
@@ -31,7 +34,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.utils.compat import axis_size
+from jax.lax import axis_size
 import numpy as np
 
 
@@ -120,52 +123,63 @@ def record_runtime(op: str, nbytes: int, n_devices: int, backend: str,
 # Collectives (call inside shard_map)
 # ---------------------------------------------------------------------------
 
-_ONESIDED_MODE = "off"   # off | interpret | tpu
+def _onesided(backend: str) -> bool:
+    if backend not in ("bulk", "onesided"):
+        raise ValueError(f"unknown backend {backend!r}; pick 'bulk' or "
+                         f"'onesided'")
+    return backend == "onesided"
 
 
-def set_onesided_mode(mode: str):
-    """Route backend="onesided" collectives through the Pallas RDMA kernel.
-
-    "off" (default): one-sided requests fall back to lax collectives —
-      identical semantics; required for the 512-placeholder-device dry-run
-      (TPU DMA primitives must not be traced there).
-    "interpret": Pallas interpret mode (CPU tests — models the remote DMA).
-    "tpu": real Mosaic lowering (TPU slices).
-    """
-    global _ONESIDED_MODE
-    assert mode in ("off", "interpret", "tpu")
-    _ONESIDED_MODE = mode
+def _exchange(x, axis_name, interpret):
+    """One-sided all-gather core: ``out[i]`` on every rank is rank i's
+    ``x`` — the a2a kernel over E copies of ``x``."""
+    from repro.kernels.onesided_a2a import onesided_all_to_all
+    E = axis_size(axis_name)
+    return onesided_all_to_all(jnp.broadcast_to(x, (E,) + x.shape),
+                               axis_name, interpret=interpret)
 
 
-def _onesided_active(backend: str) -> bool:
-    return backend == "onesided" and _ONESIDED_MODE != "off"
+def all_to_all(x, axis_name, *, split_axis=0, concat_axis=0, backend="bulk",
+               interpret=False):
+    """All-to-all: dim ``split_axis`` (size == axis size) is exchanged.
 
-
-def all_to_all(x, axis_name, *, split_axis=0, concat_axis=0, backend="bulk"):
-    """All-to-all: dim ``split_axis`` (size == axis size) is exchanged."""
+    ``backend="onesided"`` runs the Pallas RDMA kernel (``interpret``
+    runs it in the Pallas interpreter) and exchanges dim 0 only."""
     _record("all_to_all", x, axis_name, backend)
-    if (_onesided_active(backend) and split_axis == 0 and concat_axis == 0
-            and x.ndim >= 2):
+    if _onesided(backend):
+        if split_axis != 0 or concat_axis != 0 or x.ndim < 2:
+            raise ValueError(
+                "the one-sided all_to_all exchanges dim 0 of an array of "
+                f"rank >= 2 (got split_axis={split_axis}, "
+                f"concat_axis={concat_axis}, ndim={x.ndim})")
         from repro.kernels.onesided_a2a import onesided_all_to_all
-        return onesided_all_to_all(
-            x, axis_name, interpret=_ONESIDED_MODE == "interpret")
+        return onesided_all_to_all(x, axis_name, interpret=interpret)
     return jax.lax.all_to_all(
         x, axis_name, split_axis=split_axis, concat_axis=concat_axis, tiled=False
     )
 
 
-def all_gather(x, axis_name, *, axis=0, tiled=False, backend="bulk"):
+def all_gather(x, axis_name, *, axis=0, tiled=False, backend="bulk",
+               interpret=False):
     _record("all_gather", x, axis_name, backend)
+    if _onesided(backend):
+        parts = _exchange(x, axis_name, interpret)
+        if tiled:
+            return jnp.concatenate(list(parts), axis=axis)
+        return jnp.moveaxis(parts, 0, axis)
     return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
 
-def all_reduce(x, axis_name, *, backend="bulk"):
+def all_reduce(x, axis_name, *, backend="bulk", interpret=False):
     _record("all_reduce", x, axis_name, backend)
+    if _onesided(backend):
+        return _exchange(x, axis_name, interpret).sum(axis=0)
     return jax.lax.psum(x, axis_name)
 
 
 def reduce_scatter(
-    x, axis_name, *, scatter_axis=0, backend="bulk", emulate_with_a2a=False
+    x, axis_name, *, scatter_axis=0, backend="bulk", emulate_with_a2a=False,
+    interpret=False,
 ):
     """Reduce-scatter over leading dim of size == axis size.
 
@@ -175,12 +189,13 @@ def reduce_scatter(
     collective — the benchmarks quantify exactly this gap.
     """
     _record("reduce_scatter", x, axis_name, backend)
-    if _onesided_active(backend) and scatter_axis == 0:
+    if _onesided(backend):
         # NVSHMEM 2.9 has no reduce-scatter primitive (§4.4): the one-sided
         # backend ALWAYS uses the a2a + local-sum emulation, like the paper.
+        if scatter_axis != 0:
+            raise ValueError("the one-sided reduce_scatter scatters dim 0")
         from repro.kernels.onesided_a2a import onesided_reduce_scatter
-        return onesided_reduce_scatter(
-            x, axis_name, interpret=_ONESIDED_MODE == "interpret")
+        return onesided_reduce_scatter(x, axis_name, interpret=interpret)
     if emulate_with_a2a:
         exchanged = jax.lax.all_to_all(
             x, axis_name, split_axis=scatter_axis, concat_axis=scatter_axis
@@ -192,7 +207,7 @@ def reduce_scatter(
 
 
 def fetch_rows(shard, local_addr, owner, axis_name, *, backend="bulk",
-               onesided_mode=None):
+               interpret=False):
     """Batched cross-rank row fetch — the tiered cache's cold-tier transport.
 
     Each rank holds a flat ``(rows_local, D)`` slice of the cluster-wide
@@ -210,8 +225,7 @@ def fetch_rows(shard, local_addr, owner, axis_name, *, backend="bulk",
 
       * ``backend="bulk"``   — one ``psum_scatter`` over the stacked
         (E, M, D) contributions (host-launched bulk collective);
-      * ``backend="onesided"`` (when enabled via :func:`set_onesided_mode`)
-        — per-row device-initiated RDMA puts
+      * ``backend="onesided"`` — per-row device-initiated RDMA puts
         (kernels/onesided_a2a.onesided_fetch_rows), the NVSHMEM-analogue
         row fetch that wins at embedding-row message sizes.
 
@@ -219,11 +233,7 @@ def fetch_rows(shard, local_addr, owner, axis_name, *, backend="bulk",
     Call INSIDE shard_map over ``axis_name``.  One CollectiveEvent
     (op="fetch_rows") is recorded with the stacked payload bytes so
     benchmarks can account the traffic without HLO parsing.
-
-    ``onesided_mode`` overrides the process-global
-    :func:`set_onesided_mode` gate for THIS call ("interpret" | "tpu" |
-    "off") — RemoteStore threads it explicitly so building a store never
-    has to flip global tracing state.
+    ``interpret`` runs the one-sided kernel in the Pallas interpreter.
     """
     rank = jax.lax.axis_index(axis_name)
     req_addr = jax.lax.all_gather(local_addr, axis_name)      # (E, M)
@@ -232,11 +242,9 @@ def fetch_rows(shard, local_addr, owner, axis_name, *, backend="bulk",
     safe = jnp.where(mine, req_addr, 0)
     contrib = shard[safe] * mine[..., None].astype(shard.dtype)  # (E, M, D)
     _record("fetch_rows", contrib, axis_name, backend)
-    mode = _ONESIDED_MODE if onesided_mode is None else onesided_mode
-    if backend == "onesided" and mode != "off":
+    if _onesided(backend):
         from repro.kernels.onesided_a2a import onesided_fetch_rows
-        return onesided_fetch_rows(
-            contrib, axis_name, interpret=mode == "interpret")
+        return onesided_fetch_rows(contrib, axis_name, interpret=interpret)
     return jax.lax.psum_scatter(
         contrib, axis_name, scatter_dimension=0, tiled=False)
 

@@ -32,7 +32,7 @@ are sharded over ``model_axis`` according to ``cfg.sharding``.
 
 Kernel execution is table-batched by default (``cfg.fused``): each shard
 issues ONE fused TBE ``pallas_call`` covering all of its tables
-(kernels/embedding_gather.py) instead of T vmapped single-table launches —
+(kernels/embedding_gather.py) instead of T single-table launches —
 the paper's #tables sweep (§5) is a launch-count sweep under the unfused
 baseline and flat under TBE.
 """
@@ -45,7 +45,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.utils.compat import axis_size
+from jax.lax import axis_size
 
 from repro.core import comm
 from repro.core.cache_config import CacheConfig, resolve_cache_aliases
@@ -67,8 +67,8 @@ class EmbeddingBagConfig:
     emulate_rs_with_a2a: bool = False  # paper's NVSHMEM reduce-scatter workaround
     kernel_mode: str = "auto"        # auto | reference | pallas | interpret
     # fused: run the table-batched (TBE) kernel — ONE pallas_call for all T
-    # tables per shard. False vmaps the single-table kernel (T launches);
-    # kept as the A/B baseline for benchmarks/tbe_sweep.py.
+    # tables per shard. False calls the single-table kernel per table (T
+    # launches); kept as the A/B baseline for benchmarks/tbe_sweep.py.
     fused: bool = True
     # --- beyond-paper levers (EXPERIMENTS.md §beyond-paper) ---
     # rs_dtype: cast partial pooled vectors to this dtype before the
@@ -128,13 +128,17 @@ class EmbeddingBagConfig:
 
 def init_tables(rng: jax.Array, cfg: EmbeddingBagConfig) -> jax.Array:
     """(T, R, D) stacked tables; scale 1/sqrt(D) like TorchRec defaults."""
-    scale = cfg.dim ** -0.5
-    return (
-        jax.random.normal(
-            rng, (cfg.num_tables, cfg.rows_per_table, cfg.dim), dtype=jnp.float32
-        )
-        * scale
-    ).astype(cfg.dtype)
+    return _normal_tables(rng, (cfg.num_tables, cfg.rows_per_table, cfg.dim),
+                          jnp.dtype(cfg.dtype))
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _normal_tables(rng, shape, dtype):
+    # one jitted program: the draw, the scale and the cast fuse into the
+    # output buffer, so CONFIG-scale tables (13.3 GB) never need a second
+    # full-size temporary on the device
+    return (jax.random.normal(rng, shape, jnp.float32)
+            * shape[-1] ** -0.5).astype(dtype)
 
 
 def table_pspec(cfg: EmbeddingBagConfig, model_axis: str = "model"):
@@ -167,7 +171,7 @@ def pooled_lookup_local(
 
     All T tables go through ONE table-batched kernel call when
     ``cfg.fused`` (the default); ``fused=False`` restores the per-table
-    vmap baseline (3-D tables only — a ragged flat pool is always fused).
+    baseline (3-D tables only — a ragged flat pool is always fused).
     """
     if tables.ndim == 2:
         offsets = cfg.cache.slot_offsets(
@@ -192,6 +196,15 @@ def pooled_lookup_local(
         fused=cfg.fused,
     )                                                        # (T, B, D)
     return out.transpose(1, 0, 2)
+
+
+def _comm(cfg: EmbeddingBagConfig) -> dict:
+    """Collective options of the RW pipeline: the configured backend,
+    and for the one-sided backend whether its kernels run interpreted
+    (they follow ``cfg.kernel_mode``)."""
+    return dict(backend=cfg.rw_backend,
+                interpret=cfg.rw_backend == "onesided"
+                and kops.onesided_interpret(cfg.kernel_mode))
 
 
 # ---------------------------------------------------------------------------
@@ -234,11 +247,11 @@ def _rw_allgather(
             stacked,
             model_axis,
             scatter_axis=0,
-            backend=cfg.rw_backend,
             emulate_with_a2a=cfg.emulate_rs_with_a2a,
+            **_comm(cfg),
         ).astype(out_dtype)
     return comm.all_reduce(partial_out, model_axis,
-                           backend=cfg.rw_backend).astype(out_dtype)
+                           **_comm(cfg)).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -338,9 +351,9 @@ def _rw_a2a(
         packed, flat_w, flat_seg, E, capacity,
         rows_per_shard * T,  # packed ids of one shard span rows_per_shard*T
     )
-    recv_p = comm.all_to_all(send_p, model_axis, backend=cfg.rw_backend)
-    recv_w = comm.all_to_all(send_w, model_axis, backend=cfg.rw_backend)
-    recv_seg = comm.all_to_all(send_seg, model_axis, backend=cfg.rw_backend)
+    recv_p = comm.all_to_all(send_p, model_axis, **_comm(cfg))
+    recv_w = comm.all_to_all(send_w, model_axis, **_comm(cfg))
+    recv_seg = comm.all_to_all(send_seg, model_axis, **_comm(cfg))
 
     # ---- phase 2: local gather + pool (segment-sum) ------------------------
     recv_row = recv_p // T - rank * rows_per_shard            # local row id
@@ -373,8 +386,8 @@ def _rw_a2a(
         partial,
         model_axis,
         scatter_axis=0,
-        backend=cfg.rw_backend,
         emulate_with_a2a=cfg.emulate_rs_with_a2a,
+        **_comm(cfg),
     ).astype(jnp.float32)                                      # (Bl*T, D)
     pooled = pooled.reshape(Bl, T, -1).astype(table_shard.dtype)
 
@@ -386,7 +399,7 @@ def _rw_a2a(
 
     # restore model-axis replication of the full batch (tiled all-gather)
     out = comm.all_gather(
-        pooled, model_axis, axis=0, tiled=True, backend=cfg.rw_backend
+        pooled, model_axis, axis=0, tiled=True, **_comm(cfg)
     )                                                          # (B, T, D)
     return out, dropped
 

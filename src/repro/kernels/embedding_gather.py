@@ -1,60 +1,61 @@
-"""Pallas TPU kernels for the embedding-bag gather+pool phase.
+"""Pallas TPU kernel for the embedding-bag gather+pool phase.
 
 The paper's phase-2 "gather kernel" (§4.3) retrieves ``L`` rows per sample
 from an HBM-resident table and pools (weighted-sums) them. On GPU this is a
-CUDA gather; the TPU-native formulation is *scalar-prefetch driven DMA*:
+CUDA gather; on TPU it is a manual row DMA from HBM into VMEM followed by a
+vector weighted sum.
 
-  - lookup ids are scalar-prefetched into SMEM before the kernel runs,
-  - the table BlockSpec ``index_map`` reads the prefetched ids, so the
-    Pallas pipeline DMAs exactly the rows ``table[idx[b, l]]`` HBM->VMEM
-    (one (1, Db) block per grid step, double-buffered by the pipeline),
-  - the kernel body accumulates ``w[b, l] * row`` into the f32 output
-    block in VREGs.
+ONE kernel, table-batched (TBE, FBGEMM-style): ``gather_pool_tbe_flat_pallas``
+executes the lookups of ALL ``T`` tables in ONE ``pallas_call`` over a FLAT
+heterogeneous row space ``(N, D)``; table ``t``'s rows start at
+``row_offsets[t]``, so ragged per-table row counts (the tiered cache's
+``(sum S_t, D)`` slot pool) only need a different offsets vector. The
+uniform ``(T, R, D)`` stack is ``row_offsets[t] = t * R``
+(``gather_pool_tbe_pallas``) and the single table is the ``T = 1`` call
+(``gather_pool_pallas``). The paper sweeps #tables (§5); per-table
+launches pay T separate grid setups and pipeline drains, fusing removes
+them.
 
-Three kernels:
+Design (what the v5e compiler accepts):
 
-``gather_pool_pallas`` — single table. Grid ``(B, num_D_blocks, L)``; the
-L axis is innermost ("arbitrary" semantics) so all visits to an output
-block ``(b, d)`` are consecutive and accumulation is legal; B and D blocks
-are parallel.
+  * Grid ``(T, B_pad / Bt)``: one step pools a tile of ``Bt`` samples of
+    one table into an ``(Bt, D)`` f32 output block. Both axes are
+    "parallel"; no step accumulates into another's block.
+  * The table stays in HBM (``memory_space=pl.ANY``). Each step starts
+    ``Bt * L`` single-row DMAs ``table[row_offsets[t] + idx[t, b, l]]``
+    into a VMEM scratch laid out ``(L, Bt, D)``, all on one DMA
+    semaphore, then waits for all of them.
+  * Ids travel per tile: a ``(Bt * L,)`` int32 SMEM block of the
+    ``(T, nb, 1, Bt * L)`` id array, pipelined by the grid. Prefetching
+    the whole ``(T * B, L)`` id array into SMEM does not fit at serving
+    batch sizes (1 MiB of SMEM; the 2-D array pads to 128 lanes, so
+    T=26, L=32, B=128 already needs 1.7 MB).
+  * Weights are a ``(Bt, L)`` f32 VMEM block; the pool is the static loop
+    ``acc += rows[l] * w[:, l]`` over ``l`` — an f32 accumulate in the
+    same ``l`` order for every table layout, so the stacked-table and the
+    slot-pool paths pool equal rows to bitwise-equal outputs.
+  * Row ids are clamped into ``[0, N)`` in the kernel: a DMA never reads
+    outside the table, whatever a padded slot holds.
 
-``gather_pool_tbe_flat_pallas`` — TABLE-BATCHED (TBE, FBGEMM-style) over a
-FLAT heterogeneous row space: executes the lookups of ALL ``T`` tables in
-ONE ``pallas_call``, with ragged per-table row counts described only by a
-scalar-prefetched ``(T,)`` ``row_offsets`` vector. This is the kernel the
-tiered cache's flat ``(sum S_t, D)`` slot pool runs on. The paper sweeps
-#tables (§5) and per-table launches pay T separate grid setups and
-pipeline drains; fusing removes them.
+The compiler refuses single-row slices of an HBM table unless its rows
+are exactly one 128-lane tile of a 32-bit dtype (``D = 128``, f32/int32):
+wider or packed (bf16) rows are tiled (8, 128) / (16, 128) in HBM, and
+narrower rows are not lane-aligned. ``interpret=False`` therefore raises
+for other shapes instead of compiling a kernel that the chip would
+refuse; the interpreter (CPU tests) takes any shape. A one-row (1, D)
+BlockSpec per grid step — this module's earlier design — is refused
+outright ("last two dimensions of your block shape [must be] divisible by
+8 and 128").
 
-``gather_pool_tbe_pallas`` — the uniform-rows ``(T, R, D)`` wrapper:
-delegates to the flat kernel with ``row_offsets[t] = t * R``. Design:
+VMEM per step: the ``(L, Bt, D)`` row scratch (``Bt`` is chosen so it
+stays <= 4 MiB: 2 MiB at L=32, D=128, Bt=128), plus the double-buffered
+``(Bt, L)`` weights and ``(Bt, D)`` output blocks.
 
-  * Flattened row space — the stacked ``(T, R, D)`` tables are viewed as
-    one ``(T*R, D)`` array; table ``t``'s rows live at ``[t*R, (t+1)*R)``.
-    Addressing is fully general: a ``(T,)`` int32 ``row_offsets`` vector
-    is scalar-prefetched alongside the indices, so ragged per-table row
-    counts only need a different offsets vector (offsets[t] = start of
-    table t in the flat row space).
-  * Offset math — lookup ids stay TABLE-LOCAL on the host; the table
-    BlockSpec ``index_map`` computes the flat row
-    ``row_offsets[tb // B] + idx[tb, l]`` at DMA-issue time from the two
-    prefetched SMEM arrays (no O(T*B*L) index rewrite materialized in HBM).
-  * Grid layout — ``(T*B, num_D_blocks, L)``: the fused sample axis
-    ``tb = t*B + b`` covers every (table, sample) pair, so one
-    double-buffered DMA pipeline streams rows of all tables back-to-back;
-    L is innermost/"arbitrary" for legal accumulation, T*B and D parallel.
-  * Output — ``(T*B, D)`` f32, reshaped to ``(T, B, D)`` by the caller.
-
-The RW-masked (row-wise-parallel, paper §4.2) variants of BOTH kernels are
-expressed by pre-masking: ops.py maps out-of-shard ids to local row 0 with
-weight 0, so the same kernels serve the single-device and the sharded
-paths (for TBE the shard's flat row space is ``(T * R/E, D)`` and
-``row_offsets[t] = t * R/E``).
-
-VMEM budget per grid step: 2 double-buffered (1, Db) table blocks +
-(1, Db) f32 accumulator + (1, L) weights — Db is chosen ≤ 2048 lanes so the
-working set stays ≪ 1 MiB, far under v5e VMEM. Identical for the fused
-kernel: batching tables grows the grid, not the working set.
+The RW-masked (row-wise-parallel, paper §4.2) variant is expressed by
+pre-masking: ops.py maps out-of-shard ids to local row 0 with weight 0,
+so the same kernel serves the single-device and the sharded paths (the
+shard's flat row space is ``(T * R/E, D)`` with ``row_offsets[t] =
+t * R/E``).
 """
 from __future__ import annotations
 
@@ -65,88 +66,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import CompilerParams
+
+_ROW_SCRATCH_BYTES = 4 << 20   # VMEM budget for one step's (L, Bt, D) rows
+_MAX_TILE = 128                # samples per grid step
 
 
-DEFAULT_D_BLOCK = 2048  # lanes per block; multiple of 128 (MXU/VPU lane width)
+def _tile(B: int, L: int, D: int, itemsize: int) -> int:
+    """Samples per grid step: a multiple of 8 (sublanes), at most
+    ``_MAX_TILE``, with the row scratch inside its VMEM budget."""
+    fit = max(8, _ROW_SCRATCH_BYTES // (L * D * itemsize) // 8 * 8)
+    return min(_MAX_TILE, fit, -(-B // 8) * 8)
 
 
-def _gather_pool_kernel(idx_ref, w_ref, table_blk, out_blk, *, L: int):
-    """One grid step: out[b, d_blk] += w[b, l] * table[idx[b, l], d_blk]."""
-    l = pl.program_id(2)
+def _tbe_kernel(off_ref, ids_ref, w_ref, table_hbm, out_ref, rows, sem, *,
+                L: int, tile: int, num_rows: int):
+    """One grid step: pool ``tile`` samples of table ``program_id(0)``."""
+    base = off_ref[pl.program_id(0)]
 
-    @pl.when(l == 0)
-    def _init():
-        out_blk[...] = jnp.zeros_like(out_blk)
+    def row_copy(i):
+        row = jnp.clip(base + ids_ref[i], 0, num_rows - 1)
+        return pltpu.make_async_copy(
+            table_hbm.at[pl.ds(row, 1)],
+            rows.at[i % L, pl.ds(i // L, 1)], sem)
 
-    w = w_ref[0, l]
-    out_blk[...] += table_blk[...].astype(jnp.float32) * w
+    def start(i, carry):
+        row_copy(i).start()
+        return carry
 
+    def wait(i, carry):
+        # every copy moves one row on the same semaphore: any one-row
+        # descriptor waits for one of them
+        pltpu.make_async_copy(table_hbm.at[pl.ds(0, 1)],
+                              rows.at[0, pl.ds(0, 1)], sem).wait()
+        return carry
 
-def _pick_d_block(D: int) -> int:
-    if D % 128 == 0:
-        return min(D, DEFAULT_D_BLOCK)
-    # Non-128-multiple embedding dims (e.g. DLRM D=32/64): single block,
-    # Pallas pads the lane dimension internally.
-    return D
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "d_block"))
-def gather_pool_pallas(
-    table: jax.Array,     # (R, D)
-    indices: jax.Array,   # (B, L) int32 — must be in [0, R)
-    weights: jax.Array,   # (B, L) f32 — 0 for masked/padded slots
-    *,
-    interpret: bool = False,
-    d_block: int | None = None,
-) -> jax.Array:
-    """Pooled lookup: ``out[b] = sum_l weights[b,l] * table[indices[b,l]]``.
-
-    Returns (B, D) f32 (accumulation dtype; callers cast).
-    """
-    R, D = table.shape
-    B, L = indices.shape
-    Db = d_block or _pick_d_block(D)
-    if D % Db != 0:
-        raise ValueError(f"D={D} not divisible by d_block={Db}")
-    nD = D // Db
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, nD, L),
-        in_specs=[
-            # weights: one (1, L) row per sample, reused across d/l steps
-            pl.BlockSpec((1, L), lambda b, d, l, idx: (b, 0)),
-            # table: the (1, Db) block of the row named by the prefetched id
-            pl.BlockSpec((1, Db), lambda b, d, l, idx: (idx[b, l], d)),
-        ],
-        out_specs=pl.BlockSpec((1, Db), lambda b, d, l, idx: (b, d)),
-    )
-
-    return pl.pallas_call(
-        functools.partial(_gather_pool_kernel, L=L),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(indices, weights.astype(jnp.float32), table)
+    jax.lax.fori_loop(0, tile * L, start, 0)
+    jax.lax.fori_loop(0, tile * L, wait, 0)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for l in range(L):
+        acc = acc + rows[l].astype(jnp.float32) * w_ref[:, l:l + 1]
+    out_ref[...] = acc
 
 
-# ---------------------------------------------------------------------------
-# Table-batched (TBE) kernel — all T tables in one launch
-# ---------------------------------------------------------------------------
-
-def _tbe_kernel(off_ref, idx_ref, w_ref, table_blk, out_blk, *, L: int):
-    """One grid step of the fused kernel: the single-table accumulate over
-    the fused (tb = t*B + b) sample axis. ``off_ref``/``idx_ref`` are
-    consumed by the BlockSpec index_maps, not the body."""
-    del off_ref
-    _gather_pool_kernel(idx_ref, w_ref, table_blk, out_blk, L=L)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "d_block"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_pool_tbe_flat_pallas(
     flat_tables: jax.Array,   # (N, D) concatenated per-table row blocks
     row_offsets: jax.Array,   # (T,) int32 — start of table t's rows in N
@@ -154,7 +116,6 @@ def gather_pool_tbe_flat_pallas(
     weights: jax.Array,       # (T, B, L) f32 — 0 for masked/padded slots
     *,
     interpret: bool = False,
-    d_block: int | None = None,
 ) -> jax.Array:
     """Fused pooled lookup over a FLAT heterogeneous row space.
 
@@ -163,73 +124,69 @@ def gather_pool_tbe_flat_pallas(
     (or slot pools) may have RAGGED per-table row counts, described only
     by the scalar-prefetched ``row_offsets`` vector. This is what the
     tiered cache's ``(sum S_t, D)`` slot pool addresses with
-    ``row_offsets = cumsum(S_t)[:-1]``; the uniform ``(T, R, D)`` case is
-    ``row_offsets[t] = t * R`` (see :func:`gather_pool_tbe_pallas`).
+    ``row_offsets = cumsum(S_t)[:-1]``.
 
     Returns (T, B, D) f32 (accumulation dtype; callers cast). See the
-    module docstring for the offset / grid design.
+    module docstring for the grid / DMA design.
     """
     N, D = flat_tables.shape
     T, B, L = indices.shape
     if row_offsets.shape != (T,):
         raise ValueError(
             f"row_offsets must be (T,)=({T},), got {row_offsets.shape}")
-    Db = d_block or _pick_d_block(D)
-    if D % Db != 0:
-        raise ValueError(f"D={D} not divisible by d_block={Db}")
-    nD = D // Db
-    TB = T * B
-
-    flat_idx = indices.reshape(TB, L)
-    flat_w = weights.reshape(TB, L).astype(jnp.float32)
-    row_offsets = row_offsets.astype(jnp.int32)
+    itemsize = jnp.dtype(flat_tables.dtype).itemsize
+    if not interpret and (D != 128 or itemsize != 4):
+        raise NotImplementedError(
+            f"the TPU gather kernel needs (N, 128) rows of a 32-bit dtype "
+            f"(the compiler refuses one-row DMAs from other HBM tilings); "
+            f"got D={D}, {flat_tables.dtype}")
+    tile = _tile(B, L, D, itemsize)
+    nb = -(-B // tile)
+    pad = nb * tile - B
+    if pad:   # padded samples: row 0, weight 0 -> sliced off below
+        indices = jnp.pad(indices, ((0, 0), (0, pad), (0, 0)))
+        weights = jnp.pad(weights, ((0, 0), (0, pad), (0, 0)))
+    ids = indices.astype(jnp.int32).reshape(T, nb, 1, tile * L)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,   # row_offsets (T,), flat_idx (T*B, L)
-        grid=(TB, nD, L),
+        num_scalar_prefetch=1,   # row_offsets (T,)
+        grid=(T, nb),
         in_specs=[
-            # weights: one (1, L) row per fused sample
-            pl.BlockSpec((1, L), lambda tb, d, l, off, idx: (tb, 0)),
-            # flat table: block of row  off[tb // B] + idx[tb, l]
-            pl.BlockSpec(
-                (1, Db),
-                lambda tb, d, l, off, idx: (off[tb // B] + idx[tb, l], d),
-            ),
+            pl.BlockSpec((None, None, None, tile * L),
+                         lambda t, i, off: (t, i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, tile, L), lambda t, i, off: (t, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Db), lambda tb, d, l, off, idx: (tb, d)),
+        out_specs=pl.BlockSpec((None, tile, D), lambda t, i, off: (t, i, 0)),
+        scratch_shapes=[pltpu.VMEM((L, tile, D), flat_tables.dtype),
+                        pltpu.SemaphoreType.DMA(())],
     )
-
     out = pl.pallas_call(
-        functools.partial(_tbe_kernel, L=L),
+        functools.partial(_tbe_kernel, L=L, tile=tile, num_rows=N),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((TB, D), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        out_shape=jax.ShapeDtypeStruct((T, nb * tile, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(row_offsets, flat_idx, flat_w, flat_tables)
-    return out.reshape(T, B, D)
+    )(row_offsets.astype(jnp.int32), ids, weights.astype(jnp.float32),
+      flat_tables)
+    return out[:, :B]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "d_block"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_pool_tbe_pallas(
     tables: jax.Array,    # (T, R, D) stacked tables
     indices: jax.Array,   # (T, B, L) int32 TABLE-LOCAL ids — in [0, R)
     weights: jax.Array,   # (T, B, L) f32 — 0 for masked/padded slots
     *,
     interpret: bool = False,
-    d_block: int | None = None,
 ) -> jax.Array:
     """Fused pooled lookup over all tables, ONE ``pallas_call``.
 
-    ``out[t, b] = sum_l weights[t,b,l] * tables[t, indices[t,b,l]]``
-
-    The uniform-rows special case of :func:`gather_pool_tbe_flat_pallas`:
-    the stacked ``(T, R, D)`` tables are one ``(T*R, D)`` flat row space
-    with ``row_offsets[t] = t * R``.
-
-    Returns (T, B, D) f32 (accumulation dtype; callers cast). See the
-    module docstring for the flattened-row-space / offset / grid design.
+    ``out[t, b] = sum_l weights[t,b,l] * tables[t, indices[t,b,l]]`` —
+    the stacked tables are one ``(T*R, D)`` flat row space with
+    ``row_offsets[t] = t * R``. Returns (T, B, D) f32.
     """
     T, R, D = tables.shape
     Ti = indices.shape[0]
@@ -238,4 +195,20 @@ def gather_pool_tbe_pallas(
     return gather_pool_tbe_flat_pallas(
         tables.reshape(T * R, D),
         jnp.arange(T, dtype=jnp.int32) * R,
-        indices, weights, interpret=interpret, d_block=d_block)
+        indices, weights, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gather_pool_pallas(
+    table: jax.Array,     # (R, D)
+    indices: jax.Array,   # (B, L) int32 — must be in [0, R)
+    weights: jax.Array,   # (B, L) f32 — 0 for masked/padded slots
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Single-table pooled lookup, the ``T = 1`` call of the TBE kernel:
+    ``out[b] = sum_l weights[b,l] * table[indices[b,l]]`` -> (B, D) f32.
+    """
+    return gather_pool_tbe_flat_pallas(
+        table, jnp.zeros((1,), jnp.int32), indices[None], weights[None],
+        interpret=interpret)[0]

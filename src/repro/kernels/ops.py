@@ -11,9 +11,13 @@ the framework. ``mode`` selects:
     (correctness validation path used by the test suite).
   * "auto"      — "pallas" on TPU backends, else "reference".
 
+The TPU kernel compiles for (N, 128) tables of a 32-bit dtype (see
+embedding_gather.py); "pallas" raises for other shapes rather than
+substituting the reference.
+
 The batched ops additionally take ``fused``: True (default) runs the
 table-batched TBE kernel — ONE ``pallas_call`` for all tables; False
-falls back to vmapping the single-table kernel (T separate launches),
+falls back to calling the single-table kernel once per table (T launches),
 kept as the A/B baseline for the benchmark sweep.
 
 The Pallas forwards are wrapped in ``custom_vjp``s whose backward is the
@@ -42,6 +46,18 @@ def _resolve_mode(mode: str) -> str:
     if mode != "auto":
         return mode
     return "pallas" if jax.default_backend() == "tpu" else "reference"
+
+
+def onesided_interpret(mode: str) -> bool:
+    """How the one-sided RDMA collectives run under kernel ``mode``:
+    compiled (False) or in the Pallas interpreter (True).  They have no
+    jnp reference, so a mode that resolves to "reference" raises."""
+    mode = _resolve_mode(mode)
+    if mode not in ("pallas", "interpret"):
+        raise ValueError(
+            f"the one-sided backend runs Pallas RDMA kernels: kernel_mode "
+            f"must be 'pallas' (TPU) or 'interpret' (CPU), got {mode!r}")
+    return mode == "interpret"
 
 
 def _effective_weights(indices, lengths, weights):
@@ -162,10 +178,12 @@ _pooled_lookup_tbe_flat.defvjp(_tbe_flat_fwd, _tbe_flat_bwd)
 
 
 def _pooled_lookup_per_table(tables, indices, eff_w, interpret):
-    """Unfused baseline: vmap the single-table kernel (T launches)."""
-    return jax.vmap(
-        lambda t, i, w: _pooled_lookup_pallas(t, i, w, interpret)
-    )(tables, indices, eff_w)
+    """Unfused baseline: one single-table kernel call per table (T
+    launches). Not a vmap: the TPU lowering cannot batch a kernel whose
+    table stays in HBM (``memory_space=ANY``)."""
+    return jnp.stack([
+        _pooled_lookup_pallas(tables[t], indices[t], eff_w[t], interpret)
+        for t in range(tables.shape[0])])
 
 
 # --- public API --------------------------------------------------------------
@@ -239,7 +257,7 @@ def embedding_bag_batched(
     """Pooled lookup over ALL tables, ``(T,R,D) x (T,B,L) -> (T,B,D)``.
 
     ``fused=True`` executes one TBE ``pallas_call`` for every table;
-    ``fused=False`` vmaps the single-table kernel (T launches).
+    ``fused=False`` calls the single-table kernel per table (T launches).
     """
     mode = _resolve_mode(mode)
     if mode == "reference":

@@ -19,7 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.utils.compat import axis_size
+from jax.lax import axis_size
 
 from repro.configs.base import ModelConfig
 from repro.core import routing

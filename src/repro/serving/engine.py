@@ -198,6 +198,17 @@ class CTRRequest:
     lengths: np.ndarray        # (T,) valid lookups per table
 
 
+def make_scorer(cfg: DLRMConfig, ctx: Optional[ParallelContext] = None):
+    """The engines' jitted scoring program: ``(params, dense (B, F),
+    batch) -> pCTR (B,)``.  ``params["tables"]`` is the stacked
+    ``(T, R, D)`` tables, or the tiered store's flat ``(sum S_t, D)``
+    slot pool with slot ids in ``batch``."""
+    def fwd(p, dense, batch):
+        return jax.nn.sigmoid(dlrm_mod.forward(p, dense, batch, cfg, ctx))
+
+    return jax.jit(fwd)
+
+
 class DLRMEngine:
     """Micro-batching CTR inference over the DLRM forward.
 
@@ -305,11 +316,7 @@ class DLRMEngine:
                 self._cache_counter_state = \
                     self.cache.stats.counter_state()
 
-        def fwd(p, dense, batch):
-            return jax.nn.sigmoid(
-                dlrm_mod.forward(p, dense, batch, cfg, ctx))
-
-        self._fwd = jax.jit(fwd)
+        self._fwd = make_scorer(cfg, ctx)
 
     def _make_cache(self, tables, ebcfg):
         """Tiered-store construction hook — the pipelined engine swaps in

@@ -1,4 +1,3 @@
-from repro.utils.compat import shard_map  # noqa: F401
 from repro.utils.tree import (  # noqa: F401
     tree_bytes,
     tree_count,

@@ -13,7 +13,7 @@ import sys
 import numpy as np
 import jax
 import jax.numpy as jnp
-from repro.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
@@ -26,6 +26,7 @@ from repro.core.embedding_bag import (
 from repro.core.jagged import JaggedBatch, random_jagged_batch
 from repro.core.parallel import make_context
 from repro.launch import specs as S
+from repro.launch.mesh import auto_mesh
 from repro.models import decode as dec
 from repro.models import dlrm as dlrm_mod
 from repro.models import lm
@@ -48,7 +49,7 @@ def check(name, fn):
 
 # ---------------------------------------------------------------------------
 def embedding_shardings():
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     rng = np.random.default_rng(0)
     R, D, B, L = 64, 16, 16, 4
     for sharding, rw_impl, backend in [
@@ -85,28 +86,37 @@ def embedding_shardings():
 
 
 def onesided_backend_end_to_end():
-    """backend="onesided" (Pallas RDMA, interpret) == bulk == local."""
-    comm.set_onesided_mode("interpret")
-    try:
-        mesh = jax.make_mesh((8,), ("model",))
-        rng = np.random.default_rng(1)
+    """backend="onesided" (Pallas RDMA, interpret) == local, for both RW
+    impls; a one-sided request under the jnp reference mode raises."""
+    mesh = auto_mesh((8,), ("model",))
+    rng = np.random.default_rng(1)
+    batch = random_jagged_batch(rng, 4, 16, 4, 64)
+    for impl in ("a2a", "allgather"):
         cfg = EmbeddingBagConfig(
             num_tables=4, rows_per_table=64, dim=16, sharding="row",
-            rw_impl="a2a", rw_backend="onesided", capacity_factor=8.0)
+            rw_impl=impl, rw_backend="onesided", capacity_factor=8.0,
+            kernel_mode="interpret")
         tables = init_tables(jax.random.key(0), cfg)
-        batch = random_jagged_batch(rng, 4, 16, 4, 64)
         ref = pooled_lookup_local(tables, batch, cfg)
         out = jax.jit(shard_map(
             lambda t, b: pooled_lookup_sharded(t, b, cfg),
             mesh=mesh, in_specs=(table_pspec(cfg), P()), out_specs=P(),
             check_vma=False))(tables, batch)
-        assert float(jnp.abs(out - ref).max()) < 1e-4
-    finally:
-        comm.set_onesided_mode("off")
+        assert float(jnp.abs(out - ref).max()) < 1e-4, impl
+    ref_cfg = dataclasses.replace(cfg, kernel_mode="reference")
+    try:
+        jax.jit(shard_map(
+            lambda t, b: pooled_lookup_sharded(t, b, ref_cfg),
+            mesh=mesh, in_specs=(table_pspec(cfg), P()), out_specs=P(),
+            check_vma=False)).lower(tables, batch)
+    except ValueError as e:
+        assert "one-sided" in str(e), e
+    else:
+        raise AssertionError("onesided under kernel_mode='reference' ran")
 
 
 def comm_instrumentation():
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     with comm.instrument() as events:
         x = jnp.zeros((64, 4))          # per-shard (8, 4): split dim == 8
         jax.jit(shard_map(
@@ -120,7 +130,7 @@ def comm_instrumentation():
 
 
 def arch_train_and_decode(arch):
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ctx = make_context(mesh)
     tc = TrainConfig(remat=True, optimizer_state_dtype="int8")
     cfg = dataclasses.replace(configs.get_smoke_config(arch),
@@ -182,7 +192,7 @@ def arch_train_and_decode(arch):
 def beyond_paper_embedding():
     """bf16 reduce-scatter + hot-row replication on the real 8-dev mesh."""
     from repro.core.embedding_bag import extract_hot_table, pooled_lookup_hot
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     rng = np.random.default_rng(3)
     R, T, B, L = 256, 4, 16, 8
     base = EmbeddingBagConfig(num_tables=T, rows_per_table=R, dim=16,
@@ -213,7 +223,7 @@ def beyond_paper_embedding():
 
 
 def dlrm_distributed():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ctx = make_context(mesh)
     cfg = dataclasses.replace(dlrm_cfg_mod.smoke(), rows_per_table=128)
     params = dlrm_mod.init_params(jax.random.key(0), cfg)
@@ -239,12 +249,12 @@ def elastic_reshard():
     batch = {"tokens": jax.random.randint(rng, (8, 16), 0, cfg.vocab_size),
              "labels": jax.random.randint(rng, (8, 16), 0, cfg.vocab_size)}
     state = init_train_state(rng, cfg, tc, tp_size=2, dtype=jnp.float32)
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = auto_mesh((4, 2), ("data", "model"))
     ctx_a = make_context(mesh_a)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(state, d, 0)
         # new topology: tp=2 kept (vocab padding depends on it), dp reshaped
-        mesh_b = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh_b = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         ctx_b = make_context(mesh_b)
         pspecs = S.param_spec_tree(state["params"], cfg, ctx_b)
         sh = {"params": jax.tree.map(ctx_b.sharding, pspecs),

@@ -28,7 +28,7 @@ from repro.core.embedding_bag import (
     EmbeddingBagConfig, init_tables, make_cache, pooled_lookup_local,
 )
 from repro.core.jagged import JaggedBatch, random_jagged_batch
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 failures = []
 
@@ -48,11 +48,13 @@ E = 4  # forced device count
 
 
 def _fetch_via(backend, shards, addr, owner, axis="hosts"):
-    """Run comm.fetch_rows over the 4-device mesh; requests replicated."""
+    """Run comm.fetch_rows over the 4-device mesh; requests replicated
+    (a one-sided fetch runs its kernel in the Pallas interpreter)."""
     mesh = Mesh(np.asarray(jax.devices()), (axis,))
 
     def inner(shard, a, o):
-        return comm.fetch_rows(shard[0], a, o, axis, backend=backend)
+        return comm.fetch_rows(shard[0], a, o, axis, backend=backend,
+                               interpret=backend == "onesided")
 
     return np.asarray(jax.jit(shard_map(
         inner, mesh=mesh, in_specs=(P(axis), P(), P()), out_specs=P(),
@@ -70,11 +72,7 @@ def fetch_rows_onesided_vs_lax():
     want = shards[owner, local]                      # numpy oracle
     got_bulk = _fetch_via("bulk", shards, local, owner)
     np.testing.assert_array_equal(got_bulk, want)
-    comm.set_onesided_mode("interpret")
-    try:
-        got_os = _fetch_via("onesided", shards, local, owner)
-    finally:
-        comm.set_onesided_mode("off")
+    got_os = _fetch_via("onesided", shards, local, owner)
     np.testing.assert_array_equal(got_os, want)
 
 
@@ -181,8 +179,8 @@ def remote_lookup_bitwise_onesided():
                     kernel_mode="interpret"),
         batch_kw=dict(batch_size=4, pooling=3))
     assert cache.stats.misses_remote > 0
-    # the store threads its mode per-call, never via the global gate
-    assert comm._ONESIDED_MODE == "off"
+    # the kernel mode reaches the store from the config, never a global
+    assert cache.cold.backend == "onesided"
 
 
 def tier_churn_promotion_demotion():
@@ -265,6 +263,23 @@ def engine_remote_cold_tier():
     assert s.misses_remote > 0 and s.bytes_remote > 0
 
 
+def remote_store_freed_on_drop():
+    """A dropped RemoteStore frees its device shards at once: no
+    reference cycle waits for the garbage collector."""
+    import gc
+    import weakref
+
+    gc.disable()
+    try:
+        store = RemoteStore(np.zeros((2, 8, 4), np.float32), hosts=E)
+        store.fetch(np.zeros(3), np.arange(3))
+        ref = weakref.ref(store)
+        del store
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
 def run_all():
     check("fetch_rows_onesided_vs_lax", fetch_rows_onesided_vs_lax)
     check("fetch_rows_instrumented", fetch_rows_instrumented)
@@ -273,6 +288,7 @@ def run_all():
     check("remote_lookup_bitwise_onesided", remote_lookup_bitwise_onesided)
     check("tier_churn_promotion_demotion", tier_churn_promotion_demotion)
     check("engine_remote_cold_tier", engine_remote_cold_tier)
+    check("remote_store_freed_on_drop", remote_store_freed_on_drop)
 
     if failures:
         print("FAILURES:", failures)

@@ -90,7 +90,7 @@ def test_injected_second_launch_is_caught():
 
 
 def test_forbidden_collective_is_caught():
-    from repro.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()), ("x",))
@@ -101,11 +101,26 @@ def test_forbidden_collective_is_caught():
                             max_pallas_calls=0)
     report = audit(fn, args, strict)
     assert any("psum" in v for v in report.violations)
-    # whitelisting the collective makes the same program pass (jax
-    # 0.4.x traces lax.psum as the "psum2" primitive)
-    allowed = dataclasses.replace(strict,
-                                  allowed_collectives=("psum", "psum2"))
+    # whitelisting the collective makes the same program pass (a checked
+    # shard_map traces lax.psum as the "psum_invariant" primitive)
+    allowed = dataclasses.replace(
+        strict, allowed_collectives=("psum", "psum_invariant"))
     audit(fn, args, allowed).raise_if_failed()
+
+
+def test_collective_set_covers_installed_jax():
+    """Every primitive ``jax._src.lax.parallel`` defines is either a
+    collective the auditor counts or listed as moving no data."""
+    from jax._src import core
+    from jax._src.lax import parallel
+
+    from repro.analysis.contracts import (COLLECTIVE_PRIMITIVES,
+                                          NON_COLLECTIVE_PARALLEL)
+
+    defined = {v.name for v in vars(parallel).values()
+               if isinstance(v, core.Primitive)}
+    assert defined - NON_COLLECTIVE_PARALLEL <= COLLECTIVE_PRIMITIVES
+    assert NON_COLLECTIVE_PARALLEL <= defined
 
 
 def test_dropped_donation_is_caught():
