@@ -34,7 +34,6 @@ whichever tier served it).
 from __future__ import annotations
 
 import itertools
-import time
 from typing import Optional
 
 import jax
@@ -48,6 +47,7 @@ from repro.core.cache_config import CacheConfig
 from repro.core.embedding_bag import EmbeddingBagConfig
 from repro.core.jagged import JaggedBatch
 from repro.kernels import ops as kops
+from repro.obs.trace import span
 
 
 def _valid_mask(indices: np.ndarray, lengths: Optional[np.ndarray]):
@@ -163,30 +163,30 @@ class CachedEmbeddingBag:
         committed residency for the fetched rows, so any error between
         the cold fetch and the pool scatter rolls that back
         (``invalidate_fetch``) — no slot ever claims an uncopied row."""
-        t0 = time.perf_counter()
-        scatter_s = 0.0
         if plan.fetch_rows.size:
-            try:
-                rows = self.cold.fetch(plan.fetch_tables, plan.fetch_rows)
-                ts = time.perf_counter()
-                self.hot.scatter(plan.flat_addr(self.mgr.slot_offsets), rows)
-                scatter_s = time.perf_counter() - ts
-            except BaseException:
-                self.mgr.invalidate_fetch(plan)
-                raise
+            args = None
             if self.tracer is not None:
                 # one seq per prefetch: the tracer groups this pair into
                 # one calibration sample (Tracer.stage_samples)
                 args = {"seq": next(_PREFETCH_SEQ),
-                        "bytes": int(rows.nbytes), "tier": self.cold.tier}
-                self.tracer.add_span("cache.fetch", t0, ts, lane="cache",
-                                     cat="cache", args=args)
-                self.tracer.add_span("cache.scatter", ts, ts + scatter_s,
-                                     lane="cache", cat="cache",
-                                     args=dict(args))
-        self.stats.add_time("prefetch",
-                            time.perf_counter() - t0 - scatter_s)
-        self.stats.add_time("scatter", scatter_s)
+                        "bytes": int(plan.fetch_rows.size) * self.row_bytes,
+                        "tier": self.cold.tier}
+            try:
+                with span("repro.cache.fetch", self.tracer, "cache.fetch",
+                          lane="cache", cat="cache", args=args) as fetch:
+                    rows = self.cold.fetch(plan.fetch_tables,
+                                           plan.fetch_rows)
+                with span("repro.cache.scatter", self.tracer,
+                          "cache.scatter", lane="cache", cat="cache",
+                          args=None if args is None else dict(args)) \
+                        as scatter:
+                    self.hot.scatter(plan.flat_addr(self.mgr.slot_offsets),
+                                     rows)
+            except BaseException:
+                self.mgr.invalidate_fetch(plan)
+                raise
+            self.stats.add_time("prefetch", fetch.seconds)
+            self.stats.add_time("scatter", scatter.seconds)
         self.stats.update(**plan.stats_kwargs(self.row_bytes),
                           count_batch=count_batch)
 
@@ -201,14 +201,11 @@ class CachedEmbeddingBag:
         returns the slot-remapped indices.  ``lengths`` None means every
         slot valid.
         """
-        t0 = time.perf_counter()
-        plan = self.mgr.prepare(*_valid_mask(indices, lengths))
-        t1 = time.perf_counter()
-        self.stats.add_time("prefetch", t1 - t0)
-        if self.tracer is not None:
-            self.tracer.add_span("cache.admit", t0, t1, lane="cache",
-                                 cat="cache",
-                                 args={"tier": self.cold.tier})
+        with span("repro.cache.admit", self.tracer, "cache.admit",
+                  lane="cache", cat="cache",
+                  args={"tier": self.cold.tier}) as admit:
+            plan = self.mgr.prepare(*_valid_mask(indices, lengths))
+        self.stats.add_time("prefetch", admit.seconds)
         self._apply_fetch(plan, count_batch=True)
         return plan.remapped
 

@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import abc
 import functools
-import time
 import warnings
 from typing import Optional
 
@@ -43,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import comm
+from repro.obs.trace import span
 from jax import shard_map
 
 
@@ -272,11 +272,13 @@ class RemoteStore(TableStore):
                  + row_ids % self._rows_per_host).astype(np.int32)
         m = local.shape[0]
         local, owner = _pad_pow2([local, owner])
-        t0 = time.perf_counter()
-        out = self._fetch(self.shards, jnp.asarray(local), jnp.asarray(owner))
-        # device->host roundtrip: the payloads land on the serving host
-        # (modeling NIC -> host RAM) before the pool scatter moves them h2d
-        result = np.asarray(out)[:m]
+        with span("repro.comm.fetch_rows") as wire:
+            out = self._fetch(self.shards, jnp.asarray(local),
+                              jnp.asarray(owner))
+            # device->host roundtrip: the payloads land on the serving
+            # host (modeling NIC -> host RAM) before the pool scatter
+            # moves them h2d
+            result = np.asarray(out)[:m]
         # compiled programs never re-trace, so comm._record's trace-time
         # event carries no per-execution wall clock — record the measured
         # dispatch->materialize interval with the stacked payload bytes
@@ -286,7 +288,7 @@ class RemoteStore(TableStore):
             "fetch_rows",
             self.hosts * local.shape[0] * self.shards.shape[-1]
             * self.shards.dtype.itemsize,
-            self.hosts, self.backend, t0, time.perf_counter())
+            self.hosts, self.backend, wire.t0, wire.t1)
         return result
 
 

@@ -12,6 +12,11 @@ One bundle (:class:`Telemetry`) carries the two halves of observability:
     timeline, exportable as Chrome trace-event / Perfetto JSON and
     projectable onto ``perf_model.calibrate``'s ``StageSample`` inputs.
 
+Every serving stage is timed by one :class:`~repro.obs.trace.span` call,
+which also writes it into a ``jax.profiler`` trace as a
+``repro.<layer>.<stage>`` annotation, so the same stages sit beside the
+device's ops on the profiler's clock, with or without a ``Telemetry``.
+
 Wiring: pass ``telemetry=Telemetry()`` to
 :func:`repro.serving.engine.make_dlrm_engine` (either engine class).
 The engine stamps request enqueue times at ``submit``, records
@@ -33,14 +38,16 @@ from repro.obs.slo import (DriftDetector, SLOEvent, SLOMonitor, SLOPolicy,
                            expected_hit_rates)
 from repro.obs.timeseries import (EwmaSeries, RollingCounter,
                                   WindowedHistogram)
-from repro.obs.trace import LANES, Span, Tracer, validate_chrome_trace
+from repro.obs.trace import (LANES, Span, Tracer, profiled_spans, span,
+                             validate_chrome_trace)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "LogBuckets", "MetricsRegistry",
     "WindowedHistogram", "RollingCounter", "EwmaSeries",
     "SLOPolicy", "SLOEvent", "SLOMonitor", "DriftDetector",
     "expected_hit_rates",
-    "LANES", "Span", "Tracer", "validate_chrome_trace",
+    "LANES", "Span", "Tracer", "span", "profiled_spans",
+    "validate_chrome_trace",
     "SweepReport", "provenance", "write_snapshot", "Telemetry",
 ]
 

@@ -1,4 +1,5 @@
-"""``Tracer`` — one merged timeline, exportable as Chrome trace-event JSON.
+"""``Tracer`` — one merged timeline, exportable as Chrome trace-event JSON,
+and :class:`span`, the one call that times every serving stage.
 
 Before this module the repro had three disjoint timing fragments:
 ``CacheStats`` stage timers, the pipeline's ``StageSpan`` list, and
@@ -9,14 +10,23 @@ exports the result in the Chrome trace-event format, so a serving run
 opens directly in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``.
 
+Every stage boundary of the serving path is ONE :class:`span` call,
+opened where the work runs, on the thread that runs it.  It enters a
+``jax.profiler.TraceAnnotation`` under the stage's profiler name
+(``repro.<layer>.<stage>``), so a ``jax.profiler`` trace shows the
+program's stages beside the device's ops on one clock; lands the
+interval on an attached tracer's lane under its in-memory name; and
+keeps its own ``t0``/``t1``, which ``CacheStats``, ``PipelineTrace`` and
+the comm events read instead of timing the interval again.
+
 Lanes (Chrome ``tid``s inside one ``pid``):
 
   ======== ===========================================================
   lane     what lands there
   ======== ===========================================================
   engine   ``DLRMEngine.flush`` prefetch/forward spans
-  pipeline mirrored ``PipelineTrace`` stage spans (admit/fetch/scatter/
-           forward/swap) from the pipelined engine's scheduler
+  pipeline the pipelined engine's stage spans (admit/fetch/scatter/
+           forward/swap), one per ``PipelineTrace`` ``StageSpan``
   request  per-request enqueue -> score latency spans
   cache    ``CachedEmbeddingBag`` admit/fetch/scatter spans (bytes in
            ``args``)
@@ -41,12 +51,13 @@ threads and the main serving thread interleave safely on one timeline.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # lane name -> Chrome tid; the export emits one thread_name metadata
 # record per lane so Perfetto labels the rows
@@ -74,6 +85,98 @@ class Span:
     @property
     def seconds(self) -> float:
         return self.t1 - self.t0
+
+
+# one entry per ``jax.profiler`` session this process has seen, in order:
+# profiler name -> [seconds, count] of the spans that opened and closed
+# inside it.  Process-wide, as the profiler is: it lets a reader of one
+# traced window total the stages without parsing the trace.  Spans close
+# on the main and the prefetch threads alike.
+_SESSIONS: List[Dict[str, List[float]]] = []
+_RECORDING = [False]     # whether the last span found the profiler on
+_PROFILED_LOCK = threading.Lock()
+
+
+def _session() -> int:
+    """Index in ``_SESSIONS`` of the profiler session recording now, or
+    -1.  A span that finds the profiler on after one found it off opens
+    the next session; two sessions with no span between them count as
+    one."""
+    if not TraceAnnotation.is_enabled():
+        _RECORDING[0] = False
+        return -1
+    if not _RECORDING[0]:
+        with _PROFILED_LOCK:
+            if not _RECORDING[0]:
+                _SESSIONS.append({})
+                _RECORDING[0] = True
+    return len(_SESSIONS) - 1
+
+
+class span:
+    """One stage interval, timed once, where the work runs.
+
+    ``with span("repro.cache.admit", tracer, "cache.admit", lane="cache")
+    as s:`` does three things:
+
+      * enters ``jax.profiler.TraceAnnotation(name)``, which a running
+        profiler records on the host plane of the thread that opened it;
+      * on exit, lands the interval on ``tracer`` (a :class:`Tracer`, or
+        None) under ``label`` (default ``name``) with ``lane``/``cat``/
+        ``args``, as :meth:`Tracer.add_span` does — also when the body
+        raised, since the time was spent;
+      * exposes ``t0``/``t1`` (``perf_counter`` stamps) and ``seconds``
+        after exit, for the stage timers that read them.
+
+    ``name`` is the profiler's ``repro.<layer>.<stage>``; ``label`` the
+    in-memory lane name (``cache.admit``, ``pipeline.fetch``, ...).
+    """
+
+    __slots__ = ("name", "tracer", "label", "lane", "cat", "args", "t0",
+                 "t1", "_annotation", "_session")
+
+    def __init__(self, name: str, tracer: Optional["Tracer"] = None,
+                 label: Optional[str] = None, *, lane: str = "engine",
+                 cat: str = "", args: Optional[Dict[str, object]] = None):
+        self.name, self.tracer, self.label = name, tracer, label
+        self.lane, self.cat, self.args = lane, cat, args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "span":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._session = _session()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        session = _session()
+        if session >= 0 and session == self._session:
+            with _PROFILED_LOCK:
+                acc = _SESSIONS[session].setdefault(self.name, [0.0, 0])
+                acc[0] += self.t1 - self.t0
+                acc[1] += 1
+        if self.tracer is not None:
+            self.tracer.add_span(self.label or self.name, self.t0, self.t1,
+                                 lane=self.lane, cat=self.cat,
+                                 args=self.args)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+def profiled_spans() -> List[Dict[str, Tuple[float, int]]]:
+    """For each ``jax.profiler`` session of this process, in order, the
+    seconds and count per profiler name of the :class:`span` calls that
+    opened and closed while it recorded: the program's stages of each
+    traced window, totalled."""
+    with _PROFILED_LOCK:
+        return [{name: (acc[0], int(acc[1])) for name, acc in totals.items()}
+                for totals in _SESSIONS]
 
 
 def validate_chrome_trace(obj) -> int:
@@ -105,9 +208,10 @@ def validate_chrome_trace(obj) -> int:
 class Tracer:
     """Process-wide span recorder on the ``perf_counter`` clock.
 
-    ``enabled=False`` turns every record call into a no-op (the engines
-    construct spans only when a tracer is attached, so disabled tracing
-    costs one attribute check per call site).
+    ``enabled=False`` turns every record call into a no-op.  The
+    engines' :class:`span` calls run whether or not a tracer is
+    attached (the profiler sees them); without one they land nothing
+    here.
     """
 
     def __init__(self, *, enabled: bool = True, pid: int = 0):
@@ -136,18 +240,11 @@ class Tracer:
         with self._lock:
             self._spans.append(Span(name, t0, t1, lane, cat, args))
 
-    @contextlib.contextmanager
     def span(self, name: str, *, lane: str = "engine", cat: str = "",
-             args: Optional[Dict[str, object]] = None) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add_span(name, t0, time.perf_counter(), lane=lane,
-                          cat=cat, args=args)
+             args: Optional[Dict[str, object]] = None) -> span:
+        """A :class:`span` named ``name`` on the profiler and on this
+        timeline."""
+        return span(name, self, lane=lane, cat=cat, args=args)
 
     def add_collective_event(self, ev, *, name: Optional[str] = None) -> bool:
         """Land one :class:`repro.core.comm.CollectiveEvent` on the comm
@@ -283,19 +380,3 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
         return path
-
-    def add_pipeline_trace(self, trace, *, label: str = "pipeline",
-                           since: float = 0.0) -> int:
-        """Mirror an un-attached :class:`repro.pipeline.PipelineTrace`'s
-        spans onto the pipeline lane (engines attach the tracer at
-        construction instead — this is the offline path); returns the
-        number of spans added."""
-        n = 0
-        for s in trace.spans:
-            if s.start < since:
-                continue
-            self.add_span(f"pipeline.{s.stage}", s.start, s.end,
-                          lane="pipeline", cat="pipeline",
-                          args={"engine": label, "batch": s.batch})
-            n += 1
-        return n

@@ -29,11 +29,15 @@ shadow buffer:
   swap     rotate the ring (``DoubleBufferedSlotPool.swap``) — the
            prepared epoch is published.
 
-Overlap is OBSERVED, not assumed: every stage records a wall-clock
-:class:`StageSpan` into a :class:`PipelineTrace`; ``overlap_s`` is the
-measured intersection of prefetch-side spans (admit/fetch) with open
-forward spans, and is pushed into the shared ``CacheStats`` so the
-serialized and pipelined engines report comparable numbers.
+Overlap is OBSERVED, not assumed: every stage is timed by one
+``repro.obs.span`` call on the thread that runs it (so a ``jax.profiler``
+trace shows it as ``repro.cache.admit`` / ``fetch`` / ``scatter``,
+``repro.pipeline.prestage`` / ``swap``, ``repro.engine.dispatch`` /
+``collect``), and its stamps become a :class:`StageSpan` in a
+:class:`PipelineTrace`; ``overlap_s`` is the measured intersection of
+prefetch-side spans (admit/fetch) with open forward spans, and is pushed
+into the shared ``CacheStats`` so the serialized and pipelined engines
+report comparable numbers.
 
 Head-of-line behavior: a micro-batch whose working set overflows the
 shadow buffer (``CacheCapacityError`` from admit — atomic, nothing to
@@ -45,13 +49,14 @@ re-raised after the in-flight batch's scores are safely materialized.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.cache.manager import CacheCapacityError
+from repro.obs.trace import span
 from repro.pipeline.double_buffer import DoubleBufferedSlotPool
 
 STAGES = ("admit", "fetch", "scatter", "forward", "swap")
@@ -71,14 +76,47 @@ class StageSpan:
         return self.end - self.start
 
 
+def _overlap(spans: Sequence[StageSpan]) -> float:
+    """Prefetch-side wall-clock (admit + fetch spans) of ``spans`` that
+    lies inside one of their forward spans."""
+    fwd = [(s.start, s.end) for s in spans if s.stage == "forward"]
+    out = 0.0
+    for s in spans:
+        if s.stage not in ("admit", "fetch"):
+            continue
+        for f0, f1 in fwd:
+            out += max(0.0, min(s.end, f1) - max(s.start, f0))
+    return out
+
+
+class _StageSpanCall(span):
+    """A :class:`repro.obs.span` that hands its stamps to ``keep`` when
+    its body returns."""
+
+    __slots__ = ("_keep",)
+
+    def __init__(self, *args, keep=None, **kw):
+        super().__init__(*args, **kw)
+        self._keep = keep
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        if self._keep is not None and exc[0] is None:
+            self._keep(self.t0, self.t1)
+        return False
+
+
 class PipelineTrace:
     """Recorded stage spans — the pipeline's observability surface.
 
-    With a ``tracer`` (:class:`repro.obs.Tracer`, duck-typed: anything
-    with ``add_span``) every recorded span is ALSO mirrored onto the
-    unified timeline's pipeline lane as ``pipeline.<stage>``, tagged
-    with the owning engine's ``label`` — one merged view across the
-    serialized and pipelined engines.
+    Each stage is timed once, by the :class:`repro.obs.span` call that
+    :meth:`stage` makes, which keeps its stamps as a :class:`StageSpan`
+    (:meth:`record`).  With a ``tracer`` (:class:`repro.obs.Tracer`)
+    that call also lands the stage on the unified timeline's pipeline
+    lane as ``pipeline.<stage>``, tagged with the owning engine's
+    ``label`` — one merged view across the serialized and pipelined
+    engines.  The forward, built from other spans' stamps, lands there
+    through :meth:`derived`.
 
     With a ``metrics`` registry (:class:`repro.obs.MetricsRegistry`)
     every span additionally feeds a per-stage WINDOWED histogram
@@ -94,20 +132,49 @@ class PipelineTrace:
         self.label = label
         self.metrics = metrics
         self.window = window
+        self._run_start = 0          # first span of the run still open
+        self._closed_overlap = 0.0   # overlap of every closed run
+
+    @staticmethod
+    def _check(stage: str) -> None:
+        if stage not in STAGES:
+            raise ValueError(f"unknown stage {stage!r}; one of {STAGES}")
+
+    def _lane_args(self, batch: int) -> Dict[str, object]:
+        return {"engine": self.label, "batch": batch}
+
+    def stage(self, stage: str, batch: int, name: str, *,
+              keep: bool = True) -> "_StageSpanCall":
+        """The span call that times ``stage`` of micro-batch ``batch``:
+        ``name`` on the profiler, ``pipeline.<stage>`` on the tracer.
+        With ``keep`` it keeps its stamps (:meth:`record`) when its body
+        returns; a worker thread passes ``keep=False`` and the thread that
+        owns the trace records the closed span's stamps after joining."""
+        self._check(stage)
+        return _StageSpanCall(
+            name, self.tracer, f"pipeline.{stage}", lane="pipeline",
+            cat="pipeline", args=self._lane_args(batch),
+            keep=functools.partial(self.record, stage, batch) if keep
+            else None)
 
     def record(self, stage: str, batch: int, start: float,
                end: float) -> None:
-        if stage not in STAGES:
-            raise ValueError(f"unknown stage {stage!r}; one of {STAGES}")
+        self._check(stage)
         self.spans.append(StageSpan(stage, batch, start, end))
-        if self.tracer is not None:
-            self.tracer.add_span(
-                f"pipeline.{stage}", start, end, lane="pipeline",
-                cat="pipeline", args={"engine": self.label, "batch": batch})
         if self.metrics is not None:
             self.metrics.windowed_histogram(
                 f"{self.label}.stage.{stage}_s", unit="s",
                 window=self.window).observe(max(0.0, end - start))
+
+    def derived(self, stage: str, batch: int, start: float,
+                end: float) -> None:
+        """Record an interval built from other spans' stamps, which no
+        span call times, and land it on the tracer's pipeline lane."""
+        self.record(stage, batch, start, end)
+        if self.tracer is not None:
+            self.tracer.add_span(f"pipeline.{stage}", start, end,
+                                 lane="pipeline", cat="pipeline",
+                                 args=self._lane_args(batch))
 
     def by_stage(self, stage: str) -> List[StageSpan]:
         return [s for s in self.spans if s.stage == stage]
@@ -118,14 +185,17 @@ class PipelineTrace:
     def overlap_s(self) -> float:
         """Prefetch-side wall-clock (admit + fetch spans) that lies
         inside a forward span — the measured hidden latency."""
-        fwd = [(s.start, s.end) for s in self.by_stage("forward")]
-        out = 0.0
-        for s in self.spans:
-            if s.stage not in ("admit", "fetch"):
-                continue
-            for f0, f1 in fwd:
-                out += max(0.0, min(s.end, f1) - max(s.start, f0))
-        return out
+        return self._closed_overlap + _overlap(self.spans[self._run_start:])
+
+    def close_run(self) -> float:
+        """End one scheduler run: add the overlap of the spans recorded
+        since the last close to the total and return it.  A run drains
+        its in-flight forward before it returns, so no overlap crosses
+        runs, and each close costs only its own run's spans."""
+        run = _overlap(self.spans[self._run_start:])
+        self._closed_overlap += run
+        self._run_start = len(self.spans)
+        return run
 
     def overlap_fraction(self) -> float:
         pre = self.total("admit") + self.total("fetch")
@@ -133,6 +203,8 @@ class PipelineTrace:
 
     def clear(self) -> None:
         self.spans = []
+        self._run_start = 0
+        self._closed_overlap = 0.0
 
 
 class PipelineScheduler:
@@ -167,7 +239,6 @@ class PipelineScheduler:
         self.prestage = prestage
         self.trace = trace if trace is not None else PipelineTrace()
         self._seq = 0                 # global micro-batch counter (spans)
-        self._overlap_reported = 0.0  # overlap already pushed into stats
 
     def run(self, batches: Sequence[Tuple[Any, np.ndarray, np.ndarray]],
             out: Optional[Dict] = None) -> Dict:
@@ -179,6 +250,7 @@ class PipelineScheduler:
         result even when a later stage raises — the engine uses this to
         requeue only the genuinely unscored requests."""
         stats = self.pool.stats
+        trace = self.trace
         if out is None:
             out = {}
         inflight = None     # (payload, device_out, dispatch_t0, batch_id)
@@ -189,25 +261,30 @@ class PipelineScheduler:
             #    thread: every stage touches only the SHADOW buffer (the
             #    in-flight forward reads the live one), so the whole
             #    prefetch pipeline hides under batch k-1's forward...
-            box: Dict[str, Any] = {}
+            box: Dict[str, Any] = {"stages": []}
 
-            def _worker(box=box, payload=payload, indices=indices,
+            def _worker(box=box, k=k, payload=payload, indices=indices,
                         lengths=lengths):
-                stamps = [time.perf_counter()]
+                def stage(name, profiled):
+                    s = trace.stage(name, k, profiled, keep=False)
+                    box["stages"].append((name, s))
+                    return s
+
                 try:
-                    plan = box["plan"] = self.pool.prepare_next(indices,
-                                                                lengths)
-                    stamps.append(time.perf_counter())
-                    rows = self.pool.fetch_next(plan)
-                    stamps.append(time.perf_counter())
-                    self.pool.commit_next(plan, rows)
+                    with stage("admit", "repro.cache.admit"):
+                        plan = box["plan"] = self.pool.prepare_next(indices,
+                                                                    lengths)
+                    with stage("fetch", "repro.cache.fetch"):
+                        rows = self.pool.fetch_next(plan)
+                    with stage("scatter", "repro.cache.scatter"):
+                        self.pool.commit_next(plan, rows)
                     if self.prestage is not None:   # operand staging too
-                        box["staged"] = self.prestage(payload,
-                                                      plan.remapped, lengths)
+                        pre = box["prestage"] = span("repro.pipeline.prestage")
+                        with pre:
+                            box["staged"] = self.prestage(
+                                payload, plan.remapped, lengths)
                 except BaseException as e:  # noqa: BLE001 — rethrown below
                     box["err"] = e
-                stamps.append(time.perf_counter())
-                box["stamps"] = stamps
 
             # one short-lived thread per micro-batch: spawn cost is tens
             # of microseconds against millisecond-scale batches, and a
@@ -220,12 +297,14 @@ class PipelineScheduler:
                 out.update(self._drain(inflight))
                 inflight = None
             th.join()
-            stamps = box["stamps"]
-            for stage, (s0, s1) in zip(("admit", "fetch", "scatter"),
-                                       zip(stamps, stamps[1:])):
-                self.trace.record(stage, k, s0, s1)
-                stats.add_time("scatter" if stage == "scatter"
-                               else "prefetch", s1 - s0)
+            # the worker's spans are closed: keep their stamps here, on
+            # the thread that owns the trace and its histograms
+            for name, s in box["stages"]:
+                trace.record(name, k, s.t0, s.t1)
+                stats.add_time("scatter" if name == "scatter"
+                               else "prefetch", s.seconds)
+            if "prestage" in box:   # operand staging counts as scatter time
+                stats.add_time("scatter", box["prestage"].seconds)
             err = box.get("err")
             if isinstance(err, CacheCapacityError):
                 # head-of-line fallback: the working set overflowed the
@@ -237,28 +316,26 @@ class PipelineScheduler:
                 raise err
             # -- dispatch forward k on the shadow pool, then publish it
             plan = box["plan"]
-            t4 = time.perf_counter()
-            dev = self.forward(payload, plan.remapped, lengths,
-                               self.pool.shadow.pool,
-                               staged=box.get("staged"))
-            t5 = time.perf_counter()
-            self.pool.swap()
-            self.trace.record("swap", k, t5, time.perf_counter())
-            inflight = (payload, dev, t4, k)
+            with span("repro.engine.dispatch") as dispatch:
+                dev = self.forward(payload, plan.remapped, lengths,
+                                   self.pool.shadow.pool,
+                                   staged=box.get("staged"))
+            with trace.stage("swap", k, "repro.pipeline.swap"):
+                self.pool.swap()
+            inflight = (payload, dev, dispatch.t0, k)
         if inflight is not None:
             out.update(self._drain(inflight))
-        # push the measured overlap delta into the shared stats record
-        total = self.trace.overlap_s()
-        stats.add_time("overlap", total - self._overlap_reported)
-        self._overlap_reported = total
+        # the run's measured overlap, into the shared stats record
+        stats.add_time("overlap", trace.close_run())
         return out
 
     def _drain(self, inflight) -> Dict:
         """Materialize the in-flight forward's scores (the only blocking
-        point of the pipeline) and record its span."""
+        point of the pipeline) and record its span: from its dispatch
+        until its scores are on the host."""
         payload, dev, t_dispatch, k = inflight
         host = np.asarray(dev)
-        t_end = time.perf_counter()
-        self.trace.record("forward", k, t_dispatch, t_end)
-        self.pool.stats.add_time("forward", t_end - t_dispatch)
-        return self.collect(payload, host)
+        with span("repro.engine.collect") as collect:
+            self.trace.derived("forward", k, t_dispatch, collect.t0)
+            self.pool.stats.add_time("forward", collect.t0 - t_dispatch)
+            return self.collect(payload, host)

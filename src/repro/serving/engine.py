@@ -36,6 +36,7 @@ from repro.core.parallel import ParallelContext
 from repro.models import decode as dec
 from repro.models import dlrm as dlrm_mod
 from repro.models import lm
+from repro.obs.trace import span
 
 
 def _sample(logits: jax.Array, rng, temperature: float) -> jax.Array:
@@ -246,6 +247,11 @@ class DLRMEngine:
     the engine's ``CacheStats`` joins ``telemetry.metrics`` as the
     ``<obs_name>.cache`` producer.  Default None: zero overhead beyond
     one attribute check per flush.
+
+    With or without ``telemetry``, every stage of ``flush`` is one
+    ``repro.obs.span`` call, so a ``jax.profiler`` trace puts the host's
+    ``repro.engine.pad`` / ``prefetch`` / ``dispatch`` / ``collect``
+    (and the cache's ``repro.cache.*``) beside the device's ops.
     """
 
     OBS_NAME = "dlrm"
@@ -398,8 +404,10 @@ class DLRMEngine:
         if self.cache is not None:
             from repro.cache import CacheCapacityError
 
+        tracer = None if self.telemetry is None else self.telemetry.tracer
         while True:
-            dense, idx, lens = self._pad_batch(todo)
+            with span("repro.engine.pad"):
+                dense, idx, lens = self._pad_batch(todo)
             params = self.params
             if self.cache is not None:
                 # prefetch-at-flush: pin this micro-batch's rows in the
@@ -408,36 +416,38 @@ class DLRMEngine:
                 # the micro-batch instead of stalling the queue head; the
                 # __init__ floor (cache_rows >= pooling) guarantees a
                 # single request always fits.
-                p0 = time.perf_counter()
                 try:
-                    idx = self.cache.prefetch_arrays(idx, lens)
+                    with span("repro.engine.prefetch", tracer,
+                              f"{self.obs_name}.prefetch", cat="engine",
+                              args={"engine": self.obs_name,
+                                    "batch": len(todo)}):
+                        idx = self.cache.prefetch_arrays(idx, lens)
                 except CacheCapacityError:
                     if len(todo) == 1:
                         raise
                     todo = todo[: len(todo) // 2]
                     continue
-                if self.telemetry is not None:
-                    self.telemetry.tracer.add_span(
-                        f"{self.obs_name}.prefetch", p0, time.perf_counter(),
-                        lane="engine", cat="engine",
-                        args={"engine": self.obs_name, "batch": len(todo)})
                 params = {**self.params, "tables": self.cache.pool}
             break
-        batch = JaggedBatch(indices=jnp.asarray(idx),
-                            lengths=jnp.asarray(lens))
-        t0 = time.perf_counter()
-        p = np.asarray(self._fwd(params, jnp.asarray(dense), batch))
-        t1 = time.perf_counter()
-        if self.cache is not None:   # same span the pipeline scheduler logs
-            self.cache.stats.add_time("forward", t1 - t0)
-        if self.telemetry is not None:
-            self.telemetry.tracer.add_span(
-                f"{self.obs_name}.forward", t0, t1, lane="engine",
-                cat="engine",
-                args={"engine": self.obs_name, "batch": len(todo)})
-        self.queue = self.queue[len(todo):]
-        self._record_scored(todo, t1)
-        return {req.rid: float(p[i]) for i, req in enumerate(todo)}
+        with span("repro.engine.dispatch") as dispatch:
+            batch = JaggedBatch(indices=jnp.asarray(idx),
+                                lengths=jnp.asarray(lens))
+            scores = self._fwd(params, jnp.asarray(dense), batch)
+        p = np.asarray(scores)
+        with span("repro.engine.collect") as collect:
+            # the forward: dispatch until the scores are on the host —
+            # the same interval the pipeline scheduler logs
+            t0, t1 = dispatch.t0, collect.t0
+            if self.cache is not None:
+                self.cache.stats.add_time("forward", t1 - t0)
+            if tracer is not None:
+                tracer.add_span(
+                    f"{self.obs_name}.forward", t0, t1, lane="engine",
+                    cat="engine",
+                    args={"engine": self.obs_name, "batch": len(todo)})
+            self.queue = self.queue[len(todo):]
+            self._record_scored(todo, t1)
+            return {req.rid: float(p[i]) for i, req in enumerate(todo)}
 
     def _stamp_dequeue(self, todo) -> None:
         """Stamp each carved request's dequeue time (the queue-wait vs
@@ -646,7 +656,8 @@ class PipelinedDLRMEngine(DLRMEngine):
             self._stamp_dequeue(todo)
             self.queue = self.queue[len(todo):]
             submitted.extend(todo)
-            dense, idx, lens = self._pad_batch(todo)
+            with span("repro.engine.pad"):
+                dense, idx, lens = self._pad_batch(todo)
             batches.append(((todo, dense), idx, lens))
         out: Dict[int, float] = {}
         try:

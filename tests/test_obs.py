@@ -248,17 +248,33 @@ def test_overlap_s_out_of_order_and_interleaved():
 def test_pipeline_trace_mirrors_to_tracer():
     tracer = Tracer()
     tr = PipelineTrace(tracer=tracer, label="eng-a")
-    tr.record("fetch", 7, 1.0, 2.0)
+    with tr.stage("fetch", 7, "repro.cache.fetch") as s_fetch:
+        pass
     with pytest.raises(ValueError, match="unknown stage"):
         tr.record("nope", 0, 0.0, 1.0)
+    with pytest.raises(ValueError, match="unknown stage"):
+        tr.stage("nope", 0, "repro.nope")
+    # the span call landed the lane span and kept its own stamps
     (s,) = tracer.spans(lane="pipeline")
     assert s.name == "pipeline.fetch"
     assert s.args == {"engine": "eng-a", "batch": 7}
-    # the offline path mirrors an unattached trace the same way
-    tracer2 = Tracer()
-    assert tracer2.add_pipeline_trace(tr, label="late") == 1
-    (s2,) = tracer2.spans(lane="pipeline")
-    assert s2.args["engine"] == "late"
+    (st,) = tr.spans
+    assert (s.t0, s.t1) == (st.start, st.end) == (s_fetch.t0, s_fetch.t1)
+    # a worker thread's span keeps nothing; its owner records the stamps
+    with tr.stage("admit", 8, "repro.cache.admit", keep=False) as s_admit:
+        pass
+    assert len(tr.spans) == 1 and len(tracer.spans(lane="pipeline")) == 2
+    tr.record("admit", 8, s_admit.t0, s_admit.t1)
+    # a body that raises lands its lane span but keeps no stage stamps
+    with pytest.raises(RuntimeError):
+        with tr.stage("swap", 8, "repro.pipeline.swap"):
+            raise RuntimeError("boom")
+    assert [x.stage for x in tr.spans] == ["fetch", "admit"]
+    # an interval built from other stamps lands through derived()
+    tr.derived("forward", 7, 2.0, 3.0)
+    fwd = tracer.spans(lane="pipeline", name="pipeline.forward")
+    assert [(f.t0, f.t1) for f in fwd] == [(2.0, 3.0)]
+    assert len(tracer.spans(lane="pipeline")) == len(tr.spans) + 1 == 4
 
 
 # ---------------------------------------------------------------------------
