@@ -24,7 +24,19 @@ Design (what the v5e compiler accepts):
   * The table stays in HBM (``memory_space=pl.ANY``). Each step starts
     ``Bt * L`` single-row DMAs ``table[row_offsets[t] + idx[t, b, l]]``
     into a VMEM scratch laid out ``(L, Bt, D)``, all on one DMA
-    semaphore, then waits for all of them.
+    semaphore, then waits ONCE with a descriptor spanning the whole
+    scratch: the semaphore counts the bytes that landed, so that wait
+    returns only when every row is in.
+  * At serving shapes the scalar core, not HBM, sets the pace: each row
+    costs an SMEM id load, a clamp and a DMA start. The start loop is a
+    ``fori_loop`` over groups of ``g`` samples with a static inner loop
+    over the group's ``g * L`` copies, so the scratch slot ``(l, b)``
+    and the id's SMEM offset ``b * L + l`` need no per-row div or mod.
+    ``g`` follows from the shapes: enough samples that a group starts
+    about ``_COPIES_PER_ITER`` copies (one sample at L=32, 32 at the
+    one-hot L=1), and a divisor of ``Bt``. On a TPU v5e at T=26,
+    B=1024, L=32 this took a row from 35.7 ns (a wait per row, per-row
+    div/mod) to 15.7 ns; HBM's floor is ~0.6 ns.
   * Ids travel per tile: a ``(Bt * L,)`` int32 SMEM block of the
     ``(T, nb, 1, Bt * L)`` id array, pipelined by the grid. Prefetching
     the whole ``(T * B, L)`` id array into SMEM does not fit at serving
@@ -60,6 +72,7 @@ t * R/E``).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _ROW_SCRATCH_BYTES = 4 << 20   # VMEM budget for one step's (L, Bt, D) rows
 _MAX_TILE = 128                # samples per grid step
+_COPIES_PER_ITER = 32          # row DMAs started per iteration of the start loop
 
 
 def _tile(B: int, L: int, D: int, itemsize: int) -> int:
@@ -82,26 +96,24 @@ def _tbe_kernel(off_ref, ids_ref, w_ref, table_hbm, out_ref, rows, sem, *,
                 L: int, tile: int, num_rows: int):
     """One grid step: pool ``tile`` samples of table ``program_id(0)``."""
     base = off_ref[pl.program_id(0)]
-
-    def row_copy(i):
-        row = jnp.clip(base + ids_ref[i], 0, num_rows - 1)
-        return pltpu.make_async_copy(
-            table_hbm.at[pl.ds(row, 1)],
-            rows.at[i % L, pl.ds(i // L, 1)], sem)
+    # samples per iteration: about _COPIES_PER_ITER copies, dividing tile
+    g = math.gcd(tile, max(1, _COPIES_PER_ITER // L))
 
     def start(i, carry):
-        row_copy(i).start()
+        b0, k0 = i * g, i * (g * L)   # first sample, its first id
+        for j in range(g):
+            for l in range(L):
+                row = jnp.clip(base + ids_ref[k0 + (j * L + l)],
+                               0, num_rows - 1)
+                pltpu.make_async_copy(table_hbm.at[pl.ds(row, 1)],
+                                      rows.at[l, pl.ds(b0 + j, 1)],
+                                      sem).start()
         return carry
 
-    def wait(i, carry):
-        # every copy moves one row on the same semaphore: any one-row
-        # descriptor waits for one of them
-        pltpu.make_async_copy(table_hbm.at[pl.ds(0, 1)],
-                              rows.at[0, pl.ds(0, 1)], sem).wait()
-        return carry
-
-    jax.lax.fori_loop(0, tile * L, start, 0)
-    jax.lax.fori_loop(0, tile * L, wait, 0)
+    jax.lax.fori_loop(0, tile // g, start, 0)
+    # the semaphore counts bytes landed: a wait sized to the whole
+    # scratch returns once all tile * L rows are in
+    pltpu.make_async_copy(rows, rows, sem).wait()
     acc = jnp.zeros(out_ref.shape, jnp.float32)
     for l in range(L):
         acc = acc + rows[l].astype(jnp.float32) * w_ref[:, l:l + 1]

@@ -71,11 +71,12 @@ def _has_onesided_kernel(compiled) -> bool:
     return tpu_kernels(compiled.as_text())[1] > 0
 
 
-def test_tbe_kernel_compiles_at_serving_batch(one_chip):
+@pytest.mark.parametrize("pooling", [1, L])   # MLPerf one-hot, CONFIG
+def test_tbe_kernel_compiles_at_serving_batch(one_chip, pooling):
     args = (_sds((T * SLOTS, D), jnp.float32, one_chip),
             _sds((T,), jnp.int32, one_chip),
-            _sds((T, B, L), jnp.int32, one_chip),
-            _sds((T, B, L), jnp.float32, one_chip))
+            _sds((T, B, pooling), jnp.int32, one_chip),
+            _sds((T, B, pooling), jnp.float32, one_chip))
     compiled = gather_pool_tbe_flat_pallas.lower(*args).compile()
     assert _has_kernel(compiled)
     assert compiled.out_info.shape == (T, B, D)

@@ -20,6 +20,7 @@ from repro.models.layers import chunked_attention, full_attention
     (64, 128, 4, 1),      # L=1 (the LM vocab case)
     (128, 256, 16, 8),    # MXU-aligned dim
     (100, 96, 5, 3),      # non-128-multiple dim
+    (64, 128, 130, 32),   # CONFIG pooling, two tiles, padded samples
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gather_pool_sweep(R, D, B, L, dtype):

@@ -16,6 +16,7 @@ from repro.core.embedding_bag import (
 from repro.core.jagged import random_jagged_batch
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.kernels.embedding_gather import gather_pool_tbe_flat_pallas
 
 
 def _mk(T, R=64, D=32, B=6, L=5, seed=0, weighted=False):
@@ -26,6 +27,45 @@ def _mk(T, R=64, D=32, B=6, L=5, seed=0, weighted=False):
     w = (jnp.asarray(rng.standard_normal((T, B, L)), jnp.float32)
          if weighted else None)
     return tables, idx, lens, w
+
+
+def _exact(rng, shape):
+    """f32 values whose weighted sums over up to 32 terms are exact, so
+    any pooling order gives the same bits: multiples of 1/4 in [-4, 4]."""
+    return jnp.asarray(rng.integers(-16, 17, shape) / 4, jnp.float32)
+
+
+# (T, B, L, D, rows per table): B is never a multiple of the tile (padded
+# samples), the tables are ragged, and L covers the one-hot case, a
+# few-hot one and the CONFIG pooling factor.
+_FLAT_CASES = [
+    (1, 9, 1, 128, [40]),
+    (3, 20, 4, 128, [5, 17, 9]),
+    (3, 130, 1, 8, [30, 2, 50]),
+    (26, 12, 32, 16, [7, 3, 11] * 8 + [5, 9]),
+]
+
+
+@pytest.mark.parametrize("T,B,L,D,rows", _FLAT_CASES,
+                         ids=[f"T{c[0]}-B{c[1]}-L{c[2]}" for c in _FLAT_CASES])
+def test_tbe_flat_kernel_bitwise(T, B, L, D, rows):
+    """The kernel over a ragged flat row space, bitwise to the flat oracle:
+    each slot reads row ``clip(row_offsets[t] + id, 0, N - 1)`` (ids run
+    past their table and below 0) and weight-0 slots add nothing."""
+    rng = np.random.default_rng(T * 1000 + B + L)
+    N = sum(rows)
+    off = np.concatenate([[0], np.cumsum(rows)[:-1]]).astype(np.int32)
+    flat = _exact(rng, (N, D))
+    idx = rng.integers(-3, max(rows) + 3, (T, B, L)).astype(np.int32)
+    w = np.array(_exact(rng, (T, B, L)))
+    w[rng.random((T, B, L)) < 0.25] = 0.0            # masked slots
+    clamped = np.clip(off[:, None, None] + idx, 0, N - 1) - off[:, None, None]
+    got = gather_pool_tbe_flat_pallas(flat, jnp.asarray(off), jnp.asarray(idx),
+                                      jnp.asarray(w), interpret=True)
+    want = kref.embedding_bag_batched_flat_ref(
+        flat, jnp.asarray(off), jnp.asarray(clamped), None, jnp.asarray(w))
+    assert got.shape == (T, B, D)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("T", [1, 4, 16])
@@ -62,11 +102,15 @@ def test_tbe_mean_combiner():
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("T", [1, 4, 16])
+@pytest.mark.parametrize("T", [1, 3, 4, 16, 26])
 def test_tbe_rw_premasked_shards_reconstruct(T):
-    """RW variant: per-shard fused partials sum to the full pool."""
+    """RW variant: per-shard fused partials equal the masked oracle bit for
+    bit (exact values, L=32) and sum to the full pool."""
     R, E = 64, 4
-    tables, idx, lens, _ = _mk(T, R=R, seed=T + 10)
+    rng = np.random.default_rng(T + 10)
+    tables = _exact(rng, (T, R, 16))
+    idx = jnp.asarray(rng.integers(0, R, (T, 12, 32)), jnp.int32)
+    lens = jnp.asarray(rng.integers(0, 33, (T, 12)), jnp.int32)
     full = kops.embedding_bag_batched(tables, idx, lens, mode="reference")
     Rs = R // E
     acc = jnp.zeros_like(full)
@@ -76,11 +120,9 @@ def test_tbe_rw_premasked_shards_reconstruct(T):
             shard, e * Rs, idx, lens, mode="interpret", fused=True)
         ref = kops.embedding_bag_rw_partial_batched(
             shard, e * Rs, idx, lens, mode="reference")
-        np.testing.assert_allclose(np.asarray(part), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(np.asarray(part), np.asarray(ref))
         acc = acc + part
-    np.testing.assert_allclose(np.asarray(acc), np.asarray(full),
-                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(acc), np.asarray(full))
 
 
 def test_tbe_grad_matches_reference():
