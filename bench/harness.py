@@ -362,21 +362,24 @@ def _check(win: Window, pool, cfg: dict, seed: int, mlp, reference) -> float:
 def _tbe_bytes(win: Window, pool, cfg: dict, chips: int, reference) -> float:
     """Least bytes of every TBE call in the window, summed over chips: each
     chip reads the distinct rows it holds of its micro-batch once, and
-    every slot's id and weight, and writes the pooled output."""
-    T, R, D, L = (cfg["num_sparse_features"], cfg["rows_per_table"],
-                  cfg["embedding_dim"], cfg["pooling"])
-    seen = np.zeros(T * R, bool)
-    offsets = (np.arange(T, dtype=np.int64) * R)[None, :, None]
+    every live slot's id and weight, and writes the pooled output.  Table
+    ``t``'s rows follow table ``t - 1``'s, and row-wise each chip holds
+    ``ceil(R_t / chips)`` consecutive rows of every table."""
+    T, D = cfg["num_sparse_features"], cfg["embedding_dim"]
+    R = traffic.per_table(cfg, "rows_per_table")
+    offsets = np.cumsum(R) - R
+    share = -(-R // chips)
+    live = np.arange(pool.ids.shape[2]) < pool.lengths[:, None]    # (T, L)
+    slots = int(pool.lengths.sum())
     total = 0.0
     for rids in win.batches:
         rids = np.asarray(list(rids), np.int64)
-        flat = (pool.ids[rids % len(pool)] + offsets).ravel()
-        seen[flat] = True
-        distinct = np.flatnonzero(seen)
-        seen[distinct] = False
-        per_chip = np.bincount((distinct % R) // -(-R // chips),
+        flat = pool.ids[rids % len(pool)] + offsets[:, None]
+        distinct = np.unique(flat[:, live])
+        t = np.searchsorted(offsets, distinct, "right") - 1
+        per_chip = np.bincount((distinct - offsets[t]) // share[t],
                                minlength=chips)
         for n in per_chip:
-            total += reference.tbe_least_bytes(int(n), T * len(rids) * L,
+            total += reference.tbe_least_bytes(int(n), slots * len(rids),
                                                len(rids), T, D)
     return total

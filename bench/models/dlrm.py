@@ -83,7 +83,7 @@ class System:
     def __init__(self, seed: int, cfg: dict, devices, warmup_freqs=None):
         from repro.serving.engine import CTRRequest, make_dlrm_engine
 
-        self.cfg, self.devices = cfg, devices
+        self.cfg, self.devices = ref.equal_tables(cfg), devices
         self.dlrm = dlrm_config(cfg, warmup_freqs)
         params = make_weights(seed, cfg, devices)
         # the reference's copy of the MLPs, taken before the engine runs

@@ -37,6 +37,16 @@ def seed_words(seed: int) -> np.ndarray:
     return np.array([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
 
 
+def equal_tables(cfg: dict) -> dict:
+    """``cfg``, refused where ``rows_per_table`` or ``pooling`` is a list:
+    this model takes one of each for all its tables."""
+    for key in ("rows_per_table", "pooling"):
+        if not isinstance(cfg[key], int):
+            raise ValueError(f"{key} is {cfg[key]!r}: the dlrm model takes "
+                             "one int for every table")
+    return cfg
+
+
 def _mix32(x):
     """A 32-bit integer finaliser (lowbias32): every input bit moves
     about half of the output bits."""
@@ -154,6 +164,7 @@ def reference_scores(seed: int, cfg: dict, mlp, dense, ids, lengths, *,
                      precision: str = "highest", block: int = 256):
     """The reference's pCTR for the given samples, ``block`` at a time on
     the default device."""
+    equal_tables(cfg)
     words = jnp.asarray(seed_words(seed))
     mlp = jax.tree.map(jnp.asarray, mlp)
     n = dense.shape[0]
@@ -177,7 +188,7 @@ def reference_scores(seed: int, cfg: dict, mlp, dense, ids, lengths, *,
 def flops_per_sample(cfg: dict) -> int:
     """Operations one sample needs: both MLPs (2 per multiply-add), the
     interaction's distinct pairs, and the pooling's multiply-adds."""
-    bottom, top = mlp_dims(cfg)
+    bottom, top = mlp_dims(equal_tables(cfg))
     mlp = sum(2 * a * b for dims in (bottom, top)
               for a, b in zip(dims[:-1], dims[1:]))
     n = cfg["num_sparse_features"] + 1

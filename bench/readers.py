@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import importlib
 
+from bench import stats
 from bench.trace import COLLECTIVE
 
 
@@ -12,6 +13,16 @@ def submit_us(run):
     """Host microseconds per sample inside the engine's ``submit``."""
     w = run.window
     return w.submit_s / w.submitted * 1e6 if w.submitted else None
+
+
+def query_p99_ms(run):
+    """99th percentile (nearest rank) of the window's query latencies in
+    milliseconds, every query due in the window counted, an unanswered one
+    as infinite: the end-to-end definition, read in the traced run."""
+    lat = run.window.latencies_s
+    if lat is None or not len(lat):
+        return None
+    return stats.percentile(list(lat * 1e3), 99)
 
 
 def hit_rate(run):

@@ -192,6 +192,19 @@ def test_reader_arithmetic():
     assert readers.rw_collective_ms(run) is None
 
 
+def test_query_tail_reader():
+    lat = np.arange(1, 101) / 1e3          # 1..100 ms
+    win = harness.Window("server", 1.0, 100, 100, 0.0, [], {},
+                         latencies_s=lat)
+    assert readers.query_p99_ms(_run(window=win)) == pytest.approx(99.0)
+    # an unanswered query ranks above every answered one
+    win.latencies_s = np.concatenate([lat[:98], [np.inf, np.inf]])
+    assert readers.query_p99_ms(_run(window=win)) == math.inf
+    # offline windows have no queries
+    assert readers.query_p99_ms(_run()) is None
+    assert harness.reader("query_p99_ms.server") is readers.query_p99_ms
+
+
 def test_operation_counts_from_shapes():
     cfg = json.loads((ROOT / "bench/configs/dlrm-criteo-hbm.json").read_text())
     # MLPs 4,730,368 + 351 pairs x 128 x 2 + 26 x 32 x 128 x 2
