@@ -53,6 +53,21 @@ def audit_tbe_flat() -> AuditReport:
         kops.KERNEL_CONTRACTS["tbe_flat"])
 
 
+def audit_tbe_multihot() -> AuditReport:
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+
+    pooling, D, B = (3, 1, 12, 2), 16, 8
+    N, S = 4 * 32, sum(pooling)
+    return audit(
+        lambda p, o, i, ln: kops.embedding_bag_packed(
+            p, o, i, ln, pooling, mode="interpret"),
+        (_sds((N, D), jnp.float32), _sds((len(pooling),), jnp.int32),
+         _sds((B, S), jnp.int32), _sds((B, len(pooling)), jnp.int32)),
+        kops.KERNEL_CONTRACTS["tbe_multihot"])
+
+
 def audit_rw_partial_fused() -> AuditReport:
     import jax.numpy as jnp
 
@@ -149,6 +164,7 @@ def audit_tiered_forward() -> AuditReport:
 ALL_FIXTURES = (
     audit_tbe_fused,
     audit_tbe_flat,
+    audit_tbe_multihot,
     audit_rw_partial_fused,
     audit_cached_device_lookup,
     audit_pooled_lookup_local,

@@ -40,23 +40,27 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.lax import axis_size
 
 from repro.core import comm
 from repro.core.cache_config import CacheConfig, resolve_cache_aliases
-from repro.core.jagged import JaggedBatch
+from repro.core.jagged import JaggedBatch, PackedBatch
 from repro.kernels import ops as kops
 
 
 @dataclasses.dataclass(frozen=True)
 class EmbeddingBagConfig:
     num_tables: int
-    rows_per_table: int
+    # one row count for every table, stacked (T, R, D); or a tuple of one
+    # per table, kept as one flat (sum R_t, D) array, table t's rows from
+    # row_offsets()[t]
+    rows_per_table: Union[int, Tuple[int, ...]]
     dim: int
     combiner: str = "sum"            # sum | mean
     dtype: str = "float32"
@@ -70,6 +74,10 @@ class EmbeddingBagConfig:
     # tables per shard. False calls the single-table kernel per table (T
     # launches); kept as the A/B baseline for benchmarks/tbe_sweep.py.
     fused: bool = True
+    # pooling: each table's fixed multi-hot size L_t, for lookups packed
+    # as ``PackedBatch`` (only each table's L_t slots per sample); None
+    # for the padded (T, B, L) ``JaggedBatch`` layout
+    pooling: Optional[Tuple[int, ...]] = None
     # --- beyond-paper levers (EXPERIMENTS.md §beyond-paper) ---
     # rs_dtype: cast partial pooled vectors to this dtype before the
     # phase-3 reduce-scatter/all-reduce — halves output traffic at bf16
@@ -113,13 +121,27 @@ class EmbeddingBagConfig:
             object.__setattr__(self, alias, None)
 
     @property
+    def per_table(self) -> bool:
+        """Rows given per table: one flat ``(sum R_t, D)`` array."""
+        return not isinstance(self.rows_per_table, int)
+
+    @property
+    def num_rows(self) -> int:
+        """Rows of all tables together."""
+        if self.per_table:
+            return sum(self.rows_per_table)
+        return self.num_tables * self.rows_per_table
+
+    def row_offsets(self) -> np.ndarray:
+        """``(T,)`` int64: the first row of each table in the flat array,
+        the rows of tables ``0 .. t - 1`` before table ``t``."""
+        rows = np.broadcast_to(np.asarray(self.rows_per_table, np.int64),
+                               (self.num_tables,))
+        return np.cumsum(rows) - rows
+
+    @property
     def table_bytes(self) -> int:
-        return (
-            self.num_tables
-            * self.rows_per_table
-            * self.dim
-            * jnp.dtype(self.dtype).itemsize
-        )
+        return self.num_rows * self.dim * jnp.dtype(self.dtype).itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -127,9 +149,11 @@ class EmbeddingBagConfig:
 # ---------------------------------------------------------------------------
 
 def init_tables(rng: jax.Array, cfg: EmbeddingBagConfig) -> jax.Array:
-    """(T, R, D) stacked tables; scale 1/sqrt(D) like TorchRec defaults."""
-    return _normal_tables(rng, (cfg.num_tables, cfg.rows_per_table, cfg.dim),
-                          jnp.dtype(cfg.dtype))
+    """(T, R, D) stacked tables, or the flat (sum R_t, D) array of tables
+    whose rows differ; scale 1/sqrt(D) like TorchRec defaults."""
+    shape = ((cfg.num_rows, cfg.dim) if cfg.per_table
+             else (cfg.num_tables, cfg.rows_per_table, cfg.dim))
+    return _normal_tables(rng, shape, jnp.dtype(cfg.dtype))
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -141,10 +165,20 @@ def _normal_tables(rng, shape, dtype):
             * shape[-1] ** -0.5).astype(dtype)
 
 
+def _equal_tables(cfg: EmbeddingBagConfig) -> None:
+    """Refuse tables whose rows differ on the sharded paths."""
+    if cfg.per_table:
+        raise NotImplementedError(
+            f"sharding {cfg.sharding!r} of per-table rows_per_table: the "
+            f"row-wise split of uneven tables is not implemented (ROADMAP "
+            f"Reach 1); such tables are served from one device")
+
+
 def table_pspec(cfg: EmbeddingBagConfig, model_axis: str = "model"):
     """PartitionSpec for the stacked (T, R, D) table under cfg.sharding."""
     from jax.sharding import PartitionSpec as P
 
+    _equal_tables(cfg)
     return {
         "row": P(None, model_axis, None),
         "column": P(None, None, model_axis),
@@ -158,24 +192,39 @@ def table_pspec(cfg: EmbeddingBagConfig, model_axis: str = "model"):
 # ---------------------------------------------------------------------------
 
 def pooled_lookup_local(
-    tables: jax.Array, batch: JaggedBatch, cfg: EmbeddingBagConfig
+    tables: jax.Array, batch: Union[JaggedBatch, PackedBatch],
+    cfg: EmbeddingBagConfig
 ) -> jax.Array:
-    """Tables x JaggedBatch -> (B, T, D), no communication.
+    """Tables x JaggedBatch (or PackedBatch) -> (B, T, D), no
+    communication.
 
     ``tables`` is either the full stacked ``(T, R, D)`` array (ids are
-    row ids), or the tiered cache's FLAT ``(sum S_t, D)`` slot pool (ids
-    are pool-slot ids out of ``CachedEmbeddingBag.prefetch``) — the 2-D
-    case derives the kernel's per-table slot offsets from ``cfg.cache``
-    (the SAME geometry the SlotPoolManager sized the pool with, so the
-    two can never disagree).
+    row ids), or a FLAT ``(N, D)`` array: the tables whose rows differ
+    (``cfg.per_table``; offsets ``cfg.row_offsets()``), or the tiered
+    cache's ``(sum S_t, D)`` slot pool (ids are pool-slot ids out of
+    ``CachedEmbeddingBag.prefetch``; offsets from ``cfg.cache``, the SAME
+    geometry the SlotPoolManager sized the pool with, so the two can
+    never disagree).  A ``PackedBatch`` (multi-hot sizes ``cfg.pooling``)
+    reads the flat tables through the packed multi-hot kernel.
 
     All T tables go through ONE table-batched kernel call when
     ``cfg.fused`` (the default); ``fused=False`` restores the per-table
     baseline (3-D tables only — a ragged flat pool is always fused).
     """
+    if isinstance(batch, PackedBatch):
+        out = kops.embedding_bag_packed(
+            tables,
+            jnp.asarray(cfg.row_offsets(), jnp.int32),
+            batch.ids,
+            batch.lengths,
+            cfg.pooling,
+            mode=cfg.kernel_mode,
+        )                                                    # (T, B, D)
+        return out.transpose(1, 0, 2)
     if tables.ndim == 2:
-        offsets = cfg.cache.slot_offsets(
-            cfg.num_tables, cfg.rows_per_table)[:-1]
+        offsets = (cfg.cache.slot_offsets(
+            cfg.num_tables, cfg.rows_per_table)[:-1] if cfg.cache.enabled
+            else cfg.row_offsets())
         out = kops.embedding_bag_batched_flat(
             tables,
             jnp.asarray(offsets, jnp.int32),
@@ -454,6 +503,7 @@ def pooled_lookup_sharded(
     """
     if cfg.sharding == "replicated":
         return pooled_lookup_local(table_shard, batch, cfg)
+    _equal_tables(cfg)
     if cfg.sharding == "row":
         if cfg.rw_impl == "a2a":
             out, _ = _rw_a2a(table_shard, batch, cfg, model_axis)
@@ -553,6 +603,10 @@ def make_cache(tables, cfg: EmbeddingBagConfig):
     """
     from repro.cache import CachedEmbeddingBag   # deferred: cache -> core
 
+    if cfg.per_table:
+        raise NotImplementedError(
+            "the tiered store over tables whose rows differ needs "
+            "per-table tiered metadata, not implemented (ROADMAP Reach 1)")
     return CachedEmbeddingBag(tables, cfg)
 
 

@@ -74,6 +74,53 @@ class JaggedBatch:
         return jnp.where(m, self.weights, 0.0).astype(jnp.float32)
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class PackedBatch:
+    """Multi-hot lookups of ``T`` tables whose pooling ``L_t`` is fixed
+    per table, packed sample-major: only each table's ``L_t`` slots exist,
+    so a table of ``L_t = 1`` beside one of ``L_t = 100`` costs one slot,
+    not a hundred.
+
+    Attributes:
+      ids: int32 (B, S), ``S = sum L_t`` — table-local row ids; table
+        ``t``'s slots are columns ``[s_t, s_t + L_t)``, ``s_t = L_0 + ...
+        + L_{t-1}``.
+      lengths: int32 (B, T) — valid lookups per sample and table
+        (``0 <= lengths[:, t] <= L_t``); the slots past them are masked.
+    """
+
+    ids: jax.Array
+    lengths: jax.Array
+
+    def tree_flatten(self):
+        return (self.ids, self.lengths), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    @property
+    def batch_size(self) -> int:
+        return self.ids.shape[0]
+
+
+def packed_slots(pooling) -> tuple:
+    """``(table, position)`` int arrays ``(S,)`` of the packed layout's
+    slots, table-major: slot ``s`` is lookup ``position[s]`` of table
+    ``table[s]``."""
+    pooling = np.asarray(pooling, np.int64)
+    table = np.repeat(np.arange(pooling.size), pooling)
+    starts = np.cumsum(pooling) - pooling
+    return table, np.arange(table.size) - starts[table]
+
+
+def packed_weights(lengths: jax.Array, pooling) -> jax.Array:
+    """Float (B, S): 1 where a packed slot is within its table's length."""
+    table, position = packed_slots(pooling)
+    return (position[None, :] < lengths[:, table]).astype(jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Host-side CSR (paper format) <-> padded-dense conversions
 # ---------------------------------------------------------------------------

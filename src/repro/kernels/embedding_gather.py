@@ -63,6 +63,12 @@ VMEM per step: the ``(L, Bt, D)`` row scratch (``Bt`` is chosen so it
 stays <= 4 MiB: 2 MiB at L=32, D=128, Bt=128), plus the double-buffered
 ``(Bt, L)`` weights and ``(Bt, D)`` output blocks.
 
+Multi-hot features whose pooling ``L_t`` differs per table (DLRMv2: 1 to
+100) have their own kernel, ``gather_pool_tbe_multihot_pallas``: packed
+ids with only each table's ``L_t`` slots, one row DMA per packed slot.
+Padding every table to the largest ``L_t`` would start 12.1x the row DMAs
+at DLRMv2's sizes.
+
 The RW-masked (row-wise-parallel, paper §4.2) variant is expressed by
 pre-masking: ops.py maps out-of-shard ids to local row 0 with weight 0,
 so the same kernel serves the single-device and the sharded paths (the
@@ -76,6 +82,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -183,6 +190,103 @@ def gather_pool_tbe_flat_pallas(
         interpret=interpret,
     )(row_offsets.astype(jnp.int32), ids, weights.astype(jnp.float32),
       flat_tables)
+    return out[:, :B]
+
+
+def _tbe_multihot_kernel(ids_ref, w_ref, table_hbm, out_ref, rows, sem, *,
+                         pooling, tile: int):
+    """One grid step: pool ``tile`` samples of every table, table ``t``
+    from its ``pooling[t]`` packed slots."""
+    S = sum(pooling)
+    g = math.gcd(tile, max(1, _COPIES_PER_ITER // S))
+
+    def start(i, carry):
+        b0, k0 = i * g, i * (g * S)   # first sample, its first id
+        for j in range(g):
+            for s in range(S):
+                pltpu.make_async_copy(
+                    table_hbm.at[pl.ds(ids_ref[k0 + (j * S + s)], 1)],
+                    rows.at[s, pl.ds(b0 + j, 1)], sem).start()
+        return carry
+
+    jax.lax.fori_loop(0, tile // g, start, 0)
+    pltpu.make_async_copy(rows, rows, sem).wait()
+    s = 0
+    for t, L in enumerate(pooling):
+        acc = jnp.zeros(out_ref.shape[1:], jnp.float32)
+        for _ in range(L):
+            acc = acc + rows[s].astype(jnp.float32) * w_ref[:, s:s + 1]
+            s += 1
+        out_ref[t] = acc
+
+
+@functools.partial(jax.jit, static_argnames=("pooling", "interpret"))
+def gather_pool_tbe_multihot_pallas(
+    flat_tables: jax.Array,   # (N, D) concatenated per-table row blocks
+    row_offsets: jax.Array,   # (T,) int32 — start of table t's rows in N
+    ids: jax.Array,           # (B, S) int32 packed TABLE-LOCAL ids
+    weights: jax.Array,       # (B, S) f32 — 0 for masked slots
+    *,
+    pooling: tuple,           # (T,) static L_t, S = sum L_t
+    interpret: bool = False,
+) -> jax.Array:
+    """Fused pooled lookup of multi-hot features whose pooling ``L_t`` is
+    fixed per table, over a flat row space.
+
+    ``out[t, b] = sum_{l < L_t} weights[b, s_t + l] * flat_tables[
+    row_offsets[t] + ids[b, s_t + l]]``, ``s_t = L_0 + ... + L_{t-1}``: the
+    ids hold each table's ``L_t`` slots and no padding to the largest.
+    Returns (T, B, D) f32.
+
+    The grid walks tiles of ``Bt`` samples; a step starts ``Bt * S`` row
+    DMAs into a ``(S, Bt, D)`` scratch, waits once, and pools every table
+    into its ``(T, Bt, D)`` output block.  So a micro-batch starts one row
+    DMA per packed slot (plus the last tile's padding): at DLRMv2's
+    multi-hot sizes (``S`` = 214, up to 100 per table) that is 12.1x
+    fewer than one kernel pass at ``L = 100``.  Row ids are offset and
+    clamped into ``[0, N)`` before the kernel, so a DMA never reads
+    outside the table and the start loop does one SMEM load per row.
+    """
+    N, D = flat_tables.shape
+    B, S = ids.shape
+    T = len(pooling)
+    if row_offsets.shape != (T,) or sum(pooling) != S:
+        raise ValueError(
+            f"row_offsets {row_offsets.shape} and pooling (sum "
+            f"{sum(pooling)}) must match T={T} tables and S={S} slots")
+    itemsize = jnp.dtype(flat_tables.dtype).itemsize
+    if not interpret and (D != 128 or itemsize != 4):
+        raise NotImplementedError(
+            f"the TPU gather kernel needs (N, 128) rows of a 32-bit dtype "
+            f"(the compiler refuses one-row DMAs from other HBM tilings); "
+            f"got D={D}, {flat_tables.dtype}")
+    table = np.repeat(np.arange(T), pooling)
+    rows = jnp.clip(ids.astype(jnp.int32)
+                    + row_offsets.astype(jnp.int32)[table], 0, N - 1)
+    tile = _tile(B, S, D, itemsize)
+    nb = -(-B // tile)
+    pad = nb * tile - B
+    weights = weights.astype(jnp.float32)
+    if pad:   # padded samples: row 0, weight 0 -> sliced off below
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        weights = jnp.pad(weights, ((0, pad), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_tbe_multihot_kernel, pooling=pooling, tile=tile),
+        grid=(nb,),
+        in_specs=[
+            pl.BlockSpec((None, None, tile * S), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((tile, S), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((T, tile, D), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, nb * tile, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((S, tile, D), flat_tables.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(rows.reshape(nb, 1, tile * S), weights, flat_tables)
     return out[:, :B]
 
 

@@ -29,15 +29,17 @@ space with the same per-table offsets as the forward.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.jagged import packed_weights
 from repro.kernels import ref as _ref
 from repro.kernels.embedding_gather import (
     gather_pool_pallas,
     gather_pool_tbe_flat_pallas,
+    gather_pool_tbe_multihot_pallas,
     gather_pool_tbe_pallas,
 )
 
@@ -175,6 +177,31 @@ def _tbe_flat_bwd(interpret, res, g):
 
 
 _pooled_lookup_tbe_flat.defvjp(_tbe_flat_fwd, _tbe_flat_bwd)
+
+
+# --- packed multi-hot path: inference only --------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _pooled_lookup_multihot(flat_tables, row_offsets, ids, eff_w, pooling,
+                            interpret):
+    return gather_pool_tbe_multihot_pallas(
+        flat_tables, row_offsets, ids, eff_w, pooling=pooling,
+        interpret=interpret)
+
+
+def _multihot_fwd(flat_tables, row_offsets, ids, eff_w, pooling, interpret):
+    return _pooled_lookup_multihot(flat_tables, row_offsets, ids, eff_w,
+                                   pooling, interpret), None
+
+
+def _multihot_bwd(pooling, interpret, res, g):
+    raise NotImplementedError(
+        "the packed multi-hot TBE kernel (embedding_bag_packed) serves "
+        "inference only: it has no backward pass; train through "
+        "embedding_bag_batched_flat or kernel mode 'reference'")
+
+
+_pooled_lookup_multihot.defvjp(_multihot_fwd, _multihot_bwd)
 
 
 def _pooled_lookup_per_table(tables, indices, eff_w, interpret):
@@ -316,6 +343,35 @@ def embedding_bag_batched_flat(
     return out.astype(flat_tables.dtype)
 
 
+def embedding_bag_packed(
+    flat_tables: jax.Array,    # (N, D) concatenated per-table row blocks
+    row_offsets: jax.Array,    # (T,) int32 — start of table t's rows in N
+    ids: jax.Array,            # (B, S) packed table-local ids, S = sum L_t
+    lengths: jax.Array,        # (B, T) valid lookups, <= L_t
+    pooling: Tuple[int, ...],  # (T,) static L_t
+    *,
+    mode: str = "auto",
+) -> jax.Array:
+    """Sum-pooled lookup of packed multi-hot ids (``core.jagged.
+    PackedBatch``) over a flat row space -> (T, B, D).
+
+    ``out[t, b] = sum_{l < lengths[b, t]} flat_tables[row_offsets[t] +
+    ids[b, s_t + l]]``: table ``t`` owns the ``pooling[t]`` slots from
+    ``s_t = pooling[0] + ... + pooling[t - 1]``.  One fused
+    ``pallas_call`` that starts one row DMA per packed slot; no backward.
+    """
+    mode = _resolve_mode(mode)
+    eff_w = packed_weights(lengths, pooling)
+    if mode == "reference":
+        return _ref.embedding_bag_packed_ref(
+            flat_tables, row_offsets, ids, eff_w, pooling)
+    if mode not in ("pallas", "interpret"):
+        raise ValueError(f"unknown mode {mode!r}")
+    out = _pooled_lookup_multihot(flat_tables, row_offsets, ids, eff_w,
+                                  tuple(pooling), mode == "interpret")
+    return out.astype(flat_tables.dtype)
+
+
 def embedding_bag_rw_partial_batched(
     table_shards: jax.Array,   # (T, R_shard, D) this device's row slices
     row_offset,
@@ -364,6 +420,10 @@ KERNEL_CONTRACTS = {
     "tbe_flat": KernelContract(
         name="kernels.ops.embedding_bag_batched_flat",
         note="the flat (sum S_t, D) slot-pool TBE stays one launch"),
+    "tbe_multihot": KernelContract(
+        name="kernels.ops.embedding_bag_packed",
+        note="packed multi-hot lookups (a static L_t per table) stay one "
+             "launch over the flat (sum R_t, D) row space"),
     "rw_partial_fused": KernelContract(
         name="kernels.ops.embedding_bag_rw_partial_batched[fused]",
         note="the row-wise-sharded partial pool stays one launch; "

@@ -6,10 +6,11 @@ and the fallback implementation used on non-TPU backends (including the
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _pool_rows(rows, lengths, weights, combiner, out_dtype) -> jax.Array:
@@ -136,6 +137,26 @@ def embedding_bag_batched_flat_ref(
         return jax.vmap(lambda off, i, ln: fn(off, i, ln, None))(
             row_offsets, indices, lens)
     return jax.vmap(fn)(row_offsets, indices, lens, weights)
+
+
+def embedding_bag_packed_ref(
+    flat_tables: jax.Array,    # (N, D) concatenated per-table row blocks
+    row_offsets: jax.Array,    # (T,) start of table t's rows in N
+    ids: jax.Array,            # (B, S) packed table-local ids, S = sum L_t
+    weights: jax.Array,        # (B, S) f32 — 0 for masked slots
+    pooling: Tuple[int, ...],  # (T,) static L_t
+) -> jax.Array:
+    """Packed multi-hot oracle: ``out[t, b] = sum_{s in table t} w[b, s] *
+    flat_tables[clip(row_offsets[t] + ids[b, s], 0, N - 1)]`` -> (T, B, D).
+    """
+    N = flat_tables.shape[0]
+    table = np.repeat(np.arange(len(pooling)), pooling)
+    rows = flat_tables[jnp.clip(ids + row_offsets[table], 0, N - 1)]
+    contrib = rows.astype(jnp.float32) * weights[..., None]   # (B, S, D)
+    out = jax.ops.segment_sum(contrib.transpose(1, 0, 2), table,
+                              num_segments=len(pooling),
+                              indices_are_sorted=True)
+    return out.astype(flat_tables.dtype)
 
 
 def embedding_bag_masked_batched_ref(

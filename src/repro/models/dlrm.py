@@ -1,6 +1,7 @@
 """DLRM — the paper's canonical model (Fig. 2): bottom MLP, embedding
 pooling (the distributed Embedding Bag under test), dot-product feature
-interaction, top MLP.
+interaction, top MLP.  ``interaction="dcn"`` swaps the dot products for
+DCN-v2's low-rank cross network (DLRM-DCNv2, MLPerf's DLRMv2).
 
 Inference path = §4/§5 of the paper; the training path (BCE on CTR labels)
 exists so the framework's optimizer/checkpoint substrates are exercised on
@@ -11,7 +12,7 @@ distribution projection) drives this exact model.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.dlrm import DLRMConfig
 from repro.core import embedding_bag as eb
-from repro.core.jagged import JaggedBatch
+from repro.core.jagged import JaggedBatch, PackedBatch
 from repro.core.parallel import ParallelContext
 
 
@@ -45,15 +46,31 @@ def _mlp_apply(layers_, x, *, final_act=False):
     return x
 
 
+def _cross_init(rng, dim: int, rank: int, layers: int, dtype):
+    ks = jax.random.split(rng, 2 * layers)
+    return [{"v": (jax.random.truncated_normal(ks[2 * i], -2, 2, (dim, rank))
+                   * dim ** -0.5).astype(dtype),
+             "w": (jax.random.truncated_normal(ks[2 * i + 1], -2, 2,
+                                               (rank, dim))
+                   * rank ** -0.5).astype(dtype),
+             "b": jnp.zeros((dim,), dtype)}
+            for i in range(layers)]
+
+
 def init_params(rng, cfg: DLRMConfig, dtype=jnp.float32) -> Dict[str, Any]:
     ks = jax.random.split(rng, 3)
     ecfg = cfg.embedding_config()
-    return {
-        "tables": eb.init_tables(ks[0], ecfg),          # (T, R, D)
+    params = {
+        "tables": eb.init_tables(ks[0], ecfg),   # (T, R, D) or (sum R_t, D)
         "bottom": _mlp_init(
             ks[1], (cfg.num_dense_features,) + cfg.bottom_mlp, dtype),
         "top": _mlp_init(ks[2], (cfg.interaction_dim,) + cfg.top_mlp, dtype),
     }
+    if cfg.interaction == "dcn":
+        params["cross"] = _cross_init(jax.random.fold_in(rng, 3),
+                                      cfg.interaction_dim, cfg.dcn_rank,
+                                      cfg.dcn_layers, dtype)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -72,13 +89,31 @@ def dot_interaction(dense_vec: jax.Array, pooled: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# Feature interaction (DCN-v2 low-rank cross network, Wang et al. 2020)
+# ---------------------------------------------------------------------------
+
+def dcn_cross(x0: jax.Array, layers) -> jax.Array:
+    """x0 (B, N) -> (B, N): ``x_{l+1} = x0 * (W_l (V_l x_l) + b_l) + x_l``
+    for each layer ``{"v": (N, r), "w": (r, N), "b": (N,)}``
+    (arXiv:2008.13535, TorchRec ``LowRankCrossNet``)."""
+    with jax.named_scope("repro.dlrm.cross"):
+        x = x0
+        for layer in layers:
+            x = x0 * ((x @ layer["v"]) @ layer["w"] + layer["b"]) + x
+        return x
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
-def forward(params, dense: jax.Array, batch: JaggedBatch, cfg: DLRMConfig,
+def forward(params, dense: jax.Array, batch: Union[JaggedBatch, PackedBatch],
+            cfg: DLRMConfig,
             ctx: Optional[ParallelContext] = None) -> jax.Array:
     """dense (B, num_dense), batch: sparse lookups -> CTR logit (B,).
 
+    ``batch`` is a ``JaggedBatch``, or for a per-table config
+    (``cfg.per_table``) a ``PackedBatch`` of each table's ``L_t`` slots.
     With a ``ctx``, the embedding pooling runs the paper's distributed
     pipeline inside shard_map (tables sharded per cfg.sharding over the tp
     axis, batch replicated over tp / sharded over dp).
@@ -87,6 +122,7 @@ def forward(params, dense: jax.Array, batch: JaggedBatch, cfg: DLRMConfig,
     if ctx is None:
         pooled = eb.pooled_lookup_local(params["tables"], batch, ecfg)
     else:
+        tspec = eb.table_pspec(ecfg, ctx.tp_axis)   # refuses uneven tables
         B = batch.batch_size
         dp = ctx.dp_for(B)
 
@@ -99,13 +135,18 @@ def forward(params, dense: jax.Array, batch: JaggedBatch, cfg: DLRMConfig,
             weights=None if batch.weights is None else P(None, dp, None))
         pooled = shard_map(
             inner, mesh=ctx.mesh,
-            in_specs=(eb.table_pspec(ecfg, ctx.tp_axis), bspec),
+            in_specs=(tspec, bspec),
             out_specs=P(dp, None, None),
             check_vma=False,
         )(params["tables"], batch)
 
     bot = _mlp_apply(params["bottom"], dense, final_act=True)   # (B, D)
-    feats = dot_interaction(bot, pooled.astype(bot.dtype))
+    pooled = pooled.astype(bot.dtype)
+    if cfg.interaction == "dcn":
+        x0 = jnp.concatenate([bot, pooled.reshape(bot.shape[0], -1)], axis=1)
+        feats = dcn_cross(x0, params["cross"])                  # (B, (T+1)D)
+    else:
+        feats = dot_interaction(bot, pooled)
     logit = _mlp_apply(params["top"], feats)                    # (B, 1)
     return logit[:, 0]
 

@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.configs.dlrm import DLRMConfig
-from repro.core.jagged import JaggedBatch
+from repro.core.jagged import JaggedBatch, PackedBatch
 from repro.core.parallel import ParallelContext
 from repro.models import decode as dec
 from repro.models import dlrm as dlrm_mod
@@ -195,15 +195,16 @@ class CTRRequest:
     """One scoring request: dense features + per-table sparse lookups."""
     rid: int
     dense: np.ndarray          # (num_dense_features,)
-    indices: np.ndarray        # (T, L) table-local row ids (padded)
-    lengths: np.ndarray        # (T,) valid lookups per table
+    indices: np.ndarray        # (T, max L_t) table-local row ids (padded)
+    lengths: np.ndarray        # (T,) valid lookups per table, <= L_t
 
 
 def make_scorer(cfg: DLRMConfig, ctx: Optional[ParallelContext] = None):
     """The engines' jitted scoring program: ``(params, dense (B, F),
     batch) -> pCTR (B,)``.  ``params["tables"]`` is the stacked
-    ``(T, R, D)`` tables, or the tiered store's flat ``(sum S_t, D)``
-    slot pool with slot ids in ``batch``."""
+    ``(T, R, D)`` tables, the flat ``(sum R_t, D)`` tables of a per-table
+    config (``batch`` a ``PackedBatch``), or the tiered store's flat
+    ``(sum S_t, D)`` slot pool with slot ids in ``batch``."""
     def fwd(p, dense, batch):
         return jax.nn.sigmoid(dlrm_mod.forward(p, dense, batch, cfg, ctx))
 
@@ -218,6 +219,11 @@ class DLRMEngine:
     pooling inside is the fused TBE path (``cfg.fused``), so every flush
     costs a single gather kernel launch regardless of the table count.
     Fixed shapes mean the forward compiles exactly once.
+
+    A per-table config (``cfg.per_table``: rows and pooling ``R_t``,
+    ``L_t`` per table) takes requests of ``(T, max L_t)`` ids and stages
+    only each table's ``L_t`` slots (``PackedBatch``) for the packed
+    multi-hot kernel; it serves from one device, tables in HBM.
 
     With ``cfg.cache.enabled`` (``cache.rows > 0`` or a per-table
     vector) the tables live behind a tiered cache (repro/cache/):
@@ -277,8 +283,25 @@ class DLRMEngine:
         # hit-rate deltas); None until the cache exists
         self._cache_counter_state = None
 
+        # request geometry: each table's pooling L_t, the slots of a
+        # (T, max L_t) request within them (flat, table-major; what a
+        # packed batch keeps), and each such slot's table, position and
+        # row bound R_t; unsigned, for submit's range check
+        self._pooling = np.asarray(cfg.table_pooling(), np.uint64)
+        self._width = int(self._pooling.max())
+        table, self._live_pos = np.nonzero(
+            np.arange(self._width) < self._pooling[:, None])
+        self._live = table * self._width + self._live_pos
+        self._live_table = table
+        self._live_rows = np.asarray(cfg.table_rows(), np.uint64)[table]
+
         self.cache = None
         if cfg.cache.enabled or cfg.sharding_plan is not None:
+            if cfg.per_table:
+                raise NotImplementedError(
+                    "DLRMEngine: the tiered store over per-table "
+                    "rows_per_table / pooling needs per-table tiered "
+                    "metadata, not implemented (ROADMAP Reach 1)")
             if ctx is not None:
                 raise NotImplementedError(
                     "DLRMEngine: the tiered cache path scores on a single "
@@ -333,7 +356,7 @@ class DLRMEngine:
 
     def submit(self, req: CTRRequest):
         T = self.cfg.num_sparse_features
-        L = self.cfg.pooling
+        L = self._width
         F = self.cfg.num_dense_features
         # validate every field here: flush() pops requests before scoring,
         # so a shape error there would silently drop the whole micro-batch
@@ -345,15 +368,15 @@ class DLRMEngine:
                 f"{req.indices.shape} / {req.lengths.shape}")
         # dtypes too: float indices/lengths would be silently truncated by
         # the astype into the staging buffers and poison the jitted forward
-        if not np.issubdtype(req.indices.dtype, np.integer):
+        if req.indices.dtype.kind not in "iu":
             raise TypeError(
                 f"request {req.rid}: indices must be an integer dtype, "
                 f"got {req.indices.dtype}")
-        if not np.issubdtype(req.lengths.dtype, np.integer):
+        if req.lengths.dtype.kind not in "iu":
             raise TypeError(
                 f"request {req.rid}: lengths must be an integer dtype, "
                 f"got {req.lengths.dtype}")
-        if not np.issubdtype(req.dense.dtype, np.floating):
+        if req.dense.dtype.kind != "f":
             raise TypeError(
                 f"request {req.rid}: dense must be a float dtype, "
                 f"got {req.dense.dtype}")
@@ -361,16 +384,18 @@ class DLRMEngine:
         # wrong-but-silent score, the cached path would refuse the whole
         # micro-batch at prefetch — reject per-request instead, up front.
         # Only WITHIN-LENGTH slots are checked: padding beyond lengths is
-        # arbitrary (sentinels like -1 are masked downstream)
-        if req.lengths.size and (req.lengths.min() < 0
-                                 or req.lengths.max() > L):
-            raise ValueError(
-                f"request {req.rid}: lengths must be in [0, {L}]")
-        R = self.cfg.rows_per_table
-        live = req.indices[np.arange(L) < req.lengths[:, None]]
-        if live.size and (live.min() < 0 or live.max() >= R):
-            raise ValueError(
-                f"request {req.rid}: indices must be in [0, {R})")
+        # arbitrary (sentinels like -1 are masked downstream).  One
+        # comparison over all tables, unsigned so that a negative length
+        # or id reads as out of range
+        if (req.lengths.astype(np.uint64) > self._pooling).any():
+            raise ValueError(f"request {req.rid}: lengths must be in "
+                             f"[0, {self.cfg.pooling}]")
+        bad = req.indices.take(self._live).astype(np.uint64) \
+            >= self._live_rows
+        if bad.any() and (
+                bad & (self._live_pos < req.lengths[self._live_table])).any():
+            raise ValueError(f"request {req.rid}: indices must be in "
+                             f"[0, {self.cfg.rows_per_table})")
         self.queue.append(req)
         if self.telemetry is not None:
             self._enqueue_t[req.rid] = time.perf_counter()
@@ -380,7 +405,11 @@ class DLRMEngine:
     def _pad_batch(self, todo: List[CTRRequest]
                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Pad ``todo`` to the engine's fixed shapes: (B, F) dense,
-        (T, B, L) indices, (T, B) lengths — tail slots stay all-masked."""
+        (T, B, L) indices, (T, B) lengths — tail slots stay all-masked.
+        A per-table config packs instead: (B, sum L_t) ids of each
+        table's live slots, (B, T) lengths."""
+        if self.cfg.per_table:
+            return self._pack_batch(todo)
         B = self.batch_size
         T, L = self.cfg.num_sparse_features, self.cfg.pooling
         F = self.cfg.num_dense_features
@@ -392,6 +421,24 @@ class DLRMEngine:
             idx[:, i, :] = req.indices
             lens[:, i] = req.lengths
         return dense, idx, lens
+
+    def _pack_batch(self, todo: List[CTRRequest]
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        B = self.batch_size
+        T, F = self.cfg.num_sparse_features, self.cfg.num_dense_features
+        dense = np.zeros((B, F), np.float32)
+        ids = np.zeros((B, self._live.size), np.int32)
+        lens = np.zeros((B, T), np.int32)
+        for i, req in enumerate(todo):
+            dense[i] = req.dense
+            ids[i] = req.indices.take(self._live)
+            lens[i] = req.lengths
+        return dense, ids, lens
+
+    def _device_batch(self, idx, lens):
+        if self.cfg.per_table:
+            return PackedBatch(ids=jnp.asarray(idx), lengths=jnp.asarray(lens))
+        return JaggedBatch(indices=jnp.asarray(idx), lengths=jnp.asarray(lens))
 
     def flush(self) -> Dict[int, float]:
         """Score up to ``batch_size`` queued requests; returns rid -> pCTR."""
@@ -430,8 +477,7 @@ class DLRMEngine:
                 params = {**self.params, "tables": self.cache.pool}
             break
         with span("repro.engine.dispatch") as dispatch:
-            batch = JaggedBatch(indices=jnp.asarray(idx),
-                                lengths=jnp.asarray(lens))
+            batch = self._device_batch(idx, lens)
             scores = self._fwd(params, jnp.asarray(dense), batch)
         p = np.asarray(scores)
         with span("repro.engine.collect") as collect:
