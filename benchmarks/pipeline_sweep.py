@@ -82,8 +82,8 @@ NIC_BPS = 0.6e9
 class _NICDelayedHostStore(HostStore):
     """Host tables behind a modeled NIC: real rows + wire-time sleep."""
 
-    def fetch(self, t_ids, row_ids):
-        rows = super().fetch(t_ids, row_ids)
+    def fetch(self, t_ids, row_ids, out=None):
+        rows = super().fetch(t_ids, row_ids, out=out)
         time.sleep(rows.nbytes / NIC_BPS)
         return rows
 

@@ -94,11 +94,12 @@ class SchemaPin:
 
 
 PINNED_SCHEMAS: Tuple[SchemaPin, ...] = (
-    SchemaPin("repro/cache/stats.py", "as_dict", "SCHEMA_VERSION", 3,
+    SchemaPin("repro/cache/stats.py", "as_dict", "SCHEMA_VERSION", 4,
               frozenset({
                   "schema_version", "hits", "misses", "misses_host",
                   "misses_remote", "evictions", "bytes_h2d",
                   "bytes_remote", "fetch_host", "fetch_remote", "batches",
+                  "staging_allocs", "pad_rows",
                   "lookups", "hit_rate", "remote_miss_fraction", "hits_t",
                   "misses_t", "evictions_t", "lookups_t", "hit_rate_t",
                   "prefetch_s", "scatter_s", "forward_s", "overlap_s",

@@ -115,15 +115,16 @@ class CachedEmbeddingBag:
         self.mgr = SlotPoolManager(
             T, R, S, cc.policy,
             rows_per_host=self.cold.rows_per_host, home=self.cold.home)
+        # stats may be SHARED: the double-buffered pipeline pool passes
+        # one CacheStats so every buffer's traffic lands in one record
+        self.stats = stats if stats is not None else CacheStats()
         self.hot = SlotPool(T, self.mgr.S, D, self.dtype,
-                            slots_per_table=self.mgr.slots_per_table)
+                            slots_per_table=self.mgr.slots_per_table,
+                            stats=self.stats)
         # the kernel's scalar-prefetched per-table slot offsets — a jit
         # constant, so the jitted consumer compiles exactly once
         self._row_offsets = jnp.asarray(self.mgr.slot_offsets[:-1],
                                         jnp.int32)
-        # stats may be SHARED: the double-buffered pipeline pool passes
-        # one CacheStats so every buffer's traffic lands in one record
-        self.stats = stats if stats is not None else CacheStats()
         # optional obs tracer (duck-typed: anything with add_span) — the
         # engine attaches it so admit/fetch/scatter land on the unified
         # timeline's cache lane; None costs one attribute check
@@ -174,8 +175,9 @@ class CachedEmbeddingBag:
             try:
                 with span("repro.cache.fetch", self.tracer, "cache.fetch",
                           lane="cache", cat="cache", args=args) as fetch:
-                    rows = self.cold.fetch(plan.fetch_tables,
-                                           plan.fetch_rows)
+                    rows = self.cold.fetch(
+                        plan.fetch_tables, plan.fetch_rows,
+                        out=self.hot.staging(plan.fetch_rows.size))
                 with span("repro.cache.scatter", self.tracer,
                           "cache.scatter", lane="cache", cat="cache",
                           args=None if args is None else dict(args)) \

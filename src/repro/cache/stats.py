@@ -32,7 +32,16 @@ Counting semantics (matched by the numpy simulation in tests/test_cache.py):
     sweeps), and the planner prices a distinct ``cache_rows``/
     ``est_hit_rate`` per table, so the measured hit rate must be
     checkable at the same granularity (``hit_rate_t``) — that is the
-    planner -> engine round trip's feedback signal.
+    planner -> engine round trip's feedback signal;
+  * ``staging_allocs`` counts the times a slot pool allocated its host
+    staging buffer or resized it to another power of two: the missed
+    rows a cold tier gathers into it move to the device with no further
+    host copy, so a count that stops growing says the reused buffer
+    serves every prefetch;
+  * ``pad_rows`` counts the padding rows scattered: each pool scatter
+    pads its M rows to the next power of two with copies of the last
+    row, so ``pad_rows / (fetch_host + fetch_remote + pad_rows)`` is the
+    share of scattered rows that are padding.
 
 Stage timers (PR 4, the pipelined serving subsystem): the SAME spans are
 recorded whichever engine serves, so the serialized and pipelined paths
@@ -94,6 +103,8 @@ class CacheStats:
     fetch_host: int = 0
     fetch_remote: int = 0
     batches: int = 0
+    staging_allocs: int = 0
+    pad_rows: int = 0
     # per-table splits — (T,) int64, None until the first per-table update
     hits_t: Optional[np.ndarray] = None
     misses_t: Optional[np.ndarray] = None
@@ -108,7 +119,8 @@ class CacheStats:
     # bump when as_dict() keys change meaning or spelling — benchmark
     # CSVs and the plan-roundtrip assertions key off this contract.
     # v3: always-present "lookups" / "lookups_t" keys
-    SCHEMA_VERSION = 3
+    # v4: "staging_allocs" / "pad_rows"
+    SCHEMA_VERSION = 4
 
     @property
     def lookups(self) -> int:
@@ -217,6 +229,7 @@ class CacheStats:
         self.hits = self.misses = self.misses_host = self.misses_remote = 0
         self.evictions = self.bytes_h2d = self.bytes_remote = 0
         self.fetch_host = self.fetch_remote = self.batches = 0
+        self.staging_allocs = self.pad_rows = 0
         self.hits_t = self.misses_t = self.evictions_t = None
         self.prefetch_s = self.scatter_s = 0.0
         self.forward_s = self.overlap_s = 0.0
@@ -244,6 +257,8 @@ class CacheStats:
             "fetch_host": self.fetch_host,
             "fetch_remote": self.fetch_remote,
             "batches": self.batches,
+            "staging_allocs": self.staging_allocs,
+            "pad_rows": self.pad_rows,
             "lookups": self.lookups,
             "hit_rate": self.hit_rate,
             "remote_miss_fraction": self.remote_miss_fraction,

@@ -10,8 +10,10 @@ PAPERS.md), so the store is now a tier stack behind one small interface:
     (``slot_offsets[t] + slot``).  Rows are written by ONE flat scatter
     per prefetch (jitted, pool donated — in-place on accelerators).
   * :class:`HostStore`  — tier "host": the full ``(T, R, D)`` tables in
-    the serving host's memory (numpy); a fetch is a fancy-index gather
-    that crosses the host<->device link.
+    the serving host's memory (numpy); a fetch is one ``np.take`` gather
+    over their flat ``(T*R, D)`` view, written straight into the pool's
+    host staging buffer, which the scatter then moves across the
+    host<->device link.
   * :class:`RemoteStore` — tier "remote": every table row-split across
     ``hosts`` ranks (host h owns rows ``[h*R/H, (h+1)*R/H)`` of every
     table, the paper's RW layout §4.2); a fetch is ONE batched
@@ -41,9 +43,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cache.stats import CacheStats
 from repro.core import comm
 from repro.obs.trace import span
 from jax import shard_map
+
+
+def _pow2(m: int) -> int:
+    """The power of two an (m, ...) scatter operand is padded to."""
+    return 1 << (m - 1).bit_length()
 
 
 def _pad_pow2(arrays):
@@ -51,7 +59,7 @@ def _pad_pow2(arrays):
     last element — idempotent duplicates, bounds the jit shape count to
     O(log M_max) instead of one program per distinct M."""
     m = arrays[0].shape[0]
-    pad = (1 << (m - 1).bit_length()) - m
+    pad = _pow2(m) - m
     if not pad:
         return arrays
     return [np.concatenate([a, np.repeat(a[-1:], pad, axis=0)])
@@ -76,8 +84,10 @@ class TableStore(abc.ABC):
         """Rows of each table owned by one host (R for single-host tiers)."""
 
     @abc.abstractmethod
-    def fetch(self, t_ids: np.ndarray, row_ids: np.ndarray) -> np.ndarray:
-        """(M,) table ids x (M,) table-local row ids -> (M, D) payloads."""
+    def fetch(self, t_ids: np.ndarray, row_ids: np.ndarray,
+              out: Optional[np.ndarray] = None) -> np.ndarray:
+        """(M,) table ids x (M,) table-local row ids -> (M, D) payloads,
+        written into ``out`` (and returned) when one is given."""
 
 
 # ---------------------------------------------------------------------------
@@ -109,12 +119,20 @@ class SlotPool(TableStore):
     reallocated — ``scatter`` replaces the array functionally (the
     donated jit updates it in place on accelerators), so the jitted
     consumer compiles exactly once.
+
+    The pool also owns a host staging buffer, ``(P, D)`` in its dtype
+    with P the power of two the scatter pads to: a cold tier gathers a
+    prefetch's M missed rows straight into ``staging(M)``, and
+    ``scatter`` fills rows ``[M, P)`` with the last row and moves the
+    buffer as it is — each missed row is copied on the host once.  The
+    buffer is reallocated only when P changes (``stats.staging_allocs``).
     """
 
     tier = "hbm"
 
     def __init__(self, num_tables: int, slots: int, dim: int, dtype,
-                 *, slots_per_table=None):
+                 *, slots_per_table=None,
+                 stats: Optional[CacheStats] = None):
         if slots_per_table is None:
             slots_per_table = np.full(num_tables, slots, np.int64)
         self.slots_per_table = np.asarray(slots_per_table, np.int64)
@@ -126,6 +144,9 @@ class SlotPool(TableStore):
         self.slot_offsets = np.zeros(num_tables + 1, np.int64)
         np.cumsum(self.slots_per_table, out=self.slot_offsets[1:])
         self.array = jnp.zeros((int(self.slot_offsets[-1]), dim), dtype)
+        self.stats = stats if stats is not None else CacheStats()
+        self._staging: Optional[np.ndarray] = None
+        self._view: Optional[np.ndarray] = None
 
     @property
     def slots(self) -> int:
@@ -152,11 +173,38 @@ class SlotPool(TableStore):
         addr = self.slot_offsets[np.asarray(t_ids)] + np.asarray(slot_ids)
         return np.asarray(self.array)[addr]
 
+    def staging(self, m: int) -> np.ndarray:
+        """The ``(m, D)`` head of the host staging buffer, for a cold
+        tier's ``fetch(..., out=)``; ``m >= 1``.
+
+        Waits first for the pool's last scatter: the device array made
+        from the buffer may alias it (CPU) or still be reading it, and
+        the buffer is about to be written again."""
+        p = _pow2(m)
+        self.array.block_until_ready()
+        if self._staging is None or self._staging.shape[0] != p:
+            self._staging = np.empty((p,) + self.array.shape[1:],
+                                     self.array.dtype)
+            self.stats.staging_allocs += 1
+        self._view = self._staging[:m]
+        return self._view
+
     def scatter(self, flat_addr: np.ndarray, rows) -> None:
         """One flat scatter of (M, D) ``rows`` at ``slot_offsets[t] +
-        slot`` addresses (see ``PrefetchPlan.flat_addr``)."""
-        flat_addr, rows = _pad_pow2([np.asarray(flat_addr, np.int64),
-                                     np.asarray(rows)])
+        slot`` addresses (see ``PrefetchPlan.flat_addr``).
+
+        Rows gathered into :meth:`staging` move as the padded buffer
+        they already sit in; rows from anywhere else are padded into a
+        new one."""
+        flat_addr = np.asarray(flat_addr, np.int64)
+        if rows is self._view:
+            m, rows = rows.shape[0], self._staging
+            rows[m:] = rows[m - 1]
+            (flat_addr,) = _pad_pow2([flat_addr])
+        else:
+            m = flat_addr.shape[0]
+            flat_addr, rows = _pad_pow2([flat_addr, np.asarray(rows)])
+        self.stats.pad_rows += rows.shape[0] - m
         with warnings.catch_warnings():
             # CPU backends skip donation with a warning; harmless
             warnings.simplefilter("ignore")
@@ -169,22 +217,51 @@ class SlotPool(TableStore):
 # ---------------------------------------------------------------------------
 
 class HostStore(TableStore):
-    """Tier "host": the full ``(T, R, D)`` tables in local host memory."""
+    """Tier "host": the full ``(T, R, D)`` tables in local host memory.
+
+    A fetch is one unbuffered ``np.take`` over the tables' flat
+    ``(T*R, D)`` view (a reshape of the C-contiguous tables, rebuilt
+    whenever ``tables`` is set) at ``t * R + r``; the ids are range
+    checked first, since ``mode="clip"`` would otherwise read row
+    ``r - R`` of table ``t + 1`` for an ``r >= R``."""
 
     tier = "host"
 
     def __init__(self, tables):
-        self.tables = np.asarray(tables)
-        if self.tables.ndim != 3:
-            raise ValueError(
-                f"tables must be (T, R, D), got {self.tables.shape}")
+        self.tables = tables
+
+    @property
+    def tables(self) -> Optional[np.ndarray]:
+        return self._tables
+
+    @tables.setter
+    def tables(self, value) -> None:
+        # None is a test hook: a store that cannot fetch
+        self._tables = self._flat = None
+        if value is not None:
+            value = np.ascontiguousarray(value)
+            if value.ndim != 3:
+                raise ValueError(
+                    f"tables must be (T, R, D), got {value.shape}")
+            self._tables = value
+            self._flat = value.reshape(-1, value.shape[2])
 
     @property
     def rows_per_host(self) -> int:
         return self.tables.shape[1]
 
-    def fetch(self, t_ids, row_ids) -> np.ndarray:
-        return self.tables[t_ids, row_ids]
+    def fetch(self, t_ids, row_ids, out=None) -> np.ndarray:
+        if self._flat is None:
+            raise TypeError("HostStore holds no tables to fetch from")
+        T, R = self._tables.shape[:2]
+        t_ids = np.asarray(t_ids, np.int64)
+        row_ids = np.asarray(row_ids, np.int64)
+        if t_ids.size and (t_ids.min() < 0 or t_ids.max() >= T
+                           or row_ids.min() < 0 or row_ids.max() >= R):
+            raise IndexError(
+                f"fetch ids out of range: tables [0, {T}), rows [0, {R})")
+        return np.take(self._flat, t_ids * R + row_ids, axis=0, out=out,
+                       mode="clip")
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +341,7 @@ class RemoteStore(TableStore):
     def owner_of(self, row_ids: np.ndarray) -> np.ndarray:
         return np.asarray(row_ids) // self._rows_per_host
 
-    def fetch(self, t_ids, row_ids) -> np.ndarray:
+    def fetch(self, t_ids, row_ids, out=None) -> np.ndarray:
         t_ids = np.asarray(t_ids, np.int64)
         row_ids = np.asarray(row_ids, np.int64)
         owner = (row_ids // self._rows_per_host).astype(np.int32)
@@ -273,12 +350,12 @@ class RemoteStore(TableStore):
         m = local.shape[0]
         local, owner = _pad_pow2([local, owner])
         with span("repro.comm.fetch_rows") as wire:
-            out = self._fetch(self.shards, jnp.asarray(local),
-                              jnp.asarray(owner))
+            payload = self._fetch(self.shards, jnp.asarray(local),
+                                  jnp.asarray(owner))
             # device->host roundtrip: the payloads land on the serving
             # host (modeling NIC -> host RAM) before the pool scatter
             # moves them h2d
-            result = np.asarray(out)[:m]
+            result = np.asarray(payload)[:m]
         # compiled programs never re-trace, so comm._record's trace-time
         # event carries no per-execution wall clock — record the measured
         # dispatch->materialize interval with the stacked payload bytes
@@ -289,7 +366,10 @@ class RemoteStore(TableStore):
             self.hosts * local.shape[0] * self.shards.shape[-1]
             * self.shards.dtype.itemsize,
             self.hosts, self.backend, wire.t0, wire.t1)
-        return result
+        if out is None:
+            return result
+        out[...] = result
+        return out
 
 
 # ---------------------------------------------------------------------------

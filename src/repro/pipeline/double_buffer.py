@@ -124,11 +124,12 @@ class DoubleBufferedSlotPool:
         return self.buffers[plan.epoch % self.depth]
 
     def fetch_next(self, plan: PrefetchPlan) -> Optional[np.ndarray]:
-        """FETCH: pull the plan's missed rows from the cold tier.
+        """FETCH: pull the plan's missed rows from the cold tier into the
+        owning buffer's pool staging (:meth:`SlotPool.staging`).
 
         Pure host-side work (numpy gather or the ``fetch_rows``
-        collective) touching only the shadow manager on failure — safe
-        to run on a background thread while the live forward computes.
+        collective) touching only the shadow buffer — safe to run on a
+        background thread while the live forward computes.
         A failed fetch invalidates the plan's committed residency so the
         shadow never claims uncopied rows (stale-slot invalidation).
         """
@@ -136,7 +137,9 @@ class DoubleBufferedSlotPool:
             return None
         bag = self._owner_of(plan)
         try:
-            return bag.cold.fetch(plan.fetch_tables, plan.fetch_rows)
+            return bag.cold.fetch(
+                plan.fetch_tables, plan.fetch_rows,
+                out=bag.hot.staging(plan.fetch_rows.size))
         except BaseException:
             bag.mgr.invalidate_fetch(plan)
             raise

@@ -233,11 +233,12 @@ def test_flat_pool_bytes_exact():
 def test_cache_stats_schema():
     d = CacheStats().as_dict()
     assert next(iter(d)) == "schema_version"
-    assert d["schema_version"] == CacheStats.SCHEMA_VERSION == 3
+    assert d["schema_version"] == CacheStats.SCHEMA_VERSION == 4
     assert set(d) == {
         "schema_version", "hits", "misses", "misses_host", "misses_remote",
         "evictions", "bytes_h2d", "bytes_remote", "fetch_host",
-        "fetch_remote", "batches", "lookups", "hit_rate",
+        "fetch_remote", "batches", "staging_allocs", "pad_rows",
+        "lookups", "hit_rate",
         "remote_miss_fraction", "hits_t", "misses_t", "evictions_t",
         "lookups_t", "hit_rate_t",
         "prefetch_s", "scatter_s", "forward_s", "overlap_s",

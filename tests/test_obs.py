@@ -402,7 +402,7 @@ def test_serial_engine_records_latency_and_cache_spans():
     # the producer surfaces live CacheStats in the snapshot
     snap = tel.metrics.snapshot()
     prod = snap["producers"]["dlrm.cache"]
-    assert prod["schema_version"] == 3 and prod["lookups"] > 0
+    assert prod["schema_version"] == 4 and prod["lookups"] > 0
 
 
 def test_request_latency_under_pipelined_requeue_on_failure():
@@ -421,11 +421,11 @@ def test_request_latency_under_pipelined_requeue_on_failure():
     cold = piped.cache.buffers[0].cold
     real_fetch, calls = cold.fetch, {"n": 0}
 
-    def flaky(t, r):
+    def flaky(t, r, out=None):
         calls["n"] += 1
         if calls["n"] == 2:
             raise RuntimeError("transient cold-tier failure")
-        return real_fetch(t, r)
+        return real_fetch(t, r, out=out)
 
     cold.fetch = flaky
     try:

@@ -9,13 +9,19 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cache import CacheConfig, CacheStats
+from repro.cache import CacheConfig, CacheStats, CachedEmbeddingBag
 from repro.cache.manager import CacheCapacityError
 from repro.configs import dlrm as dlrm_cfg
-from repro.core.embedding_bag import EmbeddingBagConfig, init_tables
+from repro.core.embedding_bag import (
+    EmbeddingBagConfig,
+    init_tables,
+    pooled_lookup_local,
+)
+from repro.core.jagged import JaggedBatch
 from repro.core.perf_model import (
     H100_DGX,
     TPU_V5E,
@@ -119,7 +125,7 @@ def test_double_buffer_stale_slot_invalidation_on_fetch_failure():
     assert (shadow.mgr.slot_of_id[0, :6] >= 0).all()   # residency committed
 
     real_fetch = shadow.cold.fetch
-    shadow.cold.fetch = lambda *a: (_ for _ in ()).throw(
+    shadow.cold.fetch = lambda *a, **kw: (_ for _ in ()).throw(
         RuntimeError("injected cold-tier failure"))
     try:
         with pytest.raises(RuntimeError, match="injected"):
@@ -147,6 +153,36 @@ def test_double_buffer_capacity_error_is_atomic():
     with pytest.raises(CacheCapacityError):
         pool.prepare_next(idx, None)
     assert (pool.shadow.mgr.id_of_slot < 0).all()  # nothing half-admitted
+
+
+def test_double_buffer_staged_fetch_bitwise_equals_serialized_bag():
+    """Depth 2 with each buffer's fetch gathered into its pool staging:
+    every micro-batch's output is bitwise the serialized bag's and the
+    uncached oracle's, across evictions and staging size changes."""
+    cfg = _bag_cfg(T=3, R=64, D=8, cache_rows=16)
+    tables = init_tables(jax.random.key(9), cfg)
+    ring = DoubleBufferedSlotPool(tables, cfg, depth=2)
+    serial = CachedEmbeddingBag(tables, cfg)
+    rng = np.random.default_rng(10)
+    for _ in range(8):
+        idx = rng.integers(0, 64, (3, 4, 3)).astype(np.int32)
+        lens = rng.integers(1, 4, (3, 4)).astype(np.int32)
+        plan = ring.prepare_next(idx, lens)
+        rows = ring.fetch_next(plan)
+        if rows is not None:            # gathered into the owner's staging
+            assert rows.base is ring._owner_of(plan).hot._staging
+        ring.commit_next(plan, rows)
+        ring.swap()
+        got = ring.live.device_lookup(ring.pool, jnp.asarray(plan.remapped),
+                                      jnp.asarray(lens), None)
+        batch = JaggedBatch(jnp.asarray(idx), jnp.asarray(lens))
+        want = serial.lookup(batch)
+        oracle = pooled_lookup_local(tables, batch, cfg)
+        for ref in (want, oracle):
+            np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                          np.asarray(ref).view(np.uint32))
+    assert ring.stats.evictions > 0 and ring.stats.staging_allocs > 1
+    assert serial.stats.staging_allocs > 1
 
 
 # ---------------------------------------------------------------------------
@@ -248,11 +284,11 @@ def test_pipeline_error_requeues_requests():
     cold = piped.cache.buffers[0].cold            # shared by both buffers
     real_fetch, calls = cold.fetch, {"n": 0}
 
-    def flaky(t, r):
+    def flaky(t, r, out=None):
         calls["n"] += 1
         if calls["n"] == 2:
             raise RuntimeError("transient cold-tier failure")
-        return real_fetch(t, r)
+        return real_fetch(t, r, out=out)
 
     cold.fetch = flaky
     try:

@@ -111,6 +111,110 @@ def test_remote_store_rejects_uneven_rows():
 
 
 # ---------------------------------------------------------------------------
+# Staged gather: missed rows straight into the pool's reused host buffer
+# ---------------------------------------------------------------------------
+
+def _bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+def test_staged_gather_is_bitwise_two_index():
+    rng = np.random.default_rng(5)
+    tables = rng.standard_normal((4, 50, 8)).astype(np.float32)
+    store = HostStore(tables)
+    t = rng.integers(0, 4, 37)                 # mixed tables, repeats
+    r = rng.integers(0, 50, 37)
+    out = np.full((37, 8), np.nan, np.float32)
+    got = store.fetch(t, r, out=out)
+    assert got is out
+    np.testing.assert_array_equal(_bits(got), _bits(tables[t, r]))
+    np.testing.assert_array_equal(_bits(store.fetch(t, r)),
+                                  _bits(tables[t, r]))
+    # the flat view follows the tables when they are set again
+    other = -tables
+    store.tables = other
+    np.testing.assert_array_equal(_bits(store.fetch(t, r, out=out)),
+                                  _bits(other[t, r]))
+
+
+def test_staged_gather_refuses_out_of_range_ids():
+    """``mode="clip"`` must not hide a bad id: row R of table t would be
+    row 0 of table t + 1 in the flat view."""
+    tables = np.arange(3 * 4 * 2, dtype=np.float32).reshape(3, 4, 2)
+    store = HostStore(tables)
+    out = np.full((2, 2), -1, np.float32)
+    for t, r in ((0, 4), (1, 4), (0, -1), (3, 0), (-1, 0)):
+        with pytest.raises(IndexError):
+            store.fetch(np.array([0, t]), np.array([1, r]), out=out)
+        assert (out == -1).all()               # nothing was read
+
+
+def test_staged_pool_contents_across_pow2_miss_counts():
+    """Prefetches whose miss counts cross powers of two (staging sizes
+    4, 4, 8, 128, 1): every resident slot holds its table row bitwise,
+    the buffer is reallocated only at a size change, and the padding
+    is P - M rows per scatter."""
+    cfg = _cfg(T=2, R=256, cache_rows=128)
+    tables = init_tables(jax.random.key(6), cfg)
+    host = np.asarray(tables)
+    bag = make_cache(tables, cfg)
+    fresh = [0, 0]
+    for m in (3, 4, 5, 100, 1):
+        n = (m - m // 2, m // 2)               # split over both tables
+        idx = np.zeros((2, 1, n[0]), np.int32)
+        for t in (0, 1):
+            idx[t, 0, :n[t]] = fresh[t] + np.arange(n[t])
+            fresh[t] += n[t]
+        bag.prefetch_arrays(idx, np.array([[n[0]], [n[1]]], np.int32))
+        for t in (0, 1):
+            ids = np.arange(fresh[t])
+            got = bag.hot.fetch(np.full_like(ids, t),
+                                bag.mgr.slot_of_id[t, ids])
+            np.testing.assert_array_equal(_bits(got), _bits(host[t, ids]))
+    s = bag.stats
+    assert s.misses == s.fetch_host == 113 and s.evictions == 0
+    assert s.staging_allocs == 4               # P: 4, 4, 8, 128, 1
+    assert s.pad_rows == 1 + 0 + 3 + 28 + 0
+
+
+def test_staged_reuse_fence_two_prefetches_without_forward():
+    """Two gathers of one staging size into one pool, nothing reading
+    the pool between them: the second gather must wait for the first
+    scatter, which may still be reading the buffer (on CPU the device
+    array aliases it and the scatter runs asynchronously)."""
+    rng = np.random.default_rng(8)
+    tables = rng.standard_normal((2, 16384, 128)).astype(np.float32)
+    store = HostStore(tables)
+    pool = SlotPool(num_tables=2, slots=32768, dim=128, dtype=np.float32)
+    m = 16384
+    for _ in range(8):
+        t = rng.integers(0, 2, (2, m))
+        r = rng.integers(0, 16384, (2, m))
+        for k in range(2):
+            rows = store.fetch(t[k], r[k], out=pool.staging(m))
+            pool.scatter(np.arange(m) + k * m, rows)
+        got = np.asarray(pool.array)
+        for k in range(2):
+            np.testing.assert_array_equal(_bits(got[k * m:(k + 1) * m]),
+                                          _bits(tables[t[k], r[k]]))
+    assert pool.stats.staging_allocs == 1 and pool.stats.pad_rows == 0
+
+
+def test_staging_allocs_counts_only_size_changes():
+    pool = SlotPool(num_tables=1, slots=4, dim=2, dtype=np.float32)
+    for m, allocs in ((5, 1), (8, 1), (6, 1), (9, 2), (16, 2), (3, 3),
+                      (4, 3), (1, 4), (1, 4), (2, 5)):
+        view = pool.staging(m)
+        assert view.shape == (m, 2) and view.dtype == np.float32
+        assert pool.stats.staging_allocs == allocs, m
+    # rows from anywhere else are padded as before, with no allocation
+    pool.scatter(np.array([0, 1, 2]), np.ones((3, 2), np.float32))
+    assert pool.stats.staging_allocs == 5 and pool.stats.pad_rows == 1
+    np.testing.assert_array_equal(pool.fetch([0, 0, 0], [0, 1, 2]),
+                                  np.ones((3, 2), np.float32))
+
+
+# ---------------------------------------------------------------------------
 # Warmup from logged frequencies
 # ---------------------------------------------------------------------------
 
