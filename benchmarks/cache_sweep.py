@@ -48,7 +48,6 @@ from repro.core.perf_model import (
     zipf_hit_rate,
 )
 from repro.obs import SweepReport
-from repro.obs.bench import make_bench_record, make_metric, write_bench
 
 RATIOS = (0.005, 0.01, 0.05, 0.20)
 ZIPF_AS = (1.05, 1.2)
@@ -160,8 +159,6 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes + interpret-mode exactness (CI)")
-    ap.add_argument("--bench", type=str, default="BENCH_cache.json",
-                    help="BenchRecord output ('' to skip)")
     args = ap.parse_args()
 
     launches = count_cached_launches(SMOKE)
@@ -186,21 +183,6 @@ def main():
     for a in ZIPF_AS:
         curve = ", ".join(f"{r*100:g}%={by[(r, a)]:.3f}" for r in ratios)
         print(f"# zipf a={a} hit-rate vs cache ratio: {curve}")
-
-    if args.bench:
-        shape = SMOKE if args.smoke else FULL
-        # seeded traffic + deterministic eviction -> hit rates are exact
-        # replays: tight tolerances gate any cache-policy regression
-        metrics = {"pallas_launches": make_metric(
-            launches, "1", "lower_is_better", 0.0)}
-        for (ratio, a), hr in sorted(by.items()):
-            metrics[f"hit_rate_r{ratio:g}_a{a:g}"] = make_metric(
-                hr, "1", "higher_is_better", 0.02)
-        record = make_bench_record(
-            "cache", config=dict(shape, smoke=args.smoke, zipf_as=ZIPF_AS),
-            metrics=metrics)
-        write_bench(args.bench, record)
-        print(f"# wrote {args.bench}")
 
 
 if __name__ == "__main__":

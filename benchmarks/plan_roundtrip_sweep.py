@@ -55,7 +55,6 @@ from repro.core.perf_model import (
 from repro.core.sharding_plan import TableSpec, plan
 from repro.models import dlrm as dlrm_mod
 from repro.obs import SweepReport
-from repro.obs.bench import make_bench_record, make_metric, write_bench
 from repro.serving.engine import CTRRequest, make_dlrm_engine
 
 ZIPF_A = 0.9          # <= 1: exercises the truncated-zeta hit-rate fix
@@ -203,16 +202,13 @@ def report(shape, p, stats) -> str:
     assert rel <= TOL_FETCH, \
         f"measured fetch traffic off the unique-miss model by {rel:.3f}" \
         f" (> {TOL_FETCH})"
-    return rep.csv(), {"worst_hit_err": worst_hit, "fetch_rel_err": rel,
-                       "fetch_rows_per_batch": meas_per_batch}
+    return rep.csv()
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny measured shapes (CI)")
-    ap.add_argument("--bench", type=str, default="BENCH_plan.json",
-                    help="BenchRecord output ('' to skip)")
     args = ap.parse_args()
     shape = SMOKE if args.smoke else FULL
 
@@ -230,29 +226,8 @@ def main():
 
     stats = roundtrip(shape, p)
     print(f"# measured: {stats}")
-    csv, res = report(shape, p, stats)
-    print(csv)
+    print(report(shape, p, stats))
     print("# OK: plan prices check out against measured serving stats")
-
-    if args.bench:
-        # seeded traffic + deterministic warmup -> every number replays
-        # exactly; tolerances are RELATIVE to the blessed baseline, so
-        # 0.5 lets the small error metrics move by half before gating
-        # (still far inside the sweep's own TOL_* assertion bars)
-        record = make_bench_record(
-            "plan_roundtrip",
-            config=dict(shape, smoke=args.smoke, zipf_a=ZIPF_A),
-            metrics={
-                "worst_hit_err": make_metric(
-                    res["worst_hit_err"], "1", "lower_is_better", 0.5),
-                "fetch_rel_err": make_metric(
-                    res["fetch_rel_err"], "1", "lower_is_better", 0.5),
-                "fetch_rows_per_batch": make_metric(
-                    res["fetch_rows_per_batch"], "rows",
-                    "lower_is_better", 0.05),
-            })
-        write_bench(args.bench, record)
-        print(f"# wrote {args.bench}")
 
 
 if __name__ == "__main__":

@@ -60,7 +60,6 @@ from repro.core.perf_model import (
 )
 from repro.core.sharding_plan import TableSpec, plan
 from repro.obs import SweepReport
-from repro.obs.bench import make_bench_record, make_metric, write_bench
 
 HOSTS = (1, 2, 8, 32, 128)
 RATIOS = (0.005, 0.01, 0.05, 0.20)
@@ -196,8 +195,6 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny measured shapes (CI)")
-    ap.add_argument("--bench", type=str, default="BENCH_tiering.json",
-                    help="BenchRecord output ('' to skip)")
     args = ap.parse_args()
     shape = SMOKE if args.smoke else FULL
 
@@ -230,25 +227,6 @@ def main():
     print(f"# cached placements: {n_cached}")
     assert n_cached >= 1, \
         "the planner must price at least one table as 'cached' here"
-
-    if args.bench:
-        # seeded traffic on a deterministic LRU/LFU pool -> exact replays
-        record = make_bench_record(
-            "tiering",
-            config=dict(shape, smoke=args.smoke, zipf_a=ZIPF_A,
-                        hosts=m["hosts"]),
-            metrics={
-                "hit_rate": make_metric(
-                    s.hit_rate, "1", "higher_is_better", 0.02),
-                "remote_miss_fraction": make_metric(
-                    s.remote_miss_fraction, "1", "lower_is_better", None),
-                "pallas_launches": make_metric(
-                    m["launches"], "1", "lower_is_better", 0.0),
-                "cached_placements": make_metric(
-                    n_cached, "1", "higher_is_better", 0.0),
-            })
-        write_bench(args.bench, record)
-        print(f"# wrote {args.bench}")
 
 
 if __name__ == "__main__":

@@ -17,7 +17,6 @@ impls / CW / TW) for correctness and traces the collective traffic each
 one issues (the paper's phase structure).
 """
 import dataclasses
-import time
 
 import numpy as np
 import jax
@@ -115,13 +114,8 @@ def main():
         traffic = {}
         for e in events:
             traffic[e.op] = traffic.get(e.op, 0) + e.bytes_in
-        t0 = time.perf_counter()
-        jax.jit(lambda p, d, b: dlrm_mod.forward(p, d, b, cfg, ctx))(
-            params, dense, batch).block_until_ready()
-        dt = time.perf_counter() - t0
         print(f"{sharding:7s}{('/' + impl) if impl else '':11s} "
-              f"err={err:.1e}  traffic={traffic}  ({dt*1e3:.0f} ms incl. "
-              f"compile)")
+              f"err={err:.1e}  traffic={traffic}")
     print("OK: every sharding strategy reproduces the oracle.")
 
 

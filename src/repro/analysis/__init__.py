@@ -43,8 +43,7 @@ contracts without cycles, and the lint/protocol layers stay usable on
 a tree whose runtime modules don't even import.
 
 CLI: ``python -m repro.analysis`` (``--lint --contracts --protocol``,
-default all three; ``--protocol-trace PATH`` replays a recorded
-``pipeline_sweep.py --stage-trace`` artifact).  Exit 1 on any
+default all three).  Exit 1 on any
 violation — the CI ``static-analysis`` job is exactly this command.
 """
 from repro.analysis.contracts import (
@@ -69,7 +68,6 @@ from repro.analysis.protocol import (
     ProtocolViolation,
     check_scheduler_source,
     check_timeline,
-    load_timeline,
 )
 
 __all__ = [
@@ -90,5 +88,4 @@ __all__ = [
     "ProtocolViolation",
     "check_scheduler_source",
     "check_timeline",
-    "load_timeline",
 ]

@@ -5,13 +5,11 @@ this command IS the CI ``static-analysis`` gate.
 
     python -m repro.analysis                       # lint+contracts+protocol
     python -m repro.analysis --lint src/ tests/    # lint only, these paths
-    python -m repro.analysis --protocol-trace pipeline_trace.json
 """
 from __future__ import annotations
 
 import argparse
 import sys
-from typing import List
 
 DEFAULT_LINT_PATHS = ("src", "tests", "benchmarks")
 
@@ -40,21 +38,12 @@ def _run_contracts() -> int:
     return failures
 
 
-def _run_protocol(trace_path: str | None) -> int:
-    from repro.analysis.protocol import (check_scheduler_source,
-                                         check_timeline, load_timeline)
+def _run_protocol() -> int:
+    from repro.analysis.protocol import check_scheduler_source
 
-    violations: List = list(check_scheduler_source())
-    source_n = len(violations)
+    violations = check_scheduler_source()
     print(f"protocol: scheduler call-order check — "
-          f"{'ok' if not source_n else f'{source_n} violation(s)'}")
-    if trace_path:
-        spans, depth = load_timeline(trace_path)
-        timeline = check_timeline(spans, depth)
-        print(f"protocol: timeline {trace_path} ({len(spans)} spans, "
-              f"depth {depth}) — "
-              f"{'ok' if not timeline else f'{len(timeline)} violation(s)'}")
-        violations += timeline
+          f"{'ok' if not violations else f'{len(violations)} violation(s)'}")
     for v in violations:
         print(f"PROTOCOL  {v}")
     return len(violations)
@@ -68,16 +57,11 @@ def main(argv=None) -> int:
     parser.add_argument("--lint", action="store_true")
     parser.add_argument("--contracts", action="store_true")
     parser.add_argument("--protocol", action="store_true")
-    parser.add_argument("--protocol-trace", metavar="PATH",
-                        help="replay a pipeline_sweep.py --stage-trace "
-                             "JSON artifact (implies --protocol)")
     parser.add_argument("paths", nargs="*",
                         help=f"lint roots (default: "
                              f"{' '.join(DEFAULT_LINT_PATHS)})")
     args = parser.parse_args(argv)
 
-    if args.protocol_trace:
-        args.protocol = True
     if not (args.lint or args.contracts or args.protocol):
         args.lint = args.contracts = args.protocol = True
 
@@ -87,7 +71,7 @@ def main(argv=None) -> int:
     if args.contracts:
         failures += _run_contracts()
     if args.protocol:
-        failures += _run_protocol(args.protocol_trace)
+        failures += _run_protocol()
 
     if failures:
         print(f"\nFAILED: {failures} violation(s)")

@@ -18,10 +18,9 @@ either bitten this repo or is one refactor away from doing so:
       configs' escape hatch must stay construction-only.
   ``schema-pin``               key-set or version drift in the pinned
       serialization schemas (``CacheStats.as_dict``,
-      ``MetricsRegistry.snapshot``, ``SLOEvent.to_dict``,
-      ``write_snapshot``, ``make_bench_record``) — changing keys
-      without bumping the ``SCHEMA_VERSION`` breaks committed bench
-      baselines; bumping without updating the pin here means the
+      ``MetricsRegistry.snapshot``, ``SLOEvent.to_dict``) — changing
+      keys without bumping the ``SCHEMA_VERSION`` breaks every reader
+      of the old shape; bumping without updating the pin here means the
       contract was changed without review.
   ``export-drift``             ``__all__`` naming something the module
       never binds (or naming it twice) — a stale export is an
@@ -112,14 +111,6 @@ PINNED_SCHEMAS: Tuple[SchemaPin, ...] = (
               frozenset({
                   "schema_version", "kind", "rule", "tick", "engine",
                   "measured", "threshold", "table", "expected"})),
-    SchemaPin("repro/obs/export.py", "write_snapshot",
-              "SNAPSHOT_SCHEMA_VERSION", 2,
-              frozenset({"schema_version", "provenance", "metrics"})),
-    SchemaPin("repro/obs/bench.py", "make_bench_record",
-              "BENCH_SCHEMA_VERSION", 1,
-              frozenset({
-                  "schema_version", "sweep", "provenance", "config",
-                  "config_hash", "metrics"})),
 )
 
 

@@ -27,14 +27,13 @@ output equality.  This module checks the protocol itself, three ways:
     live-buffer read (forward span of batch k, reading the same slot)
     — and that each batch's own scatter fully precedes its forward.
     Flags a deliberately injected stale-commit race; stays silent on
-    every real engine/sweep trace.
+    every real engine trace.
 """
 from __future__ import annotations
 
 import ast
 import dataclasses
 import inspect
-import json
 import textwrap
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -292,18 +291,3 @@ def check_timeline(spans: Sequence, depth: int = 2,
                     f"overlaps batch {r.batch}'s forward reading the "
                     f"same slot [{r.start:.6f}, {r.end:.6f}]"))
     return violations
-
-
-def load_timeline(path: str) -> Tuple[List[TimelineSpan], int]:
-    """Load a ``pipeline_sweep.py --stage-trace`` JSON artifact:
-    ``{"schema_version": 1, "depth": D, "spans": [{stage, batch, start,
-    end}, ...]}``.  Returns (spans, depth)."""
-    with open(path) as fh:
-        payload = json.load(fh)
-    version = payload.get("schema_version")
-    if version != 1:
-        raise ValueError(f"unknown stage-trace schema_version {version!r}")
-    spans = [TimelineSpan(s["stage"], int(s["batch"]),
-                          float(s["start"]), float(s["end"]))
-             for s in payload["spans"]]
-    return spans, int(payload.get("depth", 2))

@@ -167,8 +167,8 @@ class CachedEmbeddingBag:
         if plan.fetch_rows.size:
             args = None
             if self.tracer is not None:
-                # one seq per prefetch: the tracer groups this pair into
-                # one calibration sample (Tracer.stage_samples)
+                # one seq per prefetch pairs this fetch with its scatter
+                # on the trace
                 args = {"seq": next(_PREFETCH_SEQ),
                         "bytes": int(plan.fetch_rows.size) * self.row_bytes,
                         "tier": self.cold.tier}

@@ -1,20 +1,13 @@
 """Config registry: ``get_config("<arch-id>")`` / ``get_smoke_config``.
 
 One module per assigned architecture (exact published config) plus the
-paper's own DLRM. ``ARCH_IDS`` is the assignment list (10 archs).
+paper's own DLRM. ``ARCH_IDS`` is the assignment list (10 archs).  The
+LM dataclasses live in ``repro.configs.base``, imported only by the LM
+modules, so ``repro.configs.dlrm`` loads no LM code.
 """
 from __future__ import annotations
 
 import importlib
-
-from repro.configs.base import (  # noqa: F401
-    MLAConfig,
-    ModelConfig,
-    ShapeConfig,
-    ShardingConfig,
-    TrainConfig,
-    SHAPES,
-)
 
 ARCH_IDS = [
     "moonshot-v1-16b-a3b",
@@ -39,11 +32,11 @@ def _module(name: str):
     return importlib.import_module(_MODULES[name])
 
 
-def get_config(name: str) -> ModelConfig:
+def get_config(name: str):
     return _module(name).CONFIG
 
 
-def get_smoke_config(name: str) -> ModelConfig:
+def get_smoke_config(name: str):
     return _module(name).smoke()
 
 
@@ -51,15 +44,16 @@ def shape_cells(arch: str):
     """The (shape, reason-or-None) cells assigned to ``arch``.
 
     Returns list of (ShapeConfig, skip_reason|None). long_500k runs only
-    for ssm/hybrid families (sub-quadratic decode state) per the
-    assignment; see DESIGN.md §Arch-applicability.
+    for ssm/hybrid families (sub-quadratic decode state).
     """
+    from repro.configs.base import SHAPES
+
     cfg = get_config(name=arch) if isinstance(arch, str) else arch
     cells = []
     for s in SHAPES.values():
         skip = None
         if s.name == "long_500k" and not cfg.supports_long_context:
             skip = "full-attention arch: O(S^2)/O(S) decode state at 500k " \
-                   "is out of assignment scope (DESIGN.md §Arch-applicability)"
+                   "is out of assignment scope"
         cells.append((s, skip))
     return cells

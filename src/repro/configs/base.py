@@ -1,4 +1,4 @@
-"""Config dataclasses: model architecture, input shapes, mesh, training.
+"""Config dataclasses: model architecture, input shapes, training.
 
 Every assigned architecture gets one ``src/repro/configs/<id>.py`` exposing
 ``CONFIG`` (the exact published shape) and ``smoke()`` (a reduced same-family
@@ -173,13 +173,3 @@ class TrainConfig:
     checkpoint_every: int = 50
     keep_checkpoints: int = 3
     seed: int = 0
-
-
-@dataclasses.dataclass(frozen=True)
-class ShardingConfig:
-    """Mesh-level knobs (hillclimb levers)."""
-    fsdp: bool = True                 # shard params/opt-state over data axes
-    sequence_parallel: bool = True    # shard activations over model between blocks
-    embed_rs_dtype: str = "float32"   # reduce-scatter dtype for pooled embeds
-    logits_vocab_sharded: bool = True # never materialize replicated logits
-    decode_kv_seq_sharded: bool = True  # flash-decode over model axis

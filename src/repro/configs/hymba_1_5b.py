@@ -6,7 +6,7 @@ layer, combining their (normalized) outputs; most layers use sliding-window
 attention (1024) with 3 full-attention layers (first/middle/last) — this is
 what makes ``long_500k`` decode feasible (bounded KV window + O(1) SSM
 state). Meta-token prepending is modelled as part of the sequence (128
-learnable prefix tokens are an additive detail, noted in DESIGN.md).
+learnable prefix tokens are an additive detail).
 """
 from repro.configs.base import ModelConfig
 

@@ -21,7 +21,7 @@ CONFIG = ModelConfig(
     rwkv_head_size=64,
     # §Perf hillclimb result: chunk-parallel time-mix. The naive per-step
     # scan is memory-catastrophic (12 671 s HBM term on train_4k); chunked
-    # at 1024 it is 1.69 s (7500x) for +14% FLOPs — see EXPERIMENTS.md.
+    # at 1024 it is 1.69 s (7500x) for +14% FLOPs.
     # Set 0 to reproduce the paper-faithful per-step baseline.
     rwkv_chunk=1024,
     activation="relu2",         # rwkv channel-mix uses squared relu

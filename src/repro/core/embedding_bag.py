@@ -72,13 +72,14 @@ class EmbeddingBagConfig:
     kernel_mode: str = "auto"        # auto | reference | pallas | interpret
     # fused: run the table-batched (TBE) kernel — ONE pallas_call for all T
     # tables per shard. False calls the single-table kernel per table (T
-    # launches); kept as the A/B baseline for benchmarks/tbe_sweep.py.
+    # launches); the A/B baseline tests/test_tbe.py checks the fused one
+    # against.
     fused: bool = True
     # pooling: each table's fixed multi-hot size L_t, for lookups packed
     # as ``PackedBatch`` (only each table's L_t slots per sample); None
     # for the padded (T, B, L) ``JaggedBatch`` layout
     pooling: Optional[Tuple[int, ...]] = None
-    # --- beyond-paper levers (EXPERIMENTS.md §beyond-paper) ---
+    # --- beyond-paper levers (benchmarks/beyond_paper.py) ---
     # rs_dtype: cast partial pooled vectors to this dtype before the
     # phase-3 reduce-scatter/all-reduce — halves output traffic at bf16
     # (bounded error: one rounding per shard contribution).

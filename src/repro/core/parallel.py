@@ -1,25 +1,18 @@
-"""ParallelContext — carries mesh/axis/topology knowledge through the model.
+"""ParallelContext — carries mesh/axis knowledge through the models.
 
 Models take ``ctx: ParallelContext | None``. ``None`` means single-device
-(smoke tests, kernels oracles). With a context, the model:
-
-  * looks up token embeddings through the paper's row-wise-sharded
-    embedding bag (explicit shard_map collectives),
-  * dispatches MoE tokens expert-parallel over the tp axis,
-  * runs decode attention over a sequence-sharded KV cache (flash-decode
-    combine over the tp axis),
-  * leaves dense matmuls to GSPMD, steered by parameter PartitionSpecs and
-    activation sharding constraints.
+(smoke tests, kernel oracles). With a context, the row-wise-sharded
+embedding bag runs its explicit shard_map collectives over the tp axis,
+and the dense layers are left to GSPMD, steered by the spec helpers
+below.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.configs.base import ShardingConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,7 +20,6 @@ class ParallelContext:
     mesh: Mesh
     dp_axes: Tuple[str, ...]            # ("pod", "data") or ("data",)
     tp_axis: str                        # "model"
-    config: ShardingConfig = ShardingConfig()
 
     @property
     def dp_size(self) -> int:
@@ -66,13 +58,8 @@ class ParallelContext:
         return NamedSharding(self.mesh, spec)
 
 
-def make_context(mesh: Mesh,
-                 sharding: Optional[ShardingConfig] = None) -> ParallelContext:
+def make_context(mesh: Mesh) -> ParallelContext:
     """Infer axes from the mesh: last axis = tp, rest = dp."""
     names = mesh.axis_names
-    return ParallelContext(
-        mesh=mesh,
-        dp_axes=tuple(names[:-1]),
-        tp_axis=names[-1],
-        config=sharding or ShardingConfig(),
-    )
+    return ParallelContext(mesh=mesh, dp_axes=tuple(names[:-1]),
+                           tp_axis=names[-1])

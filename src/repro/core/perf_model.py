@@ -2,7 +2,7 @@
 
 The container is CPU-only, so the paper's H100 wall-clock measurements are
 reproduced through a calibrated latency/bandwidth model, and the same model
-re-parameterized with TPU v5e constants drives the roofline/projection
+re-parameterized with TPU v5e constants drives the projection
 benchmarks. Calibration targets (from the paper's own observations):
 
   * all-reduce  : NVSHMEM ~10x faster than NCCL for msgs <= 2 KB (Fig. 1)
@@ -148,27 +148,6 @@ def embedding_bag_time(
     w: EmbeddingWorkload, n_devices: int, hw: Hardware, *, onesided: bool = False
 ) -> float:
     return sum(phase_times(w, n_devices, hw, onesided=onesided).values())
-
-
-def tbe_gather_phases(
-    w: EmbeddingWorkload, hw: Hardware, *, fused: bool
-) -> Dict[str, float]:
-    """Modeled gather-phase decomposition, fused-TBE vs per-table launches.
-
-    ``launch`` is the per-kernel setup floor (grid launch + pipeline
-    fill/drain + index prefetch), paid once under TBE and T times under the
-    per-table baseline. ``stream`` is the HBM row traffic — identical in
-    both layouts, which is exactly why the paper's #tables axis (§5) is a
-    launch-overhead axis at small pooling sizes.
-    """
-    launches = 1 if fused else w.num_tables
-    stream_bytes = (
-        w.batch_per_device * w.num_tables * w.pooling * w.dim * w.dtype_bytes
-    )
-    return {
-        "launch": launches * hw.gather_overhead_s,
-        "stream": stream_bytes / hw.hbm_Bps,
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -528,177 +507,3 @@ def tiered_speedup_vs_distributed(
         w, hw, hit_rate=hit_rate, hosts=hosts, onesided=fetch_onesided,
         zipf_a=zipf_a, rows=rows, cache_rows=cache_rows)
     return dist / tiered
-
-
-# ---------------------------------------------------------------------------
-# Roofline terms (used by benchmarks/roofline.py against dry-run artifacts)
-# ---------------------------------------------------------------------------
-
-ICI_LINK_Bps = 50e9   # per spec: ~50 GB/s/link TPU ICI
-V5E_PEAK_BF16 = 197e12
-V5E_HBM_Bps = 819e9
-
-
-def roofline_terms(
-    hlo_flops: float, hlo_bytes: float, collective_bytes: float, chips: int
-) -> Dict[str, float]:
-    return {
-        "compute_s": hlo_flops / (chips * V5E_PEAK_BF16),
-        "memory_s": hlo_bytes / (chips * V5E_HBM_Bps),
-        "collective_s": collective_bytes / (chips * ICI_LINK_Bps),
-    }
-
-
-# ---------------------------------------------------------------------------
-# Calibration — measured spans in, fitted Hardware out (repro/obs/)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class StageSample:
-    """One measured serving-stage interval, the calibration input.
-
-      * stage "h2d"          — one prefetch's host->device payload move:
-        ``seconds`` of wall-clock for ``bytes`` of missed-row payload
-        (the interval ``tiered_phase_times`` prices as ``prefetch_h2d =
-        gather_overhead_s + bytes / host_Bps``);
-      * stage "fetch_remote" — one batched ``comm.fetch_rows``
-        collective: ``bytes`` is the LOCAL payload (stacked contribution
-        over the axis size, i.e. the miss payload ``collective_time``
-        charges) and ``n_devices`` the axis size.
-
-    :meth:`repro.obs.Tracer.stage_samples` projects a recorded timeline
-    onto these records.
-    """
-
-    stage: str
-    seconds: float
-    bytes: float
-    n_devices: int = 1
-
-
-def _fit_affine(features, seconds):
-    """Least-squares ``t ~= a * f0 + b * f1`` with non-negativity clamps.
-
-    Returns ``(a, b)``; a rank-deficient or too-small sample set falls
-    back to a one-coefficient slope fit through the origin, and a
-    negative coefficient triggers a refit on the other feature alone —
-    physical constants (latency floors, inverse bandwidths) are never
-    negative.  Returns None with no samples.
-    """
-    F = np.asarray(features, np.float64).reshape(-1, 2)
-    y = np.asarray(seconds, np.float64)
-    if F.shape[0] == 0:
-        return None
-
-    def slope(col):
-        d = float((F[:, col] ** 2).sum())
-        return float((F[:, col] * y).sum()) / d if d > 0 else 0.0
-
-    if F.shape[0] < 2 or np.linalg.matrix_rank(F) < 2:
-        return 0.0, slope(1)
-    a, b = (float(v) for v in np.linalg.lstsq(F, y, rcond=None)[0])
-    if b <= 0:
-        return slope(0), 0.0
-    if a < 0:
-        return 0.0, slope(1)
-    return a, b
-
-
-def predicted_stage_time(s: StageSample, hw: Hardware, *,
-                         onesided: bool = False) -> float:
-    """Seconds the model charges for one :class:`StageSample`'s stage —
-    the exact terms ``tiered_phase_times`` uses, applied per sample."""
-    if s.stage == "h2d":
-        return hw.gather_overhead_s + s.bytes / hw.host_Bps
-    if s.stage == "fetch_remote":
-        t = hw.onesided if onesided else hw.bulk
-        return collective_time("fetch_rows", s.bytes, s.n_devices, t)
-    raise ValueError(
-        f"unknown stage {s.stage!r}; pick 'h2d' or 'fetch_remote'")
-
-
-def stage_time_error(samples, hw: Hardware, *,
-                     onesided: bool = False) -> Dict[str, float]:
-    """Model-vs-measured relative error, per stage plus "total".
-
-    Each entry is ``|sum(predicted) - sum(measured)| / sum(measured)``
-    over that stage's samples — the aggregate-throughput error a
-    capacity planner cares about (per-sample jitter averages out).
-    """
-    meas: Dict[str, float] = {}
-    pred: Dict[str, float] = {}
-    for s in samples:
-        meas[s.stage] = meas.get(s.stage, 0.0) + s.seconds
-        pred[s.stage] = pred.get(s.stage, 0.0) \
-            + predicted_stage_time(s, hw, onesided=onesided)
-    out = {stage: abs(pred[stage] - meas[stage]) / meas[stage]
-           for stage in meas if meas[stage] > 0}
-    total = sum(meas.values())
-    if total > 0:
-        out["total"] = abs(sum(pred.values()) - total) / total
-    return out
-
-
-@dataclasses.dataclass(frozen=True)
-class CalibrationResult:
-    """Outcome of :func:`calibrate`: the fitted platform plus fit context."""
-
-    hw: Hardware
-    base: Hardware
-    n_h2d: int
-    n_remote: int
-    onesided: bool
-
-    def error(self, samples) -> Dict[str, float]:
-        """Fitted model's stage-time error on ``samples`` (held-out or
-        training — the caller picks the window)."""
-        return stage_time_error(samples, self.hw, onesided=self.onesided)
-
-
-def calibrate(source, base: Hardware = H100_DGX, *,
-              onesided: bool = False) -> CalibrationResult:
-    """Fit the serving-stage constants of ``base`` to measured spans.
-
-    ``source`` is either an iterable of :class:`StageSample` or anything
-    with a ``stage_samples()`` method (a :class:`repro.obs.Tracer`).
-    Two independent least-squares fits, each replacing only the
-    constants its stage exercises (everything else — HBM bandwidth,
-    peak FLOPs, capacities — keeps ``base``'s values):
-
-      * "h2d" samples fit ``t = gather_overhead_s + bytes / host_Bps``
-        (features ``(1, bytes)`` — intercept is the per-prefetch floor,
-        slope the inverse host-link bandwidth);
-      * "fetch_remote" samples fit the α–β collective model
-        ``t = alpha_s * max(1, log2 n / 3) + c_op(n) * bytes /
-        beta_Bps``, replacing the bulk (or, with ``onesided=True``, the
-        one-sided) :class:`Transport`.
-
-    A stage with no samples keeps ``base``'s constants; a degenerate
-    slope fit (zero inverse bandwidth) pins that bandwidth to ``inf`` so
-    the fitted floor alone carries the prediction.
-    """
-    samples = list(source.stage_samples()
-                   if hasattr(source, "stage_samples") else source)
-    h2d = [s for s in samples if s.stage == "h2d"]
-    rem = [s for s in samples
-           if s.stage == "fetch_remote" and s.n_devices > 1]
-    hw = base
-    if h2d:
-        a, b = _fit_affine([(1.0, s.bytes) for s in h2d],
-                           [s.seconds for s in h2d])
-        hw = dataclasses.replace(
-            hw, gather_overhead_s=a,
-            host_Bps=(1.0 / b if b > 0 else math.inf))
-    if rem:
-        factor = _OP_FACTOR["fetch_rows"]
-        a, b = _fit_affine(
-            [(max(1.0, math.log2(s.n_devices) / 3.0),
-              factor(s.n_devices) * s.bytes) for s in rem],
-            [s.seconds for s in rem])
-        fitted = Transport(
-            name=(hw.onesided if onesided else hw.bulk).name + "-calibrated",
-            alpha_s=a, beta_Bps=(1.0 / b if b > 0 else math.inf))
-        hw = dataclasses.replace(
-            hw, **({"onesided": fitted} if onesided else {"bulk": fitted}))
-    hw = dataclasses.replace(hw, name=base.name + "-calibrated")
-    return CalibrationResult(hw, base, len(h2d), len(rem), onesided)

@@ -19,8 +19,7 @@ Per cell this:
      ``compiled.cost_analysis()`` (FLOPs / bytes), and the collective
      traffic parsed from the post-SPMD HLO (every all-gather / all-reduce /
      reduce-scatter / all-to-all / collective-permute operand),
-  4. writes a JSON artifact to ``dryrun_artifacts/<cell>.json`` —
-     benchmarks/roofline.py turns these into EXPERIMENTS.md §Roofline.
+  4. writes a JSON artifact to ``dryrun_artifacts/<cell>.json``.
 
 Sharding failures, non-divisible dims, or compile OOMs here are bugs in
 the framework's distribution config — the cell list below must be green.
@@ -28,7 +27,6 @@ the framework's distribution config — the cell list below must be green.
 import argparse
 import json
 import sys
-import time
 import traceback
 from functools import partial
 
@@ -62,17 +60,9 @@ from repro.analysis.contracts import parse_collectives  # noqa: E402,F401
 # Cell builders
 # ---------------------------------------------------------------------------
 
-def build_cell(arch: str, shape_name: str, ctx, tc: TrainConfig,
-               overrides: dict = None):
-    """Returns (jitted fn, arg ShapeDtypeStructs tuple).
-
-    ``overrides``: ModelConfig field replacements — the §Perf hillclimb
-    lever (e.g. {"rwkv_chunk": 64}).
-    """
-    import dataclasses as _dc
+def build_cell(arch: str, shape_name: str, ctx, tc: TrainConfig):
+    """Returns (jitted fn, arg ShapeDtypeStructs tuple)."""
     cfg = configs.get_config(arch)
-    if overrides:
-        cfg = _dc.replace(cfg, **overrides)
     shape = SHAPES[shape_name]
     batch_sds = S.batch_structs(cfg, shape)
     batch_spec = S.batch_specs(cfg, shape, ctx)
@@ -143,32 +133,23 @@ def cell_skip_reason(arch: str, shape_name: str):
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
-             out_dir: str = None, tc: TrainConfig = None,
-             extra_tags=None, overrides: dict = None,
-             sharding_cfg=None) -> dict:
+             out_dir: str = None, tc: TrainConfig = None) -> dict:
     out_dir = out_dir or ART_DIR
     os.makedirs(out_dir, exist_ok=True)
     tag = f"{arch}__{shape_name}__{'pod2' if multi_pod else 'pod1'}"
-    if extra_tags:
-        tag += "__" + extra_tags
     skip = cell_skip_reason(arch, shape_name)
     record = {"arch": arch, "shape": shape_name,
-              "multi_pod": multi_pod, "tag": tag,
-              "overrides": overrides or {}}
+              "multi_pod": multi_pod, "tag": tag}
     if skip:
         record["status"] = "skipped"
         record["skip_reason"] = skip
     else:
         tc = tc or TrainConfig(remat=True, optimizer_state_dtype="int8")
         mesh = make_production_mesh(multi_pod=multi_pod)
-        ctx = make_context(mesh, sharding_cfg)
-        t0 = time.perf_counter()
+        ctx = make_context(mesh)
         with mesh:
-            fn, args = build_cell(arch, shape_name, ctx, tc, overrides)
-            lowered = fn.lower(*args)
-            t1 = time.perf_counter()
-            compiled = lowered.compile()
-            t2 = time.perf_counter()
+            fn, args = build_cell(arch, shape_name, ctx, tc)
+            compiled = fn.lower(*args).compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
         hlo_text = compiled.as_text()
@@ -176,8 +157,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         record.update({
             "status": "ok",
             "n_devices": mesh.devices.size,
-            "lower_s": round(t1 - t0, 2),
-            "compile_s": round(t2 - t1, 2),
             # raw XLA aggregates (scan bodies counted ONCE — see hlo_cost)
             "xla_flops_raw": float(cost.get("flops", -1)),
             "xla_bytes_raw": float(cost.get("bytes accessed", -1)),
@@ -199,8 +178,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "memory_analysis": _mem_dict(mem),
         })
         coll_tot = sum(corrected["collective_bytes_per_device"].values())
-        print(f"[{tag}] compile {t2-t1:.1f}s  "
-              f"flops/dev={corrected['flops_per_device']:.3e}  "
+        print(f"[{tag}] flops/dev={corrected['flops_per_device']:.3e}  "
               f"coll={coll_tot:.3e}B/dev")
         print(f"[{tag}] memory: {record['memory_analysis']}")
     with open(os.path.join(out_dir, tag + ".json"), "w") as f:
@@ -268,7 +246,6 @@ def run_dlrm_cell(cache_rows: int = 0, cold_tier: str = "host",
         jax.ShapeDtypeStruct((T, batch, cfg.pooling), jnp.int32),
         jax.ShapeDtypeStruct((T, batch), jnp.int32))
 
-    t0 = time.perf_counter()
     if cache_rows:
         fn = jax.jit(lambda p, d, b: dlrm_mod.forward(p, d, b, cfg, None))
         compiled = fn.lower(params_t, dense_t, batch_t).compile()
@@ -282,7 +259,6 @@ def run_dlrm_cell(cache_rows: int = 0, cold_tier: str = "host",
     coll, counts = parse_collectives(compiled.as_text())
     record.update({
         "status": "ok",
-        "compile_s": round(time.perf_counter() - t0, 2),
         "table_bytes": T * (cache_rows or R) * D * 4,
         "collective_bytes": coll,
         "collective_counts": counts,
@@ -294,8 +270,7 @@ def run_dlrm_cell(cache_rows: int = 0, cold_tier: str = "host",
         from repro.serving.engine import KERNEL_CONTRACTS
         audit_hlo(compiled.as_text(),
                   KERNEL_CONTRACTS["tiered_forward"]).raise_if_failed()
-    print(f"[{tag}] compile {record['compile_s']}s  "
-          f"table/pool bytes {record['table_bytes']:.3e}  "
+    print(f"[{tag}] table/pool bytes {record['table_bytes']:.3e}  "
           f"collectives {counts}")
     with open(os.path.join(out_dir, tag + ".json"), "w") as f:
         json.dump(record, f, indent=1)

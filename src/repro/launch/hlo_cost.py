@@ -16,7 +16,6 @@ Extracted per device:
                       (matmuls are >95% of model FLOPs; elementwise ignored)
   * collective bytes — operand bytes of all-gather / all-reduce /
                       reduce-scatter / all-to-all / collective-permute
-                      (per-op convention in DESIGN.md)
   * hbm bytes (approx) — sum of instruction result bytes x2 (read+write),
                       counting fusions as one read-inputs/write-output —
                       an upper-ish bound on steady-state HBM traffic,

@@ -2,7 +2,7 @@
 state, KV caches, and input batches — plus ShapeDtypeStruct builders
 (``input_specs``) for the dry-run.
 
-Sharding policy (the baseline; hillclimb levers in ShardingConfig):
+Sharding policy:
   * embed table (1, Vp, d)      -> P(None, tp, None)    — the paper's RW
   * lm head (d, Vp)             -> P(dp?, tp)           — vocab-parallel
   * column-parallel weights (wq/wk/wv/gate/up/in_proj/ck/w_r...) —
@@ -12,8 +12,8 @@ Sharding policy (the baseline; hillclimb levers in ShardingConfig):
   * norms/scalars               -> replicated
   * optimizer moments           -> parameter spec (int8 blocks append
     trailing Nones — optim/quant.py keeps blocks on the last axis)
-  * activations (B, S, d)       -> P(dp, None, None) (sequence_parallel:
-    P(dp, tp, None) between blocks)
+  * activations (B, S, d)       -> P(dp, tp, None) between blocks when tp
+    divides S, else P(dp, None, None)
   * KV caches                   -> batch over dp, KV seq over tp
 """
 from __future__ import annotations
@@ -46,10 +46,6 @@ def _path_names(path) -> Tuple[str, ...]:
 def param_spec_tree(params, cfg: ModelConfig, ctx: ParallelContext):
     """PartitionSpec pytree matching ``params`` (see module docstring)."""
     tp = ctx.tp_axis
-    fsdp = ctx.config.fsdp
-
-    def dp_for(dim: int):
-        return ctx.dp_for(dim) if fsdp else None
 
     # Head-aligned TP gating: sharding a flattened (H*hd) projection dim
     # when H (or KH) does not divide tp splits WITHIN heads; GSPMD then
@@ -75,30 +71,30 @@ def param_spec_tree(params, cfg: ModelConfig, ctx: ParallelContext):
         if name == "embed":
             return P(None, ctx.tp_for(shape[1]), None)
         if name == "head":
-            return P(dp_for(shape[0]), ctx.tp_for(shape[1]))
+            return P(ctx.dp_for(shape[0]), ctx.tp_for(shape[1]))
         if name in _REPLICATED or nd <= 1 or "projector" in names and name == "fc1":
             return P(*([None] * nd))
         if in_moe and name in ("gate", "up", "down") and nd == 4:
             # (n, E, d, f): EP over tp; FSDP over the larger inner dim
-            return P(None, ctx.tp_for(shape[1]), dp_for(shape[2]), None)
+            return P(None, ctx.tp_for(shape[1]), ctx.dp_for(shape[2]), None)
         if name in ("w", "b"):                       # norms inside stacks
             return P(*([None] * nd))
         if name == "wq" and not q_heads_ok:
-            return P(*([None] * (nd - 2)), dp_for(shape[-2]), None)
+            return P(*([None] * (nd - 2)), ctx.dp_for(shape[-2]), None)
         if name in ("wk", "wv") and not kv_heads_ok:
-            return P(*([None] * (nd - 2)), dp_for(shape[-2]), None)
+            return P(*([None] * (nd - 2)), ctx.dp_for(shape[-2]), None)
         if name == "wo" and not q_heads_ok:
-            return P(*([None] * (nd - 2)), None, dp_for(shape[-1]))
+            return P(*([None] * (nd - 2)), None, ctx.dp_for(shape[-1]))
         if name in _ROW_PARALLEL and nd >= 2:
             spec = [None] * nd
             spec[-2] = ctx.tp_for(shape[-2])
-            spec[-1] = dp_for(shape[-1]) if shape[-1] >= 1024 else None
+            spec[-1] = ctx.dp_for(shape[-1]) if shape[-1] >= 1024 else None
             return P(*spec)
         if nd >= 2:                                  # column-parallel default
             spec = [None] * nd
             spec[-1] = ctx.tp_for(shape[-1])
             if shape[-2] >= 1024:
-                spec[-2] = dp_for(shape[-2])
+                spec[-2] = ctx.dp_for(shape[-2])
             return P(*spec)
         return P(*([None] * nd))
 

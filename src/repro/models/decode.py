@@ -2,11 +2,10 @@
 
 Cache layout: per-layer tensors STACKED on a leading num_layers axis and
 scanned, like the forward pass. The KV sequence dim is sharded over the tp
-axis (ctx.config.decode_kv_seq_sharded) and attention runs as a
-flash-decode: each shard computes a partial (o, m, l) over its cache slice
-and the triple combines with a pmax/psum over the axis
-(layers.combine_decode_partials) — this is what makes a 32k-context,
-128-batch decode fit 16 GB/chip without replicating the cache.
+axis and attention runs as a flash-decode: each shard computes a partial
+(o, m, l) over its cache slice and the triple combines with a pmax/psum
+over the axis (layers.combine_decode_partials) — this is what makes a
+32k-context, 128-batch decode fit 16 GB/chip without replicating the cache.
 
 Per-sample ``length`` (B,) supports continuous batching (slots at
 different positions); cache writes are batched scatters.
@@ -93,14 +92,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 def cache_specs(cfg: ModelConfig, ctx: ParallelContext) -> Dict[str, Any]:
     """PartitionSpecs mirroring init_cache: batch over dp, KV seq over tp."""
     tp = ctx.tp_axis
-    seq = tp if ctx.config.decode_kv_seq_sharded else None
 
     def spec_of(path_leaf_shape):
         return None  # placeholder; tree built below
 
     def kv_spec(dpb):
-        return {"k": P(None, dpb, seq, None, None),
-                "v": P(None, dpb, seq, None, None)}
+        return {"k": P(None, dpb, tp, None, None),
+                "v": P(None, dpb, tp, None, None)}
 
     def build(batch: int):
         dpb = ctx.dp_for(batch)
@@ -110,8 +108,8 @@ def cache_specs(cfg: ModelConfig, ctx: ParallelContext) -> Dict[str, Any]:
             specs["blocks"] = kv_spec(dpb)
         elif fam == "moe":
             if cfg.attention == "mla":
-                ls = {"c_kv": P(None, dpb, seq, None),
-                      "k_rope": P(None, dpb, seq, None)}
+                ls = {"c_kv": P(None, dpb, tp, None),
+                      "k_rope": P(None, dpb, tp, None)}
                 if cfg.first_k_dense:
                     specs["dense_blocks"] = dict(ls)
                 specs["moe_blocks"] = dict(ls)
@@ -142,7 +140,7 @@ def _decode_attn(q, k_cache, v_cache, length, cfg: ModelConfig,
                  ctx: Optional[ParallelContext], *, window=None):
     """q (B,1,H,hd), caches (B,S,KH,hd). Returns (B,1,H*hd)."""
     B = q.shape[0]
-    if ctx is None or not ctx.config.decode_kv_seq_sharded:
+    if ctx is None:
         o, m, l = layers.decode_attention_partial(
             q, k_cache, v_cache, length[:, None], window=window)
         out = layers.combine_decode_partials(o, m, l)
@@ -171,7 +169,7 @@ def _decode_attn(q, k_cache, v_cache, length, cfg: ModelConfig,
 def _mla_decode_attn(pl, x, c_kv, k_rope, length, cfg: ModelConfig,
                      ctx: Optional[ParallelContext]):
     B = x.shape[0]
-    if ctx is None or not ctx.config.decode_kv_seq_sharded:
+    if ctx is None:
         ctx_l, m, l = mla.mla_decode_partial(pl, x, cfg, c_kv, k_rope,
                                              length[:, None])
         combined = layers.combine_decode_partials(ctx_l, m, l)

@@ -257,7 +257,7 @@ def lm_logits(params, hidden: jax.Array, cfg: ModelConfig,
     """hidden (..., d) -> logits (..., Vp), vocab-sharded under GSPMD."""
     head = params["embed"][0].T if cfg.tie_embeddings else params["head"]
     logits = hidden @ head
-    if ctx is not None and ctx.config.logits_vocab_sharded:
+    if ctx is not None:
         spec = (P(ctx.dp_for(hidden.shape[0]), None, ctx.tp_axis)
                 if logits.ndim == 3 else P(None, ctx.tp_axis))
         logits = ctx.constrain(logits, spec)
@@ -311,18 +311,17 @@ _SP_FAMILIES = ("dense", "vlm", "moe", "audio")
 def _carry_constraint(h, cfg, ctx):
     """Between-block activation sharding (scan-carry spec).
 
-    sequence_parallel shards the carry over the tp axis along S —
-    Megatron-SP: saved activations (the remat residuals) shrink by tp_size,
-    at the cost of an all-gather before attention/FFN and a
-    reduce-scatter after (GSPMD inserts them). Recurrent families scan
+    The carry is sharded over the tp axis along S (Megatron-SP): saved
+    activations (the remat residuals) shrink by tp_size, at the cost of an
+    all-gather before attention/FFN and a reduce-scatter after (GSPMD
+    inserts them). Recurrent families scan
     over time/chunks inside the block, where a seq-sharded carry would
     force per-step resharding — they stay batch-sharded only.
     """
     if ctx is None:
         return h
     B, S, _ = h.shape
-    if (ctx.config.sequence_parallel and cfg.family in _SP_FAMILIES
-            and S % ctx.tp_size == 0):
+    if cfg.family in _SP_FAMILIES and S % ctx.tp_size == 0:
         return ctx.constrain(h, P(ctx.dp_for(B), ctx.tp_axis, None))
     return ctx.constrain(h, P(ctx.dp_for(B), None, None))
 
@@ -412,7 +411,7 @@ def forward(params, tokens: jax.Array, cfg: ModelConfig,
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     if ctx is not None:
         sp = (P(ctx.dp_for(B), ctx.tp_axis, None)
-              if ctx.config.sequence_parallel and S % ctx.tp_size == 0
+              if S % ctx.tp_size == 0
               else P(ctx.dp_for(B), None, None))
         h = ctx.constrain(h, sp)
 

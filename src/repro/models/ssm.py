@@ -218,7 +218,7 @@ def rwkv_time_mix(p, x, cfg: ModelConfig, *, state=None, x_last=None):
 
     ``state`` (B,H,hs,hs) and ``x_last`` (B,d) carry decode state; None for
     a fresh sequence. Scans timesteps (the honest recurrent form; the
-    chunked-parallel form is a hillclimb lever, see EXPERIMENTS.md).
+    chunked-parallel form is ``rwkv_chunk``).
     """
     B, S, d = x.shape
     hs = cfg.rwkv_head_size

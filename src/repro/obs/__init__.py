@@ -9,8 +9,7 @@ One bundle (:class:`Telemetry`) carries the two halves of observability:
   * :class:`~repro.obs.trace.Tracer` — every engine stage span, pipeline
     ``StageSpan``, per-request enqueue->score latency span, and
     timestamped ``CollectiveEvent`` merged onto ONE ``perf_counter``
-    timeline, exportable as Chrome trace-event / Perfetto JSON and
-    projectable onto ``perf_model.calibrate``'s ``StageSample`` inputs.
+    timeline, exportable as Chrome trace-event / Perfetto JSON.
 
 Every serving stage is timed by one :class:`~repro.obs.trace.span` call,
 which also writes it into a ``jax.profiler`` trace as a
@@ -31,7 +30,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-from repro.obs.export import SweepReport, provenance, write_snapshot
+from repro.obs.export import SweepReport
 from repro.obs.metrics import (Counter, Gauge, Histogram, LogBuckets,
                                MetricsRegistry)
 from repro.obs.slo import (DriftDetector, SLOEvent, SLOMonitor, SLOPolicy,
@@ -48,7 +47,7 @@ __all__ = [
     "expected_hit_rates",
     "LANES", "Span", "Tracer", "span", "profiled_spans",
     "validate_chrome_trace",
-    "SweepReport", "provenance", "write_snapshot", "Telemetry",
+    "SweepReport", "Telemetry",
 ]
 
 

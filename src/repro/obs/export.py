@@ -1,55 +1,15 @@
-"""Shared benchmark exporters: one CSV assembler, one snapshot writer.
+"""One CSV assembler for the paper-figure scripts in ``benchmarks/``.
 
-Every sweep in ``benchmarks/`` used to hand-roll its CSV — an
-``io.StringIO``, a hand-printed header, and f-string rows whose column
-order silently drifted from the header's.  :class:`SweepReport` is the
-one assembler they now share: columns are declared once, every row is
-validated against them, and comment lines (the ``# ...`` context the
-sweeps interleave) ride along in order.
-
-:func:`write_snapshot` is the matching JSON artifact writer — a
-:class:`~repro.obs.metrics.MetricsRegistry` snapshot plus arbitrary
-extras under one top-level schema (the CI obs-smoke step's
-``BENCH_obs.json``).
+Every script there used to hand-roll its CSV — an ``io.StringIO``, a
+hand-printed header, and f-string rows whose column order silently
+drifted from the header's.  :class:`SweepReport` is the one assembler
+they now share: columns are declared once, every row is validated
+against them, and comment lines (the ``# ...`` context the scripts
+interleave) ride along in order.
 """
 from __future__ import annotations
 
-import datetime
-import json
-import subprocess
-from typing import Dict, List, Optional, Sequence
-
-# v2: provenance header/section (git sha, UTC timestamp, jax version)
-SNAPSHOT_SCHEMA_VERSION = 2
-
-
-def git_sha(default: str = "unknown") -> str:
-    """The repo's HEAD sha (``default`` outside a checkout / without git)."""
-    try:
-        out = subprocess.run(
-            ["git", "rev-parse", "HEAD"], capture_output=True, text=True,
-            timeout=10)
-    except (OSError, subprocess.TimeoutExpired):
-        return default
-    sha = out.stdout.strip()
-    return sha if out.returncode == 0 and sha else default
-
-
-def provenance() -> Dict[str, str]:
-    """Who/when/what produced an artifact: git sha, UTC timestamp, jax
-    version — stamped into every sweep CSV and JSON snapshot so
-    artifacts stay attributable across the bench trajectory."""
-    try:
-        import jax
-        jax_version = jax.__version__
-    except Exception:                        # gated import (stub builds)
-        jax_version = "unavailable"
-    return {
-        "git_sha": git_sha(),
-        "timestamp_utc": datetime.datetime.now(
-            datetime.timezone.utc).strftime("%Y-%m-%dT%H:%M:%SZ"),
-        "jax_version": jax_version,
-    }
+from typing import List, Sequence
 
 
 class SweepReport:
@@ -98,38 +58,3 @@ class SweepReport:
     def csv(self) -> str:
         """Header + rows/comments, newline-terminated."""
         return "\n".join([self.header, *self._lines]) + "\n"
-
-    def write(self, path: str) -> str:
-        """Write the CSV with a ``# key: value`` provenance header
-        (git sha, UTC timestamp, jax version) ahead of the column
-        header — comment lines, so every existing CSV consumer that
-        skips ``#`` still parses the file."""
-        prov = provenance()
-        with open(path, "w") as f:
-            for k in ("git_sha", "timestamp_utc", "jax_version"):
-                f.write(f"# {k}: {prov[k]}\n")
-            f.write(self.csv())
-        return path
-
-
-def write_snapshot(path: str, *, metrics=None,
-                   extra: Optional[Dict] = None) -> str:
-    """Write a versioned JSON benchmark artifact.
-
-    ``metrics`` is a :class:`~repro.obs.metrics.MetricsRegistry` (its
-    ``snapshot()`` lands under ``"metrics"``); ``extra`` merges in
-    sweep-specific results (calibration numbers, assertions' measured
-    values).  A ``"provenance"`` section (git sha, UTC timestamp, jax
-    version) is always stamped in.  Returns ``path``.
-    """
-    payload: Dict[str, object] = {
-        "schema_version": SNAPSHOT_SCHEMA_VERSION,
-        "provenance": provenance(),
-    }
-    if metrics is not None:
-        payload["metrics"] = metrics.snapshot()
-    if extra:
-        payload.update(extra)
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2, default=str)
-    return path

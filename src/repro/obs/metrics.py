@@ -13,8 +13,8 @@ repro's one place such measurements accumulate.  Three instrument kinds:
 
 Every instrument is get-or-create by name through the registry, and
 ``snapshot()`` serializes the whole registry under a versioned schema
-(``MetricsRegistry.SCHEMA_VERSION``) so benchmark artifacts (e.g.
-``BENCH_obs.json``) stay machine-comparable across commits.  External
+(``MetricsRegistry.SCHEMA_VERSION``) so a snapshot stays
+machine-comparable across commits.  External
 stats records join the same snapshot as *producers*:
 ``register_producer("dlrm.cache", stats.as_dict)`` absorbs a
 :class:`repro.cache.CacheStats` (its own ``schema_version`` rides along
@@ -201,8 +201,8 @@ class MetricsRegistry:
     cross-rotate each other's windows.
     """
 
-    # bump when snapshot() keys change meaning or spelling — BENCH_obs.json
-    # and the CI obs-smoke artifact key off this contract.
+    # bump when snapshot() keys change meaning or spelling (pinned by
+    # analysis/lint.py's schema-pin rule).
     # v2: windowed/rolling/ewma sections (repro.obs.timeseries)
     SCHEMA_VERSION = 2
 
@@ -298,30 +298,6 @@ class MetricsRegistry:
         if prefix in self._producers and not replace:
             raise ValueError(f"producer {prefix!r} already registered")
         self._producers[prefix] = fn
-
-    @property
-    def observation_count(self) -> int:
-        """Total histogram observations (the overhead model's op count)."""
-        return sum(h.count for h in self._histograms.values())
-
-    def windowed_op_counts(self) -> Dict[str, int]:
-        """Lifetime op counts of the windowed instruments, split by kind
-        — the inputs of the overhead projection (benchmarks/slo_sweep.py
-        multiplies each by a microbenchmarked per-op cost):
-
-          * ``observe`` — WindowedHistogram observations;
-          * ``inc``     — RollingCounter increments;
-          * ``rotate``  — window rotations across both windowed kinds;
-          * ``ewma``    — per-ELEMENT EwmaSeries updates.
-        """
-        return {
-            "observe": sum(w.lifetime_count
-                           for w in self._windowed.values()),
-            "inc": sum(c.ops for c in self._rolling.values()),
-            "rotate": (sum(w.rotations for w in self._windowed.values())
-                       + sum(c.rotations for c in self._rolling.values())),
-            "ewma": sum(e.update_ops for e in self._ewma.values()),
-        }
 
     def snapshot(self) -> Dict[str, object]:
         """One stable, JSON-serializable view of every instrument."""

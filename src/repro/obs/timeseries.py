@@ -76,7 +76,7 @@ class WindowedHistogram:
         self._cur = _Tick()
         self.count = 0                      # windowed observation count
         self.total = 0.0                    # windowed sum
-        self.lifetime_count = 0             # never evicted (op counting)
+        self.lifetime_count = 0             # never evicted
         self.rotations = 0
 
     def observe(self, v: float) -> None:
@@ -179,7 +179,7 @@ class RollingCounter:
     ticks (same open-tick semantics as :class:`WindowedHistogram`)."""
 
     __slots__ = ("name", "unit", "window", "_closed", "_cur", "total",
-                 "lifetime_total", "ops", "rotations")
+                 "lifetime_total", "rotations")
 
     def __init__(self, name: str, unit: str = "1", *, window: int = 32):
         if window < 1:
@@ -189,7 +189,6 @@ class RollingCounter:
         self._cur = 0
         self.total = 0                      # windowed total
         self.lifetime_total = 0
-        self.ops = 0                        # inc() calls (op counting)
         self.rotations = 0
 
     def inc(self, n: int = 1) -> None:
@@ -199,7 +198,6 @@ class RollingCounter:
         self._cur += int(n)
         self.total += int(n)
         self.lifetime_total += int(n)
-        self.ops += 1
 
     def rotate(self) -> None:
         self.rotations += 1
@@ -236,8 +234,7 @@ class EwmaSeries:
     lookups this window contributes no evidence), first-ever updates
     set the value directly (no bias toward an arbitrary init)."""
 
-    __slots__ = ("name", "unit", "alpha", "values", "updates",
-                 "update_ops")
+    __slots__ = ("name", "unit", "alpha", "values", "updates")
 
     def __init__(self, name: str, unit: str = "1", *,
                  alpha: float = 0.25):
@@ -246,7 +243,6 @@ class EwmaSeries:
         self.name, self.unit, self.alpha = name, unit, alpha
         self.values: Optional[np.ndarray] = None
         self.updates: Optional[np.ndarray] = None
-        self.update_ops = 0                 # element updates (op counting)
 
     def update(self, x, mask=None) -> None:
         x = np.asarray(x, np.float64)
@@ -271,7 +267,6 @@ class EwmaSeries:
         self.values[first] = x[first]
         self.values[rest] += self.alpha * (x[rest] - self.values[rest])
         self.updates[m] += 1
-        self.update_ops += int(m.sum())
 
     def get(self) -> Optional[np.ndarray]:
         """Copy of the current (T,) estimates (None before any update)."""

@@ -39,12 +39,7 @@ Export schema: every event is a complete-event (``ph: "X"``) or
 metadata (``ph: "M"``) record carrying ``ph/ts/dur/pid/tid/name`` —
 ``ts``/``dur`` in microseconds relative to the tracer's epoch, as the
 format requires.  :func:`validate_chrome_trace` pins that contract (the
-golden-schema test and the CI obs-smoke step both run it).
-
-The tracer also closes the measurement loop back into the perf model:
-:meth:`Tracer.stage_samples` projects cache spans and collective events
-onto :class:`repro.core.perf_model.StageSample`, the input of
-``perf_model.calibrate`` — measured spans in, fitted ``Hardware`` out.
+golden-schema test runs it).
 
 Threading: ``add_span`` locks, so the pipeline's background prefetch
 threads and the main serving thread interleave safely on one timeline.
@@ -183,7 +178,7 @@ def validate_chrome_trace(obj) -> int:
     """Assert ``obj`` is well-formed Chrome trace-event JSON; returns the
     event count.  Every event must carry valid ``ph``/``ts``/``dur``/
     ``pid``/``tid``/``name`` fields — the contract the golden-schema test
-    and the CI obs-smoke step pin."""
+    pins."""
     if not isinstance(obj, dict) or "traceEvents" not in obj:
         raise ValueError("trace must be a dict with a 'traceEvents' list")
     events = obj["traceEvents"]
@@ -300,54 +295,6 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._spans = []
-
-    def stage_samples(self, *, since: float = 0.0) -> List:
-        """Project the timeline onto ``perf_model.StageSample`` records —
-        the calibration input (``perf_model.calibrate(tracer)``).
-
-          * cache spans (one prefetch = one ``seq``) -> stage "h2d": the
-            wall-clock of moving that prefetch's missed-row payload onto
-            the device.  With a host cold tier both the cold gather and
-            the pool scatter cross host memory / the host link, so both
-            spans count; with a remote cold tier the cold fetch is the
-            collective (sampled separately below) and only the scatter
-            is host-link work.
-          * timestamped ``fetch_rows`` collective events -> stage
-            "fetch_remote", with ``bytes`` the PER-HOST payload
-            (``bytes_in`` of the stacked (E, M, D) contribution divided
-            by the axis size — the miss payload the model charges).
-
-        ``since`` filters to spans starting at or after that
-        ``perf_counter`` stamp (sweeps use it to split train/held-out
-        windows off one shared timeline).
-        """
-        from repro.core.perf_model import StageSample
-
-        groups: Dict[object, Dict[str, float]] = {}
-        samples: List[StageSample] = []
-        for s in self.spans():
-            if s.t0 < since:
-                continue
-            if s.cat == "cache" and s.args and "seq" in s.args:
-                tier = s.args.get("tier", "host")
-                if s.name == "cache.fetch" and tier != "host":
-                    continue      # the remote collective is sampled below
-                g = groups.setdefault(s.args["seq"],
-                                      {"t": 0.0, "bytes": 0.0})
-                g["t"] += s.seconds
-                g["bytes"] = max(g["bytes"],
-                                 float(s.args.get("bytes", 0)))
-            elif s.cat == "comm" and s.name == "fetch_rows" \
-                    and s.t1 > s.t0 and s.args:
-                n = int(s.args.get("axis_size", 1))
-                if n > 1:
-                    samples.append(StageSample(
-                        "fetch_remote", s.seconds,
-                        float(s.args.get("bytes", 0)) / n, n))
-        samples.extend(
-            StageSample("h2d", g["t"], g["bytes"])
-            for g in groups.values() if g["bytes"] > 0 and g["t"] > 0)
-        return samples
 
     # -- export --------------------------------------------------------------
 

@@ -1,7 +1,7 @@
 """Sharded AdamW on parameter pytrees (no optax in this environment).
 
 State sharding: moments inherit the parameter PartitionSpecs, so with
-FSDP-sharded params (ShardingConfig.fsdp) the optimizer is ZeRO-3-
+FSDP-sharded params (launch/specs.py) the optimizer is ZeRO-3-
 equivalent for free under GSPMD. ``state_dtype`` selects the moment
 representation: float32 | bfloat16 | int8 (blockwise, optim/quant.py).
 """

@@ -2,8 +2,7 @@
 
 At deepseek-v3 scale (671 B params) fp32 Adam moments alone are 5.4 TB —
 over the 4 TB HBM of a full v5e pod. Blockwise int8 moments (one f32
-scale per 128 values, +3% overhead) cut that 4x; EXPERIMENTS.md §Dry-run
-records the per-chip effect.
+scale per 128 values, +3% overhead) cut that 4x.
 
 Sharding-friendly layout: blocks run along the LAST axis only, so the
 quantized payload keeps the parameter's leading-axis sharding —

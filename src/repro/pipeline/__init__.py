@@ -58,9 +58,8 @@ runs, and the pooled output is invariant to slot layout (same kernel,
 same summation order, same row payloads).
 
 Consumers: ``serving.engine.PipelinedDLRMEngine`` (selected by
-``DLRMConfig.pipeline_depth``), ``benchmarks/pipeline_sweep.py``
-(measured depth-1 vs depth-2 + modeled recovery), and the
-forced-multi-device checks in tests/_pipeline_checks.py.
+``DLRMConfig.pipeline_depth``), and the forced-multi-device checks in
+tests/_pipeline_checks.py.
 """
 from repro.pipeline.double_buffer import DoubleBufferedSlotPool
 from repro.pipeline.scheduler import (

@@ -1,4 +1,2 @@
-from repro.serving.engine import (  # noqa: F401
-    ContinuousBatcher,
-    generate,
-)
+"""DLRM serving: ``repro.serving.engine`` holds the two CTR scoring
+engines and :func:`~repro.serving.engine.make_dlrm_engine`."""

@@ -1,17 +1,9 @@
-"""Batched inference engines.
+"""DLRM serving engines.
 
-LM serving: ``generate`` is the simple API (one batch of prompts,
-greedy/temperature); ``ContinuousBatcher`` is the serving loop: a fixed
-pool of cache slots at possibly different lengths (per-sample ``length``
-in the cache); finished sequences are evicted and queued requests admitted
-by overwriting the slot's cache lines — the decode step itself is one
-jitted function whose shape never changes, so admission/eviction never
-recompiles.
-
-DLRM serving: ``DLRMEngine`` micro-batches CTR scoring requests into one
-fixed-shape jitted forward whose embedding pooling runs the fused
-table-batched (TBE) kernel — one ``pallas_call`` per batch for all 26
-Criteo-like tables instead of 26 launches (the paper's #tables axis).
+``DLRMEngine`` micro-batches CTR scoring requests into one fixed-shape
+jitted forward whose embedding pooling runs the fused table-batched (TBE)
+kernel — one ``pallas_call`` per batch for all 26 Criteo-like tables
+instead of 26 launches (the paper's #tables axis).
 ``PipelinedDLRMEngine`` (selected by ``DLRMConfig.cache.pipeline_depth
 >= 2`` via :func:`make_dlrm_engine`) runs the same scoring as a software
 pipeline over double-buffered slot pools (repro/pipeline/): batch k+1's
@@ -23,167 +15,17 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
 from repro.configs.dlrm import DLRMConfig
 from repro.core.jagged import JaggedBatch, PackedBatch
 from repro.core.parallel import ParallelContext
-from repro.models import decode as dec
 from repro.models import dlrm as dlrm_mod
-from repro.models import lm
 from repro.obs.trace import span
-
-
-def _sample(logits: jax.Array, rng, temperature: float) -> jax.Array:
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.random.categorical(rng, logits / temperature).astype(jnp.int32)
-
-
-def generate(params, cfg: ModelConfig, prompts: jax.Array, max_new: int,
-             ctx: Optional[ParallelContext] = None, *,
-             temperature: float = 0.0, seed: int = 0,
-             frames: Optional[jax.Array] = None) -> jax.Array:
-    """prompts (B, S) -> (B, max_new) generated ids (greedy by default)."""
-    B, S = prompts.shape
-    cache, hidden = dec.prefill(params, prompts, cfg, ctx,
-                                max_len=S + max_new, frames=frames)
-    logits = lm.lm_logits(params, hidden[:, -1:], cfg, ctx)[:, 0]
-    logits = jnp.where(jnp.arange(logits.shape[-1]) < cfg.vocab_size,
-                       logits, -jnp.inf)
-    rng = jax.random.key(seed)
-    tok = _sample(logits, rng, temperature)
-
-    @jax.jit
-    def step(cache, tok, rng):
-        cache, h = dec.decode_step(params, cache, tok, cfg, ctx)
-        lg = lm.lm_logits(params, h[:, None], cfg, ctx)[:, 0]
-        lg = jnp.where(jnp.arange(lg.shape[-1]) < cfg.vocab_size,
-                       lg, -jnp.inf)
-        rng, sub = jax.random.split(rng)
-        return cache, _sample(lg, sub, temperature), rng
-
-    outs = [tok]
-    for _ in range(max_new - 1):
-        cache, tok, rng = step(cache, tok, rng)
-        outs.append(tok)
-    return jnp.stack(outs, axis=1)
-
-
-# ---------------------------------------------------------------------------
-# Continuous batching
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class Request:
-    rid: int
-    prompt: np.ndarray
-    max_new: int
-    generated: List[int] = dataclasses.field(default_factory=list)
-
-
-class ContinuousBatcher:
-    """Slot-based continuous batching over a single decode_step program.
-
-    The batch dimension of the shared cache is the slot pool. Admission:
-    prefill the request alone (its own jitted program per prompt-length
-    bucket), then splice its cache lines into the slot. Eviction zeroes
-    the slot length. One decode_step advances every active slot.
-    """
-
-    def __init__(self, params, cfg: ModelConfig, num_slots: int,
-                 max_len: int, ctx: Optional[ParallelContext] = None,
-                 eos_id: int = 1):
-        self.params, self.cfg, self.ctx = params, cfg, ctx
-        self.num_slots, self.max_len, self.eos = num_slots, max_len, eos_id
-        # cache dtype must match the params' compute dtype (prefill writes
-        # param-dtype activations into the spliced slots)
-        self.cache = dec.init_cache(cfg, num_slots, max_len,
-                                    dtype=params["embed"].dtype)
-        self.slots: List[Optional[Request]] = [None] * num_slots
-        self.tokens = jnp.zeros((num_slots,), jnp.int32)
-        self.queue: List[Request] = []
-        self.done: Dict[int, Request] = {}
-
-        self._decode = jax.jit(
-            lambda c, t: dec.decode_step(params, c, t, cfg, ctx))
-        self._head = jax.jit(
-            lambda h: lm.lm_logits(params, h[:, None], cfg, ctx)[:, 0])
-
-    def submit(self, req: Request):
-        self.queue.append(req)
-
-    # -- internal ----------------------------------------------------------
-    def _admit(self):
-        for i in range(self.num_slots):
-            if self.slots[i] is None and self.queue:
-                req = self.queue.pop(0)
-                prompt = jnp.asarray(req.prompt[None, :], jnp.int32)
-                cache1, hidden = dec.prefill(self.params, prompt, self.cfg,
-                                             None, max_len=self.max_len)
-                # splice the single-request cache into slot i. The batch
-                # axis position is STRUCTURAL: nested dicts ("blocks" etc.)
-                # are layer-stacked with batch at axis 1; top-level arrays
-                # ("enc") have batch leading. Never infer from shapes —
-                # nL == num_slots would be ambiguous.
-                new_cache = {}
-                for k, v in self.cache.items():
-                    if k == "length":
-                        new_cache[k] = v.at[i].set(prompt.shape[1])
-                    elif isinstance(v, dict):       # layer-stacked: (nL, B, ...)
-                        new_cache[k] = {
-                            kk: v[kk].at[:, i].set(cache1[k][kk][:, 0])
-                            for kk in v}
-                    else:                           # batch-leading: (B, ...)
-                        new_cache[k] = v.at[i].set(cache1[k][0])
-                self.cache = new_cache
-                lg = self._head(hidden[:, -1])
-                first = int(jnp.argmax(lg[0, : self.cfg.vocab_size]))
-                req.generated.append(first)
-                self.tokens = self.tokens.at[i].set(first)
-                self.slots[i] = req
-
-    def _evict(self):
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            if (len(req.generated) >= req.max_new or
-                    (req.generated and req.generated[-1] == self.eos)):
-                self.done[req.rid] = req
-                self.slots[i] = None
-                self.cache["length"] = self.cache["length"].at[i].set(0)
-
-    def step(self):
-        """Admit, decode one token for all active slots, evict finished."""
-        self._admit()
-        if not any(s is not None for s in self.slots):
-            return False
-        self.cache, hidden = self._decode(self.cache, self.tokens)
-        logits = self._head(hidden)
-        nxt = jnp.argmax(
-            jnp.where(jnp.arange(logits.shape[-1]) < self.cfg.vocab_size,
-                      logits, -jnp.inf), axis=-1).astype(jnp.int32)
-        self.tokens = nxt
-        host = np.asarray(nxt)
-        for i, req in enumerate(self.slots):
-            if req is not None:
-                req.generated.append(int(host[i]))
-        self._evict()
-        return True
-
-    def run_to_completion(self, max_steps: int = 10_000):
-        steps = 0
-        while (self.queue or any(s is not None for s in self.slots)) \
-                and steps < max_steps:
-            if not self.step():
-                break
-            steps += 1
-        return self.done
 
 
 # ---------------------------------------------------------------------------

@@ -454,20 +454,21 @@ def test_lint_schema_pin_key_drift():
     """Changing a pinned schema's keys WITHOUT bumping the version is a
     violation; bumping the version flips it to a pin-update reminder."""
     drifted = (
-        "SNAPSHOT_SCHEMA_VERSION = 2\n"
-        "def write_snapshot(path, metrics=None):\n"
-        "    payload = {\n"
-        "        'schema_version': SNAPSHOT_SCHEMA_VERSION,\n"
-        "        'provenance': 1,\n"
-        "        'renamed_metrics': 2,\n"
+        "SLO_EVENT_SCHEMA_VERSION = 1\n"
+        "def to_dict(self):\n"
+        "    return {\n"
+        "        'schema_version': SLO_EVENT_SCHEMA_VERSION,\n"
+        "        'kind': 1, 'rule': 1, 'tick': 1, 'engine': 1,\n"
+        "        'measured': 1, 'renamed_threshold': 1,\n"
+        "        'table': 1, 'expected': 1,\n"
         "    }\n")
-    violations = lint_source(drifted, "src/repro/obs/export.py")
+    violations = lint_source(drifted, "src/repro/obs/slo.py")
     assert [v.rule for v in violations] == ["schema-pin"]
     assert "bump" in violations[0].message
 
-    bumped = drifted.replace("SNAPSHOT_SCHEMA_VERSION = 2",
-                             "SNAPSHOT_SCHEMA_VERSION = 3")
-    violations = lint_source(bumped, "src/repro/obs/export.py")
+    bumped = drifted.replace("SLO_EVENT_SCHEMA_VERSION = 1",
+                             "SLO_EVENT_SCHEMA_VERSION = 2")
+    violations = lint_source(bumped, "src/repro/obs/slo.py")
     assert [v.rule for v in violations] == ["schema-pin"]
     assert "update" in violations[0].message
 

@@ -1,11 +1,16 @@
-"""Unified telemetry subsystem (repro/obs/): metrics, tracer, exporters,
-and the measured-span -> perf-model calibration loop.
+"""Unified telemetry subsystem (repro/obs/): metrics, tracer, exporters.
 
 Engine-integration tests run the real serving paths (host cold tier —
-single device, CPU-tractable smoke shapes); the multi-rank runtime
-``fetch_rows`` timestamp check lives in tests/_tiering_checks.py."""
+single device, CPU-tractable smoke shapes); both engines on one
+telemetry over a remote cold tier run tests/_obs_checks.py in a
+subprocess with a forced 2-device CPU backend, and the multi-rank
+runtime ``fetch_rows`` timestamp check of the bare cache lives in
+tests/_tiering_checks.py."""
 import dataclasses
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -16,15 +21,6 @@ import pytest
 from repro.cache import CacheConfig
 from repro.configs import dlrm as dlrm_cfg
 from repro.core import comm
-from repro.core.perf_model import (
-    H100_DGX,
-    CalibrationResult,
-    StageSample,
-    Transport,
-    calibrate,
-    collective_time,
-    stage_time_error,
-)
 from repro.models import dlrm as dlrm_mod
 from repro.obs import (
     LANES,
@@ -34,7 +30,6 @@ from repro.obs import (
     Telemetry,
     Tracer,
     validate_chrome_trace,
-    write_snapshot,
 )
 from repro.pipeline import PipelineTrace
 from repro.serving.engine import CTRRequest, make_dlrm_engine
@@ -106,7 +101,6 @@ def test_metrics_registry_snapshot_schema():
         m.register_producer("cache", dict)
     m.register_producer("cache", lambda: {"hits": 9}, replace=True)
     assert m.snapshot()["producers"]["cache"] == {"hits": 9}
-    assert m.observation_count == 1
 
 
 # ---------------------------------------------------------------------------
@@ -278,81 +272,7 @@ def test_pipeline_trace_mirrors_to_tracer():
 
 
 # ---------------------------------------------------------------------------
-# Calibration: measured samples -> fitted Hardware
-# ---------------------------------------------------------------------------
-
-def _synthetic_samples(hw, rng, *, n_each=6, hosts=4):
-    out = []
-    for b in rng.uniform(1e4, 1e6, n_each):
-        out.append(StageSample(
-            "h2d", hw.gather_overhead_s + b / hw.host_Bps, b))
-    for b in rng.uniform(1e4, 1e6, n_each):
-        out.append(StageSample(
-            "fetch_remote", collective_time("fetch_rows", b, hosts,
-                                            hw.bulk), b, hosts))
-    return out
-
-
-def test_calibrate_recovers_synthetic_constants():
-    true = dataclasses.replace(
-        H100_DGX, gather_overhead_s=5e-4, host_Bps=2e8,
-        bulk=Transport("true", alpha_s=1.2e-3, beta_Bps=1e8))
-    rng = np.random.default_rng(0)
-    samples = _synthetic_samples(true, rng)
-    res = calibrate(samples, H100_DGX)
-    assert isinstance(res, CalibrationResult)
-    assert res.n_h2d == res.n_remote == 6
-    assert res.hw.gather_overhead_s == pytest.approx(5e-4, rel=1e-6)
-    assert res.hw.host_Bps == pytest.approx(2e8, rel=1e-6)
-    assert res.hw.bulk.alpha_s == pytest.approx(1.2e-3, rel=1e-6)
-    assert res.hw.bulk.beta_Bps == pytest.approx(1e8, rel=1e-6)
-    assert res.hw.name.endswith("-calibrated")
-    # the fit is exact, so model-vs-measured error collapses to ~0
-    held = _synthetic_samples(true, rng)
-    before = stage_time_error(held, H100_DGX)
-    after = res.error(held)
-    assert after["total"] < 1e-9 < before["total"]
-    assert set(after) == {"h2d", "fetch_remote", "total"}
-    # unexercised constants keep the base platform's values
-    assert res.hw.hbm_Bps == H100_DGX.hbm_Bps
-    assert res.hw.onesided == H100_DGX.onesided
-
-
-def test_calibrate_onesided_replaces_other_transport():
-    true = dataclasses.replace(
-        H100_DGX, onesided=Transport("t", alpha_s=2e-4, beta_Bps=5e8))
-    samples = [StageSample(
-        "fetch_remote",
-        collective_time("fetch_rows", b, 4, true.onesided), b, 4)
-        for b in (1e4, 1e5, 1e6)]
-    res = calibrate(samples, H100_DGX, onesided=True)
-    assert res.hw.onesided.alpha_s == pytest.approx(2e-4, rel=1e-6)
-    assert res.hw.bulk == H100_DGX.bulk          # untouched
-    assert res.error(samples)["total"] < 1e-9
-
-
-def test_calibrate_degenerate_inputs():
-    # no samples at all: base constants survive
-    res = calibrate([], H100_DGX)
-    assert res.hw.host_Bps == H100_DGX.host_Bps
-    assert res.n_h2d == res.n_remote == 0
-    # one sample: slope-only fit through the origin, never negative
-    res = calibrate([StageSample("h2d", 1e-3, 1e5)], H100_DGX)
-    assert res.hw.gather_overhead_s == 0.0
-    assert res.hw.host_Bps == pytest.approx(1e5 / 1e-3)
-    # identical bytes (rank-1 design): still a usable non-negative fit
-    res = calibrate([StageSample("h2d", 1e-3, 1e5),
-                     StageSample("h2d", 2e-3, 1e5)], H100_DGX)
-    assert res.hw.gather_overhead_s >= 0.0 and res.hw.host_Bps > 0
-    with pytest.raises(ValueError, match="unknown stage"):
-        stage_time_error([StageSample("nope", 1.0, 1.0)], H100_DGX)
-    # single-host "fetch_remote" samples cannot constrain a collective
-    res = calibrate([StageSample("fetch_remote", 1e-3, 1e5, 1)], H100_DGX)
-    assert res.hw.bulk == H100_DGX.bulk and res.n_remote == 0
-
-
-# ---------------------------------------------------------------------------
-# Engine integration: request latency, cache-lane spans, stage samples
+# Engine integration: request latency, cache-lane spans
 # ---------------------------------------------------------------------------
 
 def _smoke_cfg(depth=1):
@@ -396,9 +316,6 @@ def test_serial_engine_records_latency_and_cache_spans():
     scatters = tel.tracer.spans(lane="cache", name="cache.scatter")
     assert {s.args["seq"] for s in fetches} == \
         {s.args["seq"] for s in scatters}
-    samples = tel.tracer.stage_samples()
-    assert samples and all(s.stage == "h2d" for s in samples)
-    assert all(s.bytes > 0 and s.seconds > 0 for s in samples)
     # the producer surfaces live CacheStats in the snapshot
     snap = tel.metrics.snapshot()
     prod = snap["producers"]["dlrm.cache"]
@@ -483,10 +400,35 @@ def test_telemetry_disabled_records_nothing():
 
 
 # ---------------------------------------------------------------------------
+# Both engines, one telemetry, remote cold tier (subprocess, 2 devices)
+# ---------------------------------------------------------------------------
+
+def test_both_engines_share_one_merged_trace(tmp_path):
+    trace_path, seen_path = tmp_path / "trace.json", tmp_path / "seen.json"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "_obs_checks.py"),
+         str(trace_path), str(seen_path)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    trace = json.loads(trace_path.read_text())
+    seen = json.loads(seen_path.read_text())
+    assert validate_chrome_trace(trace) > 0
+    engines = {e["args"]["engine"] for e in trace["traceEvents"]
+               if e.get("args", {}).get("engine")}
+    assert {"dlrm", "dlrm_pipelined"} <= engines, engines
+    # each engine's requests land in its own histogram of the one
+    # Telemetry, and the remote tier's collectives on its comm lane
+    assert seen["latency_counts"] == {"dlrm": 12, "dlrm_pipelined": 12}
+    assert seen["timed_fetch_rows"] > 0
+
+
+# ---------------------------------------------------------------------------
 # Exporters
 # ---------------------------------------------------------------------------
 
-def test_sweep_report_validates_columns(tmp_path):
+def test_sweep_report_validates_columns():
     rep = SweepReport("sweep", "x", "y")
     rep.add(sweep="s", x=1, y=2.5)
     rep.comment("context line")
@@ -501,24 +443,3 @@ def test_sweep_report_validates_columns(tmp_path):
         SweepReport("a", "a")
     with pytest.raises(ValueError, match="at least one"):
         SweepReport()
-    path = rep.write(str(tmp_path / "out.csv"))
-    text = open(path).read()
-    # a provenance header (comment lines) precedes the verbatim CSV
-    header, body = text.split("# jax_version:", 1)
-    assert header.startswith("# git_sha:")
-    assert "# timestamp_utc:" in header
-    assert body.split("\n", 1)[1] == rep.csv()
-
-
-def test_write_snapshot(tmp_path):
-    m = MetricsRegistry()
-    m.histogram("lat").observe(0.5)
-    path = write_snapshot(str(tmp_path / "bench.json"), metrics=m,
-                          extra={"calibration": {"host_Bps": 1e8}})
-    with open(path) as f:
-        got = json.load(f)
-    assert got["schema_version"] == 2
-    assert set(got["provenance"]) == {"git_sha", "timestamp_utc",
-                                      "jax_version"}
-    assert got["metrics"]["histograms"]["lat"]["count"] == 1
-    assert got["calibration"] == {"host_Bps": 1e8}

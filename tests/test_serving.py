@@ -1,62 +1,8 @@
-"""Serving engine: generate() and continuous batching equivalence."""
+"""DLRM serving engine: scores, request validation, the tiered store."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from repro import configs
-from repro.models import lm
-from repro.serving.engine import ContinuousBatcher, Request, generate
-
-
-@pytest.fixture(scope="module")
-def setup():
-    cfg = configs.get_smoke_config("granite-8b")
-    params = lm.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
-    return cfg, params
-
-
-def test_generate_greedy_deterministic(setup):
-    cfg, params = setup
-    prompts = jax.random.randint(jax.random.key(1), (2, 6), 0,
-                                 cfg.vocab_size)
-    a = generate(params, cfg, prompts, max_new=5)
-    b = generate(params, cfg, prompts, max_new=5)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert a.shape == (2, 5)
-    assert int(a.max()) < cfg.vocab_size
-
-
-def test_continuous_batcher_matches_generate(setup):
-    cfg, params = setup
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size, (3, 6)).astype(np.int32)
-    max_new = 4
-    ref = np.asarray(generate(params, cfg, jnp.asarray(prompts), max_new,
-                              temperature=0.0))
-    eng = ContinuousBatcher(params, cfg, num_slots=2, max_len=32,
-                            eos_id=-1)  # no eos: run to max_new
-    for rid in range(3):
-        eng.submit(Request(rid=rid, prompt=prompts[rid], max_new=max_new))
-    done = eng.run_to_completion()
-    assert sorted(done) == [0, 1, 2]
-    for rid in range(3):
-        np.testing.assert_array_equal(np.asarray(done[rid].generated),
-                                      ref[rid])
-
-
-def test_batcher_slot_reuse(setup):
-    cfg, params = setup
-    rng = np.random.default_rng(1)
-    eng = ContinuousBatcher(params, cfg, num_slots=1, max_len=24, eos_id=-1)
-    for rid in range(3):
-        eng.submit(Request(rid=rid,
-                           prompt=rng.integers(0, cfg.vocab_size, 4).astype(
-                               np.int32),
-                           max_new=3))
-    done = eng.run_to_completion()
-    assert len(done) == 3
-    assert all(len(r.generated) == 3 for r in done.values())
 
 
 # ---------------------------------------------------------------------------

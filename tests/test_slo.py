@@ -1,13 +1,13 @@
-"""SLO monitoring, drift detection, and the BenchRecord perf gate.
+"""SLO monitoring and drift detection.
 
 Unit tests drive :class:`SLOMonitor` / :class:`DriftDetector` over
 hand-fed windowed instruments (breach-event schema, evidence gating,
 transition-only drift firing); engine-integration tests attach both to a
-real serving run and check the slo tracer lane; the bench section pins
-every ``compare_bench`` verdict and the CLI's bless/compare round trip.
+real serving run and check the slo tracer lane; the flash-crowd tests
+serve a priced plan under stationary and rotated traffic and hold the
+detector to both.
 """
 import dataclasses
-import json
 
 import jax
 import numpy as np
@@ -17,6 +17,7 @@ from repro.cache import CacheConfig
 from repro.configs import dlrm as dlrm_cfg
 from repro.core.perf_model import H100_DGX
 from repro.core.sharding_plan import TableSpec, plan
+from repro.data.synthetic import dlrm_drift_batches
 from repro.models import dlrm as dlrm_mod
 from repro.obs import (
     LANES,
@@ -26,15 +27,6 @@ from repro.obs import (
     Telemetry,
     expected_hit_rates,
 )
-from repro.obs.bench import (
-    compare_bench,
-    config_hash,
-    load_bench,
-    make_bench_record,
-    make_metric,
-    write_bench,
-)
-from repro.obs.bench import main as bench_main
 from repro.obs.slo import SLO_EVENT_SCHEMA_VERSION, SLOEvent
 from repro.serving.engine import CTRRequest, make_dlrm_engine
 
@@ -281,107 +273,88 @@ def test_pipelined_engine_records_stage_windows():
 
 
 # ---------------------------------------------------------------------------
-# compare_bench: the verdict matrix and the CLI round trip
+# Flash crowd: a priced plan served under stationary vs rotated traffic
 # ---------------------------------------------------------------------------
 
-def _record(metrics, sweep="demo", config=None):
-    return make_bench_record(sweep, config=config or {"shape": 1},
-                             metrics=metrics)
+# six same-spec tables under a tight budget: every table cached, in
+# pools of more than one size; the hot set jumps at batch ROTATE_AT
+CROWD = dict(tables=6, rows=2048, dim=16, pooling=8, batch=8,
+             budget=48_000, window=4, batches=24, zipf_a=0.9)
+ROTATE_AT = 12
+DETECT_BOUND = 8          # micro-batches from the rotation to the event
 
 
-def test_compare_bench_verdict_matrix():
-    base = _record({
-        "lat_ms": make_metric(10.0, "ms", "lower_is_better", 0.10),
-        "hit_rate": make_metric(0.90, "1", "higher_is_better", 0.02),
-        "gone": make_metric(1.0, "1", "lower_is_better", 0.1),
-        "gone_info": make_metric(1.0, "1", "lower_is_better", None),
-        "wall_s": make_metric(3.0, "s", "lower_is_better", None),
-        "zero": make_metric(0.0, "1", "lower_is_better", 0.5),
-    })
-    cur = _record({
-        "lat_ms": make_metric(8.0, "ms", "lower_is_better", 0.10),
-        "hit_rate": make_metric(0.70, "1", "higher_is_better", 0.02),
-        "wall_s": make_metric(30.0, "s", "lower_is_better", None),
-        "zero": make_metric(0.4, "1", "lower_is_better", 0.5),
-        "brand_new": make_metric(5.0, "1", "lower_is_better", 0.1),
-    })
-    cmp_ = compare_bench(base, cur)
-    by = {v.metric: v.status for v in cmp_.verdicts}
-    assert by == {
-        "lat_ms": "improvement",          # 20% faster, beyond tolerance
-        "hit_rate": "regression",         # -22% relative, gates
-        "gone": "missing_metric",         # gated metric vanished: gates
-        "gone_info": "informational",     # informational vanished: ok
-        "wall_s": "informational",        # 10x slower but never gates
-        "zero": "within_tolerance",       # baseline 0 -> absolute delta
-        "brand_new": "new_metric",
-    }
-    assert not cmp_.ok
-    gating = {v.metric for v in cmp_.verdicts if v.gating}
-    assert gating == {"hit_rate", "gone"}
+def _serve_crowd(rotate_every):
+    """Serve CROWD's traffic from an engine warmed on the popularity the
+    planner priced; returns (monitor, detector) after every batch."""
+    c = CROWD
+    T, R, L, B = c["tables"], c["rows"], c["pooling"], c["batch"]
+    p = plan([TableSpec(f"t{i}", rows=R, dim=c["dim"], pooling=L)
+              for i in range(T)],
+             num_shards=2, batch_per_shard=B,
+             hbm_budget_bytes=c["budget"], hw=H100_DGX, zipf_a=c["zipf_a"])
+    assert [pl.strategy for pl in p.placements] == ["cached"] * T
+    expected = expected_hit_rates(p, T)
+    base = dataclasses.replace(
+        dlrm_cfg.smoke(), num_sparse_features=T, rows_per_table=R,
+        embedding_dim=c["dim"], pooling=L, bottom_mlp=(32, c["dim"]),
+        kernel_mode="reference")
+    freqs = (np.arange(1, R + 1, dtype=np.float64) ** -c["zipf_a"]) * 1e7
+    cfg = dataclasses.replace(
+        base, sharding_plan=p,
+        cache=dataclasses.replace(base.cache, warmup_freqs=freqs))
+    tel = Telemetry(window=c["window"])
+    eng = make_dlrm_engine(dlrm_mod.init_params(jax.random.key(0), base),
+                           cfg, batch_size=B, telemetry=tel)
+    # the floor sits below the stationary windowed hit rate and above
+    # the post-rotation crater
+    floor = max(0.05, float(expected.mean()) - 0.15)
+    mon = SLOMonitor(tel, SLOPolicy(name="serving", hit_rate_floor=floor,
+                                    min_window_lookups=1),
+                     engine=eng.obs_name)
+    det = DriftDetector(tel, expected, engine=eng.obs_name,
+                        threshold=0.15, min_updates=3)
+    gen = dlrm_drift_batches(cfg, B, seed=3, zipf_a=c["zipf_a"],
+                             rotate_every=rotate_every)
+    rid = 0
+    for _ in range(c["batches"]):
+        d = next(gen)
+        idx = np.asarray(d["batch"].indices)
+        lens = np.asarray(d["batch"].lengths)
+        for i in range(B):
+            eng.submit(CTRRequest(
+                rid=rid, dense=d["dense"][i],
+                indices=idx[:, i, :].astype(np.int32),
+                lengths=lens[:, i].astype(np.int32)))
+            rid += 1
+        eng.run_to_completion()
+    return mon, det
 
 
-def test_compare_bench_config_hash_gate():
-    base = _record({"m": make_metric(1.0, "1", "lower_is_better", 0.1)},
-                   config={"rows": 64})
-    cur = _record({"m": make_metric(1.0, "1", "lower_is_better", 0.1)},
-                  config={"rows": 128})
-    cmp_ = compare_bench(base, cur)
-    assert not cmp_.ok and "config hash changed" in cmp_.failures[0]
-    assert compare_bench(base, cur, allow_config_change=True).ok
-    assert config_hash({"rows": 64}) != config_hash({"rows": 128})
-    assert base["config_hash"] == config_hash({"rows": 64})
+@pytest.fixture(scope="module")
+def flash_crowd():
+    return _serve_crowd(rotate_every=ROTATE_AT)
 
 
-def test_compare_bench_direction_flip_fails():
-    base = _record({"m": make_metric(1.0, "1", "lower_is_better", 0.1)})
-    cur = _record({"m": make_metric(1.0, "1", "higher_is_better", 0.1)})
-    cmp_ = compare_bench(base, cur)
-    assert not cmp_.ok and "flipped direction" in cmp_.failures[0]
+def test_stationary_traffic_raises_no_drift_and_no_breach():
+    """Traffic that matches the plan is the null hypothesis: every
+    window is judged and nothing fires."""
+    mon, det = _serve_crowd(rotate_every=0)
+    assert mon.windows_evaluated == CROWD["batches"]
+    assert det.summary()["events"] == 0, det.summary()
+    assert mon.breaches == 0, mon.summary()
 
 
-def test_make_metric_validation():
-    with pytest.raises(ValueError, match="direction"):
-        make_metric(1.0, "ms", "sideways", 0.1)
-    with pytest.raises(ValueError, match="tolerance"):
-        make_metric(1.0, "ms", "lower_is_better", -0.5)
-    with pytest.raises(ValueError, match="make_metric"):
-        make_bench_record("s", config={}, metrics={"m": {"value": 1.0}})
+def test_flash_crowd_trips_drift_after_the_rotation_within_bound(
+        flash_crowd):
+    _, det = flash_crowd
+    first = det.first_detection_tick
+    assert first is not None, "drift never fired on the rotated hot set"
+    # ticks are 1-based, so batch ROTATE_AT (0-based) is tick ROTATE_AT+1
+    assert 0 < first - ROTATE_AT <= DETECT_BOUND, first
 
 
-def test_bench_record_round_trip_and_provenance(tmp_path):
-    rec = _record({"m": make_metric(1.0, "1", "lower_is_better", 0.1)})
-    path = str(tmp_path / "BENCH_demo.json")
-    write_bench(path, rec)
-    loaded = load_bench(path)
-    assert loaded == json.loads(json.dumps(rec, default=str))
-    assert {"git_sha", "timestamp_utc", "jax_version"} <= \
-        set(loaded["provenance"])
-    with pytest.raises(ValueError, match="not a BenchRecord"):
-        bad = str(tmp_path / "bad.json")
-        with open(bad, "w") as f:
-            json.dump({"hello": 1}, f)
-        load_bench(bad)
-
-
-def test_bench_cli_bless_then_compare(tmp_path, capsys):
-    cur_dir, base_dir = tmp_path / "cur", tmp_path / "baselines"
-    cur_dir.mkdir()
-    path = str(cur_dir / "BENCH_demo.json")
-    write_bench(path, _record(
-        {"hit_rate": make_metric(0.9, "1", "higher_is_better", 0.02)}))
-    # no baseline yet: compare passes with a bless hint
-    assert bench_main(["compare", path, "--baselines",
-                       str(base_dir)]) == 0
-    assert "NO BASELINE" in capsys.readouterr().out
-    assert bench_main(["bless", path, "--baselines", str(base_dir)]) == 0
-    assert bench_main(["compare", path, "--baselines",
-                       str(base_dir)]) == 0
-    assert "bench gate: clean" in capsys.readouterr().out
-    # regress the metric: the gate must fail with exit code 1
-    write_bench(path, _record(
-        {"hit_rate": make_metric(0.5, "1", "higher_is_better", 0.02)}))
-    assert bench_main(["compare", path, "--baselines",
-                       str(base_dir)]) == 1
-    out = capsys.readouterr().out
-    assert "regression" in out and "FAIL" in out
+def test_flash_crowd_breaches_the_hit_rate_floor(flash_crowd):
+    mon, _ = flash_crowd
+    assert mon.summary()["breaches_by_rule"].get("hit_rate", 0) > 0, \
+        mon.summary()

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis import audit
+from repro.analysis import audit, count_pallas_calls
 from repro.core.embedding_bag import (
     EmbeddingBagConfig,
     init_tables,
@@ -158,6 +158,19 @@ def test_tbe_single_pallas_call():
               t, 0, i, mode="interpret", fused=True),
           (tables[:, :8], idx),
           kops.KERNEL_CONTRACTS["rw_partial_fused"]).raise_if_failed()
+
+
+def test_unfused_path_launches_one_kernel_per_table():
+    """The per-table baseline the fused kernel is measured against pays
+    one pallas_call per table (the fused one is pinned to one above)."""
+    for T in (1, 4):
+        tables, idx, _, _ = _mk(T)
+        eff_w = jnp.ones(idx.shape, jnp.float32)
+        n = count_pallas_calls(
+            lambda t, i, w: kops.embedding_bag_batched(
+                t, i, None, w, mode="interpret", fused=False),
+            tables, idx, eff_w)
+        assert n == T, (T, n)
 
 
 def test_pooled_lookup_local_fused_switch():

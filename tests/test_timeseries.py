@@ -151,7 +151,7 @@ def test_ewma_masked_update():
 
 
 # ---------------------------------------------------------------------------
-# Registry integration: prefix rotation, config pinning, op accounting
+# Registry integration: prefix rotation, config pinning
 # ---------------------------------------------------------------------------
 
 def test_registry_prefix_rotation_is_scoped():
@@ -182,20 +182,6 @@ def test_registry_pins_window_and_alpha():
     m.ewma("hr", alpha=0.25)
     with pytest.raises(ValueError):
         m.ewma("hr", alpha=0.5)
-
-
-def test_registry_windowed_op_counts():
-    m = MetricsRegistry()
-    wh = m.windowed_histogram("lat", window=2)
-    rc = m.rolling_counter("hits", window=2)
-    ew = m.ewma("hr")
-    wh.observe(1e-3)
-    wh.observe(2e-3)
-    rc.inc(3)
-    ew.update(np.array([0.5, 0.5]))
-    m.rotate_windows()
-    counts = m.windowed_op_counts()
-    assert counts == {"observe": 2, "inc": 1, "rotate": 2, "ewma": 2}
 
 
 def test_registry_snapshot_includes_windowed_sections():
